@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compat
 from repro.core import ProjectorSpec, VolumeGeometry, parallel_beam
 from repro.core.distributed import distribute
 from repro.recon.sirt import sirt
@@ -53,7 +54,7 @@ def run(csv_rows: list):
     f = jnp.asarray(rng.normal(size=vol.shape), jnp.float32)
 
     for ws in SHARD_COUNTS:
-        mesh = jax.make_mesh((ws, 1), ("data", "model"),
+        mesh = compat.make_mesh((ws, 1), ("data", "model"),
                              devices=jax.devices()[:ws])
         g = parallel_beam(ANGLES_PER_SHARD * ws, 8, 32, vol)
         dp = distribute(ProjectorSpec(g), mesh)

@@ -15,7 +15,7 @@ import numpy as np
 
 from repro.configs.leap_ct import table1_geometries
 from repro.core import Projector
-from repro.launch.roofline import HBM_BW
+from repro.launch import roofline
 
 
 def _time(fn, *args, reps=3):
@@ -38,7 +38,7 @@ def projected_tpu_seconds(geom, model="sf") -> float:
     # per angle: one streamed pass over the (z-contracted) volume + tile output
     traffic = geom.n_angles * (v.nx * v.ny * max(geom.n_rows, v.nz) * 4) \
         + sino_bytes + vol_bytes
-    return traffic / HBM_BW
+    return traffic / roofline.peaks("TPU v5 lite")["hbm_bw"]
 
 
 def run(csv_rows: list):

@@ -1,30 +1,26 @@
-"""Version-compatibility shims for drift-prone jax APIs.
+"""The one seam for jax APIs that have moved or changed behaviour.
 
-The repo pins jax in ``requirements-test.txt`` but must keep working as the
-pin moves (the ``jax-drift`` CI leg runs tier-1 against the latest release).
-Every API that jax has renamed/moved recently — and that previously broke a
-whole test suite with an ``AttributeError`` at call time — is funneled
-through this module so the next rename is a one-line fix here instead of a
-sweep across the tree.
+The repo supports exactly the jax pinned in ``requirements-test.txt``
+(0.9.0).  Every API below changed shape across recent releases and once
+broke a whole test suite at call time; funneling it through this module
+(RL003 enforces that) keeps the next change a one-line fix here instead of
+a sweep across the tree.
 
-Covered drift:
-
-* ``shard_map`` — promoted from ``jax.experimental.shard_map`` to
-  ``jax.shard_map`` (and its replication-check kwarg renamed
-  ``check_rep`` -> ``check_vma``) in jax 0.6/0.7.
-* ``tree_flatten_with_path`` / ``tree_map_with_path`` — ``jax.tree.*``
-  only grew the ``*_with_path`` variants after 0.4.37; the
-  ``jax.tree_util`` spellings exist on every supported version.
-* ``Compiled.cost_analysis()`` — returned a one-element *list* of dicts
-  up to jax 0.4.x and a plain dict from 0.5; ``cost_analysis_dict``
-  normalizes both to a dict.
+* ``shard_map`` — now ``jax.shard_map`` with the ``check_vma`` kwarg.
+* ``axis_size`` — ``jax.lax.axis_size`` inside ``shard_map``.
+* ``tree_flatten_with_path`` / ``tree_map_with_path`` — the
+  ``jax.tree_util`` spellings.
+* ``Compiled.cost_analysis()`` — ``cost_analysis_dict`` returns a dict
+  (``{}`` where a backend reports nothing).
+* ``make_mesh`` — ``jax.make_mesh`` now defaults to ``Explicit`` axes,
+  under which ``with_sharding_constraint`` and un-annotated reshapes of
+  sharded arrays raise; this repo's code is written for ``Auto`` axes.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Dict
+from typing import Any, Callable, Dict, Optional, Sequence
 
 import jax
-import jax.core
 
 __all__ = [
     "shard_map",
@@ -32,56 +28,36 @@ __all__ = [
     "tree_flatten_with_path",
     "tree_map_with_path",
     "cost_analysis_dict",
+    "make_mesh",
 ]
 
 
-# --------------------------------------------------------------------------- #
-# shard_map: jax.experimental.shard_map (<= 0.4/0.5, kwarg check_rep) vs
-# jax.shard_map (>= 0.6, kwarg check_vma).
-# --------------------------------------------------------------------------- #
 def shard_map(f: Callable, mesh, in_specs, out_specs,
               check_vma: bool = True) -> Callable:
-    """Dispatch to whichever ``shard_map`` this jax ships.
-
-    ``check_vma`` follows the new-jax spelling; it maps onto ``check_rep``
-    on versions that predate the rename (the semantics are identical for
-    our usage: disable the replication/varying-mesh-axes check).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+    """``jax.shard_map`` (``check_vma=False`` disables the varying-mesh-axes
+    check, which the hand-written collectives here do not annotate)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def axis_size(name) -> int:
-    """Static size of a named mesh axis inside ``shard_map``.
-
-    ``jax.lax.axis_size`` only exists on new jax; older versions expose the
-    same static value through ``jax.core.axis_frame``."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return int(jax.core.axis_frame(name))
+    """Static size of a named mesh axis inside ``shard_map``."""
+    return jax.lax.axis_size(name)
 
 
-# --------------------------------------------------------------------------- #
-# tree path helpers: jax.tree_util works everywhere; jax.tree.* only on
-# new jax.
-# --------------------------------------------------------------------------- #
 tree_flatten_with_path = jax.tree_util.tree_flatten_with_path
 tree_map_with_path = jax.tree_util.tree_map_with_path
 
 
-# --------------------------------------------------------------------------- #
-# Compiled.cost_analysis(): list-of-dicts (old) vs dict (new).
-# --------------------------------------------------------------------------- #
 def cost_analysis_dict(compiled: Any) -> Dict[str, Any]:
-    """``compiled.cost_analysis()`` normalized to a flat dict (possibly
-    empty — some backends return None)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, dict):
-        return cost
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost and isinstance(cost[0], dict) else {}
-    return {}
+    """``compiled.cost_analysis()`` as a flat dict (empty where the backend
+    returns None)."""
+    return compiled.cost_analysis() or {}
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Optional[Sequence[Any]] = None):
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    auto = (jax.sharding.AxisType.Auto,) * len(shape)
+    return jax.make_mesh(tuple(shape), tuple(axes), axis_types=auto,
+                         devices=devices)

@@ -26,11 +26,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import tune
+
 NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def flash_ref(q, k, v, window: Optional[int] = None):
@@ -139,7 +137,7 @@ def flash_attention(q, k, v, window: Optional[int] = None,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=tune._interpret(),
     )(qg, k, v)
     return out.reshape(B, H, S, hd)
 
@@ -312,7 +310,7 @@ def _fwd_with_stats(q, k, v, window, bq, bk):
         scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32)],
-        interpret=_interpret(),
+        interpret=tune._interpret(),
     )(qg, k, v)
     return o, lse
 
@@ -361,7 +359,7 @@ def _fa_bwd(window, bq, bk, res, do):
                                lambda b, kv, g, qi, ki: (b, kv, g, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, S, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
-        interpret=_interpret(),
+        interpret=tune._interpret(),
     )(qg, k, v, dog, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -384,7 +382,7 @@ def _fa_bwd(window, bq, bk, res, do):
                    jax.ShapeDtypeStruct((B, KV, G, S, hd), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, hd), jnp.float32),
                         pltpu.VMEM((bk, hd), jnp.float32)],
-        interpret=_interpret(),
+        interpret=tune._interpret(),
     )(qg, k, v, dog, lse, delta)
     # per-group dk/dv sum over the G query heads sharing each kv head
     dq = dq.reshape(B, H, S, hd)

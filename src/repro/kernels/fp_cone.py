@@ -60,19 +60,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.geometry import CTGeometry
-from repro.kernels import precision, ref, tune
+from repro.kernels import fp_par, precision, ref, tune
 from repro.kernels.footprint import trapezoid_pixel_weight
 
 
 _EPS = 1e-9
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def _round_up(n: int, m: int) -> int:
-    return ((n + m - 1) // m) * m
 
 
 def _mag_bounds(geom: CTGeometry) -> Tuple[float, float]:
@@ -83,17 +75,38 @@ def _mag_bounds(geom: CTGeometry) -> Tuple[float, float]:
     return mag_min, mag_max
 
 
-def _u_window_size_div(geom: CTGeometry, bg: int, nu: int) -> int:
+def _u_window_size_div(geom: CTGeometry, bg: int) -> int:
     """Static bound on the detector-column window covering one bg voxel tile
-    for a *divergent* (fan / cone transaxial) beam (BP kernels).
+    for a *divergent* (fan / cone transaxial) beam (BP kernels), before
+    alignment (``tune.aligned_window``).
     |duc/dgi| <= sqrt(2) * dx * mag_max and one voxel footprint spans
     <= sqrt(2) * dx * mag_max; curved (fan) footprints are never wider."""
     du, dx = geom.pixel_width, geom.vol.dx
     _, mag_max = _mag_bounds(geom)
     span = bg * dx * math.sqrt(2.0) * mag_max / du
     margin = 2.0 * math.sqrt(2.0) * dx * mag_max / du + 4.0
-    w = int(math.ceil(span + 2 * margin)) + 2
-    return min(_round_up(max(w, 8), 8), nu)
+    return max(int(math.ceil(span + 2 * margin)) + 2, 8)
+
+
+def _line_major_batch(fb, gathered_x: bool):
+    """(B, nx, ny, nz) -> (NL, B*NG, nz): loop axis first and the batch
+    stacked along the gathered axis, each sample's gathered axis
+    zero-padded to the sublane tile, so one kernel block is a whole
+    (NG, nz) line plane of one sample."""
+    if gathered_x:
+        fb = jnp.swapaxes(fb, 1, 2)
+    B, nl, ng, nz = fb.shape
+    ngp = tune.round_up(ng, tune.sublane_tile(fb.dtype))
+    fb = jnp.pad(fb, ((0, 0), (0, 0), (0, ngp - ng), (0, 0)))
+    return jnp.swapaxes(fb, 0, 1).reshape(nl, B * ngp, nz), ngp
+
+
+def _volume_from_line_major(out, B: int, ng: int, gathered_x: bool):
+    """Inverse of :func:`_line_major_batch` for a BP accumulator
+    (NL, B*NGp, nz) -> (B, nx, ny, nz)."""
+    nl, bng, nz = out.shape
+    out = jnp.swapaxes(out.reshape(nl, B, bng // B, nz)[:, :, :ng], 0, 1)
+    return jnp.swapaxes(out, 1, 2) if gathered_x else out
 
 
 def _corner_trapezoid(P, gi, q0, l0, lif, sdd, dxv, curved: bool = False):
@@ -181,12 +194,12 @@ def _view_params_cone(geom: CTGeometry) -> Tuple[np.ndarray, np.ndarray,
 
 
 def _fp_cone_kernel(params_ref,        # SMEM (n_views, 20)
-                    f_ref,             # VMEM (NG, 1, NZ) volume line
+                    f_ref,             # VMEM (NG, NZ) volume line plane
                     out_ref,           # VMEM (1, BU, BV) sino tile
-                    *, W: int, NZW: int, u0: float, du: float,
+                    *, W: int, u0: float, du: float,
                     v0: float, dv: float, z0c: float, dz: float,
                     sdd: float, dxv: float, ng: int, nz: int,
-                    bu: int, bv: int, nav: int):
+                    bu: int, bv: int, nav: int, align: int):
     a = pl.program_id(0)
     ub = pl.program_id(1)
     vb = pl.program_id(2)
@@ -215,47 +228,36 @@ def _fp_cone_kernel(params_ref,        # SMEM (n_views, 20)
         return (u * l0 - sdd * q0) / den
 
     g1, g2 = gi_of(u_first), gi_of(u_last)
-    start = jnp.floor(jnp.minimum(g1, g2)).astype(jnp.int32) - (
-        W - jnp.abs(jnp.ceil(g2 - g1)).astype(jnp.int32)) // 2
-    start = jnp.clip(start, 0, max(ng - W, 0))
+    start = tune.window_start(g1, g2, W, ng, align)
 
-    gi = start.astype(jnp.float32) + jax.lax.broadcasted_iota(
-        jnp.float32, (1, W), 1)                              # (1, W)
+    win = f_ref[pl.ds(start, W), :]                          # (W, nz)
+    gi = start + tune.iota_f32((1, W), 1)                    # (1, W)
     # corner projections -> sorted trapezoid breakpoints (shared with BP)
     t0, t1, t2, t3, h, rt2 = _corner_trapezoid(P, gi, q0, l0, lif, sdd, dxv)
 
-    uk = u_first + du * jax.lax.broadcasted_iota(jnp.float32, (bu, 1), 0)
+    uk = u_first + du * tune.iota_f32((bu, 1), 0)
     el = uk - du / 2.0
     wu = trapezoid_pixel_weight(el, el + du, t0, t1, t2, t3, h)  # (bu, W)
 
     ell = jnp.maximum(Al * gi + l0, _EPS)
     mag = sdd / ell                                          # (1, W)
-    v_first = v0 + (vb * bv) * dv
-    vlane = v_first + dv * jax.lax.broadcasted_iota(jnp.float32, (bv, 1), 0)
+    elv = v0 + (vb * bv) * dv - dv / 2.0 + dv * tune.iota_f32((bv, 1), 0)
+    zt = z0c + dz * tune.iota_f32((1, nz), 1)                # (1, nz)
 
     acc = jnp.zeros((bu, bv), jnp.float32)
     for w in range(W):
-        mag_w = mag[0, w]
-        rt2_w = rt2[0, w]
-        inv_mag = 1.0 / jnp.maximum(mag_w, 1e-9)
-        # z index window covering this view-row block at this magnification
-        zc_first = v_first * inv_mag
-        z0i = jnp.floor((zc_first - z0c) / dz).astype(jnp.int32) - 2
-        z0i = jnp.clip(z0i, 0, max(nz - NZW, 0))
-        zt = z0c + (z0i.astype(jnp.float32)
-                    + jax.lax.broadcasted_iota(jnp.float32, (1, NZW), 1)) * dz
-        vlo = (zt - dz / 2.0) * mag_w                        # (1, NZW)
+        # The axial overlap spans the whole z line (lanes): Mosaic slices
+        # lanes only at static or 128-aligned offsets.
+        mag_w = mag[:, w:w + 1]                              # (1, 1)
+        vlo = (zt - dz / 2.0) * mag_w                        # (1, nz)
         vhi = (zt + dz / 2.0) * mag_w
-        elv = vlane - dv / 2.0                               # (bv, 1)
         ov = jnp.maximum(jnp.minimum(vhi, elv + dv)
-                         - jnp.maximum(vlo, elv), 0.0) / dv  # (bv, NZW)
-        obl = jnp.sqrt(1.0 + (zt * zt) / jnp.maximum(rt2_w, 1e-9))
-        Wz = ov * obl                                        # (bv, NZW)
-        fwin = f_ref[start + w, 0, pl.ds(z0i, NZW)]          # (NZW,)
-        rv = jax.lax.dot_general(precision.cast_like(Wz, fwin), fwin[:, None],
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)[:, 0]
-        acc = acc + wu[:, w][:, None] * rv[None, :]
+                         - jnp.maximum(vlo, elv), 0.0) / dv  # (bv, nz)
+        obl = jnp.sqrt(1.0 + (zt * zt) / jnp.maximum(rt2[:, w:w + 1], 1e-9))
+        fw = win[w:w + 1, :]                                 # (1, nz)
+        rv = precision.mxu_dot(fw, precision.cast_like(ov * obl, fw),
+                               ((1,), (1,)))                 # (1, bv)
+        acc = acc + wu[:, w:w + 1] * rv
     precision.store_tile(out_ref, 0, acc)
 
 
@@ -268,39 +270,38 @@ def _run_group(fb, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
     if params.shape[0] == 0:
         return None
     vol = geom.vol
-    if not gathered_x:
-        fb = jnp.swapaxes(fb, 1, 2)
-    B, ng, nl, nz = fb.shape
-    fs = fb.reshape(B * ng, nl, nz)
+    B = fb.shape[0]
+    fs, ng = _line_major_batch(fb, gathered_x)
+    nl, _, nz = fs.shape
     na = params.shape[0]
-    nup = _round_up(geom.n_cols, bu)
-    nvp = _round_up(geom.n_rows, bv)
+    nup = tune.round_up(geom.n_cols, bu)
+    nvp = tune.round_up(geom.n_rows, bv)
     mag_min, mag_max = _mag_bounds(geom)
     span = bu * geom.pixel_width * math.sqrt(2.0) / (vol.dx * mag_min)
     margin = 2.0 * (math.sqrt(2.0) * vol.dx * mag_max
                     + geom.pixel_width) / (vol.dx * mag_min) + 4.0
-    W = min(int(math.ceil(span + 2 * margin)) + 2, ng)
-    NZW = min(int(math.ceil(bv * geom.pixel_height / (mag_min * vol.dz)))
-              + 6, nz)
+    align = tune.sublane_tile(fs.dtype)
+    W = tune.aligned_window(int(math.ceil(span + 2 * margin)) + 2, ng, align)
     kernel = functools.partial(
-        _fp_cone_kernel, W=W, NZW=NZW,
+        _fp_cone_kernel, W=W,
         u0=float(geom.u_coords()[0]), du=geom.pixel_width,
         v0=float(geom.v_coords()[0]), dv=geom.pixel_height,
         z0c=float(vol.z_coords()[0]), dz=vol.dz,
-        sdd=geom.sdd, dxv=vol.dx, ng=ng, nz=nz, bu=bu, bv=bv, nav=na)
+        sdd=geom.sdd, dxv=vol.dx, ng=ng, nz=nz, bu=bu, bv=bv, nav=na,
+        align=align)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B * na, nup // bu, nvp // bv, nl),
-            in_specs=[pl.BlockSpec((ng, 1, nz),
-                                   lambda a, ub, vb, l, *_: (a // na, l, 0))],
+            in_specs=[pl.BlockSpec((None, ng, nz),
+                                   lambda a, ub, vb, l, *_: (l, a // na, 0))],
             out_specs=pl.BlockSpec((1, bu, bv),
                                    lambda a, ub, vb, l, *_: (a, ub, vb)),
         ),
         # output buffer is the cross-step accumulator: always f32
         out_shape=jax.ShapeDtypeStruct((B * na, nup, nvp), jnp.float32),
-        interpret=_interpret(),
+        interpret=tune._interpret(),
     )(jnp.asarray(params), fs)
     return out.reshape(B, na, nup, nvp)
 
@@ -345,10 +346,11 @@ def fp_cone_sf_pallas(f, geom: CTGeometry, bu: Optional[int] = None,
 # --------------------------------------------------------------------------- #
 def _bp_cone_kernel(params_ref,        # SMEM (n_views, 20)
                     q_ref,             # VMEM (bab, NU, bv) u-major sino stripes
-                    out_ref,           # VMEM (bg, 1, nz) volume tile (z lanes)
+                    out_ref,           # VMEM (bg, nz) volume tile (z lanes)
                     *, Wu: int, u0: float, du: float, v0: float, dv: float,
                     z0c: float, dz: float, sdd: float, dxv: float,
-                    nu: int, nz: int, bg: int, bv: int, bab: int, ngb: int):
+                    nu: int, nz: int, bg: int, bv: int, bab: int, ngb: int,
+                    align: int):
     """One program: accumulate ``bab`` views x ``bv`` detector rows into one
     (bg gathered elements, nz) volume tile — the exact transpose of
     ``_fp_cone_kernel``:
@@ -372,13 +374,11 @@ def _bp_cone_kernel(params_ref,        # SMEM (n_views, 20)
     lif = li.astype(jnp.float32)
     # Batched runs fold the batch into the gathered-output grid axis; the
     # params table stays (n_views, 20) in SMEM shared across samples.
-    gi0 = jax.lax.rem(gall, ngb) * bg
-    gi_abs = gi0.astype(jnp.float32) + jax.lax.broadcasted_iota(
-        jnp.float32, (bg, 1), 0)                             # (bg, 1)
-    v_first = v0 + (vb * bv) * dv
-    elv = v_first - dv / 2.0 + dv * jax.lax.broadcasted_iota(
-        jnp.float32, (bv, 1), 0)                             # (bv, 1)
-    zt = z0c + dz * jax.lax.broadcasted_iota(jnp.float32, (1, nz), 1)
+    gi0 = (jax.lax.rem(gall, ngb) * bg).astype(jnp.float32)
+    gi_abs = gi0 + tune.iota_f32((bg, 1), 0)                 # (bg, 1)
+    elv = v0 + (vb * bv) * dv - dv / 2.0 + dv * tune.iota_f32((bv, 1), 0)
+    zt = z0c + dz * tune.iota_f32((1, nz), 1)                # (1, nz)
+    row = jax.lax.broadcasted_iota(jnp.int32, (bg, 1), 0)
 
     acc = jnp.zeros((bg, nz), jnp.float32)
     for j in range(bab):
@@ -394,72 +394,67 @@ def _bp_cone_kernel(params_ref,        # SMEM (n_views, 20)
             lg = jnp.maximum(Al * gi + l0, _EPS)
             return sdd * qg / lg
 
-        uc_a = uc_of(gi0.astype(jnp.float32))
-        uc_b = uc_of((gi0 + bg - 1).astype(jnp.float32))
-        ustart = jnp.floor(
-            (jnp.minimum(uc_a, uc_b) - u0) / du).astype(jnp.int32) - (
-            Wu - jnp.abs(jnp.ceil((uc_b - uc_a) / du)).astype(jnp.int32)) // 2
-        ustart = jnp.clip(ustart, 0, max(nu - Wu, 0))
+        uc_a = uc_of(gi0)
+        uc_b = uc_of(gi0 + (bg - 1))
+        ustart = tune.window_start((uc_a - u0) / du, (uc_b - u0) / du,
+                                   Wu, nu, align)
 
         qwin = q_ref[j, pl.ds(ustart, Wu), :]                # (Wu, bv)
         t0, t1, t2, t3, h, rt2 = _corner_trapezoid(
             P, gi_abs, q0, l0, lif, sdd, dxv)                # (bg, 1)
-        uk = u0 + (ustart.astype(jnp.float32)
-                   + jax.lax.broadcasted_iota(jnp.float32, (1, Wu), 1)) * du
+        uk = u0 + (ustart + tune.iota_f32((1, Wu), 1)) * du
         el = uk - du / 2.0                                   # (1, Wu)
         wgt = trapezoid_pixel_weight(el, el + du, t0, t1, t2, t3, h)
-        rows = jax.lax.dot_general(precision.cast_like(wgt, qwin),
-                                   qwin,                     # (bg, bv)
-                                   (((1,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
+        rows = precision.mxu_dot(precision.cast_like(wgt, qwin),
+                                 qwin)                       # (bg, bv)
         # Transposed per-element axial resample: every gathered element has
         # its own magnification, so its bv u-contracted detector rows map
         # through an element-specific (bv, nz) overlap matrix onto z lanes.
-        zcols = []
+        # Row g of the tile is contracted alone by masking the others.
+        mag = sdd / jnp.maximum(Al * gi_abs + l0, _EPS)      # (bg, 1)
+        obl = jnp.sqrt(1.0 + (zt * zt) / jnp.maximum(rt2, _EPS))  # (bg, nz)
         for g in range(bg):
-            ell_g = jnp.maximum(Al * gi_abs[g, 0] + l0, _EPS)
-            mag_g = sdd / ell_g
+            mag_g = mag[g:g + 1, :]                          # (1, 1)
             vlo = (zt - dz / 2.0) * mag_g                    # (1, nz)
             vhi = (zt + dz / 2.0) * mag_g
             ov = jnp.maximum(jnp.minimum(vhi, elv + dv)
                              - jnp.maximum(vlo, elv), 0.0) / dv   # (bv, nz)
-            obl = jnp.sqrt(1.0 + (zt * zt) / jnp.maximum(rt2[g, 0], _EPS))
-            Wz = ov * obl                                    # (bv, nz)
-            zcols.append(jax.lax.dot_general(
-                rows[g][None, :], Wz, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))         # (1, nz)
-        acc = acc + jnp.concatenate(zcols, axis=0)
-    precision.store_tile(out_ref, (slice(None), 0, slice(None)), acc)
+            acc = acc + precision.mxu_dot(
+                jnp.where(row == g, rows, 0.0),
+                ov * obl[g:g + 1, :])                        # (bg, nz)
+    precision.store_tile(out_ref, slice(None), acc)
 
 
 def _run_bp_group(q, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
                   bg: int, bv: int, bab: int):
     """q: (B, na_group, n_cols, n_rows) u-major sino slice for this view
     group.  The batch is folded into the gathered-output grid axis (the
-    transpose of the FP's view-axis folding).  Returns the gathered-axis-
-    major volume accumulator (B, NG, NL, nz)."""
+    transpose of the FP's view-axis folding).  Returns this group's volume
+    accumulator (B, nx, ny, nz)."""
     vol = geom.vol
     ng, nl = (vol.nx, vol.ny) if gathered_x else (vol.ny, vol.nx)
     nz = vol.nz
+    align = tune.sublane_tile(q.dtype)
+    q = fp_par._pad_cols(q, align)
     B, na, nu_, nv_ = q.shape
     bab = max(1, min(bab, na))
-    nap = _round_up(na, bab)
+    nap = tune.round_up(na, bab)
     if nap != na:
         params = np.concatenate([params, np.repeat(params[-1:],
                                                    nap - na, 0)], 0)
         q = jnp.pad(q, ((0, 0), (0, nap - na), (0, 0), (0, 0)))
-    nvp = _round_up(nv_, bv)
+    nvp = tune.round_up(nv_, bv)
     q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, nvp - nv_)))
     qs = q.reshape(B * nap, nu_, nvp)
-    ngp = _round_up(ng, bg)
+    ngp = tune.round_up(ng, bg)
     ngb, nab = ngp // bg, nap // bab
-    Wu = _u_window_size_div(geom, bg, nu_)
+    Wu = tune.aligned_window(_u_window_size_div(geom, bg), nu_, align)
     kernel = functools.partial(
         _bp_cone_kernel, Wu=Wu,
         u0=float(geom.u_coords()[0]), du=geom.pixel_width,
         v0=float(geom.v_coords()[0]), dv=geom.pixel_height,
         z0c=float(vol.z_coords()[0]), dz=vol.dz, sdd=geom.sdd, dxv=vol.dx,
-        nu=nu_, nz=nz, bg=bg, bv=bv, bab=bab, ngb=ngb)
+        nu=nu_, nz=nz, bg=bg, bv=bv, bab=bab, ngb=ngb, align=align)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -468,14 +463,14 @@ def _run_bp_group(q, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
             in_specs=[pl.BlockSpec((bab, nu_, bv),
                                    lambda gall, l, vb, ab, *_:
                                    (gall // ngb * nab + ab, 0, vb))],
-            out_specs=pl.BlockSpec((bg, 1, nz),
-                                   lambda gall, l, vb, ab, *_: (gall, l, 0)),
+            out_specs=pl.BlockSpec((None, bg, nz),
+                                   lambda gall, l, vb, ab, *_: (l, gall, 0)),
         ),
         # output buffer is the cross-step accumulator: always f32
-        out_shape=jax.ShapeDtypeStruct((B * ngp, nl, nz), jnp.float32),
-        interpret=_interpret(),
+        out_shape=jax.ShapeDtypeStruct((nl, B * ngp, nz), jnp.float32),
+        interpret=tune._interpret(),
     )(jnp.asarray(params), qs)
-    return out.reshape(B, ngp, nl, nz)[:, :ng]
+    return _volume_from_line_major(out, B, ng, gathered_x)
 
 
 def bp_cone_sf_pallas(sino, geom: CTGeometry, bg: Optional[int] = None,
@@ -512,9 +507,8 @@ def bp_cone_sf_pallas(sino, geom: CTGeometry, bg: Optional[int] = None,
         acc = acc + _run_bp_group(q[:, :nax], px, geom, True,
                                   cfg.bg, cfg.bv, cfg.bab)
     if py.shape[0]:
-        accy = _run_bp_group(q[:, nax:], py, geom, False,
-                             cfg.bg, cfg.bv, cfg.bab)
-        acc = acc + jnp.swapaxes(accy, 1, 2)
+        acc = acc + _run_bp_group(q[:, nax:], py, geom, False,
+                                  cfg.bg, cfg.bv, cfg.bab)
     acc = acc.astype(out_dtype)
     return acc if batched else acc[0]
 

@@ -48,7 +48,7 @@ from repro.kernels import precision, tune
 from repro.kernels.footprint import trapezoid_pixel_weight
 from repro.kernels.fp_cone import (_corner_trapezoid, _mag_bounds,
                                    _u_window_size_div, _view_params_cone)
-from repro.kernels.fp_par import _interpret, _pad_views, _round_up
+from repro.kernels.fp_par import _line_major, _pad_cols, _pad_views
 from repro.kernels.ref import _z_overlap_matrix
 
 _EPS = 1e-9
@@ -64,16 +64,17 @@ def _curved_stretch(geom: CTGeometry) -> float:
     return 1.0 / (math.cos(gmax) ** 2)
 
 
-def _window_size_fan(geom: CTGeometry, bu: int, ng: int) -> int:
+def _window_size_fan(geom: CTGeometry, bu: int) -> int:
     """Static bound on the gathered-axis window covering one u-tile (same
-    construction as the cone kernel, plus the curved-detector stretch)."""
+    construction as the cone kernel, plus the curved-detector stretch),
+    before alignment (``tune.aligned_window``)."""
     du, dx = geom.pixel_width, geom.vol.dx
     mag_min, mag_max = _mag_bounds(geom)
     stretch = _curved_stretch(geom)
     span = bu * du * math.sqrt(2.0) * stretch / (dx * mag_min)
     margin = 2.0 * (math.sqrt(2.0) * dx * mag_max + du) / (dx * mag_min) + 4.0
     w = int(math.ceil(span + 2 * margin)) + 2
-    return min(_round_up(max(w, 8), 8), ng)
+    return max(w, 8)
 
 
 def _fan_trapezoid(P, gi, q0, l0, lif, sdd, dxv, curved):
@@ -92,10 +93,11 @@ def _fan_trapezoid(P, gi, q0, l0, lif, sdd, dxv, curved):
 # Forward kernel
 # --------------------------------------------------------------------------- #
 def _fp_fan_kernel(params_ref,          # SMEM (n_views, 20)
-                   g_ref,               # VMEM (NG, 1, bv) volume line
+                   g_ref,               # VMEM (NG, bv) volume line
                    out_ref,             # VMEM (ba, bu, bv) sino tile
                    *, W: int, u0: float, du: float, sdd: float, dxv: float,
-                   ng: int, bu: int, bv: int, ba: int, curved: bool):
+                   ng: int, bu: int, bv: int, ba: int, curved: bool,
+                   align: int):
     """One program: for ``ba`` consecutive views, contract a (bu, W)
     corner-projection footprint tile against the same (W, bv) volume window
     on the MXU.  Identical structure to ``fp_par._fp_kernel`` — the lane
@@ -134,22 +136,18 @@ def _fp_fan_kernel(params_ref,          # SMEM (n_views, 20)
             return (u * l0 - sdd * q0) / den
 
         g1, g2 = gi_of(u_first), gi_of(u_last)
-        start = jnp.floor(jnp.minimum(g1, g2)).astype(jnp.int32) - (
-            W - jnp.abs(jnp.ceil(g2 - g1)).astype(jnp.int32)) // 2
-        start = jnp.clip(start, 0, max(ng - W, 0))
+        start = tune.window_start(g1, g2, W, ng, align)
 
-        win = g_ref[pl.ds(start, W), 0, :]                     # (W, bv)
-        gi = start.astype(jnp.float32) + jax.lax.broadcasted_iota(
-            jnp.float32, (1, W), 1)                            # (1, W)
+        win = g_ref[pl.ds(start, W), :]                        # (W, bv)
+        gi = start + tune.iota_f32((1, W), 1)                  # (1, W)
         t0, t1, t2, t3, h = _fan_trapezoid(P, gi, q0, l0, lif, sdd, dxv,
                                            curved)
 
-        uk = u_first + du * jax.lax.broadcasted_iota(jnp.float32, (bu, 1), 0)
+        uk = u_first + du * tune.iota_f32((bu, 1), 0)
         el = uk - du / 2.0                                     # (bu, 1)
         wgt = trapezoid_pixel_weight(el, el + du, t0, t1, t2, t3, h)
-        precision.store_tile(out_ref, j, jax.lax.dot_general(
-            precision.cast_like(wgt, win), win, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32))
+        precision.store_tile(out_ref, j, precision.mxu_dot(
+            precision.cast_like(wgt, win), win))
 
 
 def _run_fp_group(g, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
@@ -160,32 +158,33 @@ def _run_fp_group(g, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
         raise ValueError(
             "empty view group reached the fan Pallas kernel; callers "
             "(_fp_core/_bp_core) must skip groups with no views")
-    if not gathered_x:
-        g = jnp.swapaxes(g, 0, 1)
-    ng, nl, nvp = g.shape
+    g = _line_major(g, gathered_x)
+    nl, ng, nvp = g.shape
     na = params.shape[0]
     params, _, ba = _pad_views(params, ba)     # padded views dropped after
     nap = params.shape[0]
-    nup = _round_up(geom.n_cols, bu)
-    W = _window_size_fan(geom, bu, ng)
+    nup = tune.round_up(geom.n_cols, bu)
+    align = tune.sublane_tile(g.dtype)
+    W = tune.aligned_window(_window_size_fan(geom, bu), ng, align)
     grid = (nap // ba, nup // bu, nvp // bv, nl)
     kernel = functools.partial(
         _fp_fan_kernel, W=W, u0=float(geom.u_coords()[0]),
         du=geom.pixel_width, sdd=geom.sdd, dxv=geom.vol.dx, ng=ng,
-        bu=bu, bv=bv, ba=ba, curved=geom.detector_type == "curved")
+        bu=bu, bv=bv, ba=ba, curved=geom.detector_type == "curved",
+        align=align)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
-            in_specs=[pl.BlockSpec((ng, 1, bv),
-                                   lambda ab, ub, vb, l, *_: (0, l, vb))],
+            in_specs=[pl.BlockSpec((None, ng, bv),
+                                   lambda ab, ub, vb, l, *_: (l, 0, vb))],
             out_specs=pl.BlockSpec((ba, bu, bv),
                                    lambda ab, ub, vb, l, *_: (ab, ub, vb)),
         ),
         # f32 cross-step accumulator regardless of the tile dtype.
         out_shape=jax.ShapeDtypeStruct((nap, nup, nvp), jnp.float32),
-        interpret=_interpret(),
+        interpret=tune._interpret(),
     )(jnp.asarray(params), g)
     return out[:na]
 
@@ -194,7 +193,7 @@ def _fp_core(g, geom: CTGeometry, cfg: tune.KernelConfig):
     """g: (nx, ny, NV) lane-packed axial-footprint volume.  Returns the
     u-major sinogram (n_angles, n_cols, NV)."""
     nv_lanes = g.shape[2]
-    nvp = _round_up(nv_lanes, cfg.bv)
+    nvp = tune.round_up(nv_lanes, cfg.bv)
     g = jnp.pad(g, ((0, 0), (0, 0), (0, nvp - nv_lanes)))
     px, py, order = _view_params_cone(geom)
     outs = []
@@ -246,10 +245,10 @@ def fp_fan_sf_pallas(f, geom: CTGeometry, bu: Optional[int] = None,
 # --------------------------------------------------------------------------- #
 def _bp_fan_kernel(params_ref,          # SMEM (n_views, 20)
                    q_ref,               # VMEM (bab, NU, bv) sino stripes
-                   out_ref,             # VMEM (bs*bg, 1, bv) volume tile
+                   out_ref,             # VMEM (bs*bg, bv) volume tile
                    *, Wu: int, u0: float, du: float, sdd: float, dxv: float,
                    nu: int, bg: int, bv: int, bab: int, bs: int,
-                   curved: bool):
+                   curved: bool, align: int):
     """One program: accumulate ``bab`` views into ``bs`` consecutive
     (bg, bv) volume sub-tiles — the exact transpose of ``_fp_fan_kernel``
     (same corner-projected breakpoints, transposed contraction).  Stripe
@@ -280,49 +279,46 @@ def _bp_fan_kernel(params_ref,          # SMEM (n_views, 20)
             return sdd * qg / lg
 
         for sj in range(bs):
-            gi0 = (gb * bs + sj) * bg
-            gi_abs = gi0 + jax.lax.broadcasted_iota(jnp.float32, (bg, 1), 0)
-            uc_a = uc_of(gi0.astype(jnp.float32))
-            uc_b = uc_of((gi0 + bg - 1).astype(jnp.float32))
-            ustart = jnp.floor(
-                (jnp.minimum(uc_a, uc_b) - u0) / du).astype(jnp.int32) - (
-                Wu - jnp.abs(jnp.ceil((uc_b - uc_a) / du)).astype(
-                    jnp.int32)) // 2
-            ustart = jnp.clip(ustart, 0, max(nu - Wu, 0))
+            gi0 = ((gb * bs + sj) * bg).astype(jnp.float32)
+            gi_abs = gi0 + tune.iota_f32((bg, 1), 0)
+            uc_a = uc_of(gi0)
+            uc_b = uc_of(gi0 + (bg - 1))
+            ustart = tune.window_start((uc_a - u0) / du, (uc_b - u0) / du,
+                                       Wu, nu, align)
 
             qwin = q_ref[j, pl.ds(ustart, Wu), :]              # (Wu, bv)
             t0, t1, t2, t3, h = _fan_trapezoid(P, gi_abs, q0, l0, lif, sdd,
                                                dxv, curved)    # (bg, 1)
-            uk = u0 + (ustart.astype(jnp.float32)
-                       + jax.lax.broadcasted_iota(
-                           jnp.float32, (1, Wu), 1)) * du
+            uk = u0 + (ustart + tune.iota_f32((1, Wu), 1)) * du
             el = uk - du / 2.0                                 # (1, Wu)
             wgt = trapezoid_pixel_weight(el, el + du, t0, t1, t2, t3, h)
-            subs[sj] = subs[sj] + jax.lax.dot_general(
-                precision.cast_like(wgt, qwin), qwin, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            subs[sj] = subs[sj] + precision.mxu_dot(
+                precision.cast_like(wgt, qwin), qwin)
     acc = subs[0] if bs == 1 else jnp.concatenate(subs, axis=0)
-    precision.store_tile(out_ref, (slice(None), 0, slice(None)), acc)
+    precision.store_tile(out_ref, slice(None), acc)
 
 
 def _run_bp_group(q, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
                   bg: int, bv: int, bab: int = 1, bs: int = 1):
-    """q: (na_group, NUp, NVp) u-major sino slice for this view group.
-    Returns the gathered-axis-major volume accumulator (NG, NL, NVp)."""
+    """q: (na_group, NU, NVp) u-major sino slice for this view group.
+    Returns the loop-axis-major volume accumulator (NL, NG, NVp)."""
     ng, nl = ((geom.vol.nx, geom.vol.ny) if gathered_x
               else (geom.vol.ny, geom.vol.nx))
+    align = tune.sublane_tile(q.dtype)
+    q = _pad_cols(q, align)
     na, nup, nvp = q.shape
     params, q, bab = _pad_views(params, bab, q)
     nap = params.shape[0]
     bs = max(1, min(bs, max(1, ng // bg)))    # don't block past the axis
     bstr = bg * bs                            # gathered voxels per program
-    ngp = _round_up(ng, bstr)
-    Wu = _u_window_size_div(geom, bg, nup)
+    ngp = tune.round_up(ng, bstr)
+    Wu = tune.aligned_window(_u_window_size_div(geom, bg), nup, align)
     grid = (ngp // bstr, nl, nvp // bv, nap // bab)
     kernel = functools.partial(
         _bp_fan_kernel, Wu=Wu, u0=float(geom.u_coords()[0]),
         du=geom.pixel_width, sdd=geom.sdd, dxv=geom.vol.dx, nu=nup,
-        bg=bg, bv=bv, bab=bab, bs=bs, curved=geom.detector_type == "curved")
+        bg=bg, bv=bv, bab=bab, bs=bs, curved=geom.detector_type == "curved",
+        align=align)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -330,33 +326,32 @@ def _run_bp_group(q, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
             grid=grid,
             in_specs=[pl.BlockSpec((bab, nup, bv),
                                    lambda gb, l, vb, ab, *_: (ab, 0, vb))],
-            out_specs=pl.BlockSpec((bstr, 1, bv),
-                                   lambda gb, l, vb, ab, *_: (gb, l, vb)),
+            out_specs=pl.BlockSpec((None, bstr, bv),
+                                   lambda gb, l, vb, ab, *_: (l, gb, vb)),
         ),
         # f32 cross-step accumulator regardless of the stripe dtype.
-        out_shape=jax.ShapeDtypeStruct((ngp, nl, nvp), jnp.float32),
-        interpret=_interpret(),
+        out_shape=jax.ShapeDtypeStruct((nl, ngp, nvp), jnp.float32),
+        interpret=tune._interpret(),
     )(jnp.asarray(params), q)
-    return out[:ng]
+    return out[:, :ng]
 
 
 def _bp_core(q, geom: CTGeometry, cfg: tune.KernelConfig):
     """q: (n_angles, n_cols, NV) u-major lane-packed sinogram.  Returns the
     transaxial volume accumulator (nx, ny, NV)."""
     nv_lanes = q.shape[2]
-    nvp = _round_up(nv_lanes, cfg.bv)
+    nvp = tune.round_up(nv_lanes, cfg.bv)
     q = jnp.pad(q, ((0, 0), (0, 0), (0, nvp - nv_lanes)))
     px, py, order = _view_params_cone(geom)
     q = q[order]                                               # group-major
     nax = px.shape[0]
     acc = jnp.zeros((geom.vol.nx, geom.vol.ny, nvp), jnp.float32)
-    if nax:
-        acc = acc + _run_bp_group(q[:nax], px, geom, True,
+    if nax:                                     # (ny, nx, NVp): transpose
+        acc = acc + jnp.swapaxes(_run_bp_group(
+            q[:nax], px, geom, True, cfg.bg, cfg.bv, cfg.bab, cfg.bs), 0, 1)
+    if py.shape[0]:                             # (nx, ny, NVp) already
+        acc = acc + _run_bp_group(q[nax:], py, geom, False,
                                   cfg.bg, cfg.bv, cfg.bab, cfg.bs)
-    if py.shape[0]:
-        accy = _run_bp_group(q[nax:], py, geom, False,
-                             cfg.bg, cfg.bv, cfg.bab, cfg.bs)
-        acc = acc + jnp.swapaxes(accy, 0, 1)
     return acc[:, :, :nv_lanes]
 
 

@@ -65,7 +65,9 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.geometry import CTGeometry
 from repro.kernels import precision, ref, tune
 from repro.kernels.footprint import trapezoid_pixel_weight
-from repro.kernels.fp_cone import _corner_trapezoid, _interpret, _round_up
+from repro.kernels.fp_cone import (_corner_trapezoid, _line_major_batch,
+                                   _volume_from_line_major)
+from repro.kernels.fp_par import _pad_cols
 
 _EPS = 1e-9
 _AXIAL_TOL = 1e-4
@@ -221,12 +223,12 @@ def _view_params_modular(geom: CTGeometry, fr=None
 # Forward kernel
 # --------------------------------------------------------------------------- #
 def _fp_modular_kernel(params_ref,     # SMEM (n_views, 24)
-                       f_ref,          # VMEM (NG, 1, NZ) volume line
+                       f_ref,          # VMEM (NG, NZ) volume line plane
                        out_ref,        # VMEM (1, BU, BV) sino tile
-                       *, W: int, NZW: int, u0: float, du: float,
+                       *, W: int, u0: float, du: float,
                        v0: float, dv: float, z0c: float, dz: float,
                        sdd_ref: float, dxv: float, ng: int, nz: int,
-                       bu: int, bv: int, nav: int):
+                       bu: int, bv: int, nav: int, align: int):
     """One program: one view x one (bu, bv) sino tile x one volume line —
     the exact cone FP kernel with the per-view frame read from the prefetch
     row: static ``sdd`` becomes ``sdd_ref`` (transaxial, via the q̂
@@ -260,66 +262,50 @@ def _fp_modular_kernel(params_ref,     # SMEM (n_views, 24)
         return (u * l0 - sdd_ref * q0) / den
 
     g1, g2 = gi_of(u_first), gi_of(u_last)
-    start = jnp.floor(jnp.minimum(g1, g2)).astype(jnp.int32) - (
-        W - jnp.abs(jnp.ceil(g2 - g1)).astype(jnp.int32)) // 2
-    start = jnp.clip(start, 0, max(ng - W, 0))
+    start = tune.window_start(g1, g2, W, ng, align)
 
-    gi = start.astype(jnp.float32) + jax.lax.broadcasted_iota(
-        jnp.float32, (1, W), 1)                              # (1, W)
+    win = f_ref[pl.ds(start, W), :]                          # (W, nz)
+    gi = start + tune.iota_f32((1, W), 1)                    # (1, W)
     t0, t1, t2, t3, h, rt2 = _corner_trapezoid(P, gi, q0, l0, lif,
                                                sdd_ref, dxv)
 
-    uk = u_first + du * jax.lax.broadcasted_iota(jnp.float32, (bu, 1), 0)
+    uk = u_first + du * tune.iota_f32((bu, 1), 0)
     el = uk - du / 2.0
     wu = trapezoid_pixel_weight(el, el + du, t0, t1, t2, t3, h)  # (bu, W)
 
     ell = jnp.maximum(Al * gi + l0, _EPS)
     mag = mags / ell                         # signed per-element magnification
-    v_first = v0 + (vb * bv) * dv
-    v_last = v_first + (bv - 1) * dv
-    vlane = v_first + dv * jax.lax.broadcasted_iota(jnp.float32, (bv, 1), 0)
+    elv = v0 + (vb * bv) * dv - dv / 2.0 + dv * tune.iota_f32((bv, 1), 0)
+    zs = z0c + dz * tune.iota_f32((1, nz), 1) - sz           # (1, nz)
 
     acc = jnp.zeros((bu, bv), jnp.float32)
     for w in range(W):
-        mag_w = mag[0, w]
-        rt2_w = rt2[0, w]
-        inv_mag = ell[0, w] / mags           # sign-safe 1/mag (|mags| > 0)
-        # z window covering this row block at this view's axial map
-        zc_a = (v_first - cv) * inv_mag + sz
-        zc_b = (v_last - cv) * inv_mag + sz
-        z0i = jnp.floor((jnp.minimum(zc_a, zc_b) - z0c) / dz
-                        ).astype(jnp.int32) - 2
-        z0i = jnp.clip(z0i, 0, max(nz - NZW, 0))
-        zt = z0c + (z0i.astype(jnp.float32)
-                    + jax.lax.broadcasted_iota(jnp.float32, (1, NZW), 1)) * dz
-        va = (zt - dz / 2.0 - sz) * mag_w + cv           # (1, NZW)
-        vb_ = (zt + dz / 2.0 - sz) * mag_w + cv
+        # The axial overlap spans the whole z line (lanes), as in the
+        # exact cone FP kernel.
+        mag_w = mag[:, w:w + 1]                              # (1, 1)
+        va = (zs - dz / 2.0) * mag_w + cv                    # (1, nz)
+        vb_ = (zs + dz / 2.0) * mag_w + cv
         vlo = jnp.minimum(va, vb_)           # sorted: mag may be negative
         vhi = jnp.maximum(va, vb_)
-        elv = vlane - dv / 2.0                               # (bv, 1)
         ov = jnp.maximum(jnp.minimum(vhi, elv + dv)
-                         - jnp.maximum(vlo, elv), 0.0) / dv  # (bv, NZW)
-        obl = jnp.sqrt(1.0 + ((zt - sz) * (zt - sz))
-                       / jnp.maximum(rt2_w, 1e-9))
-        Wz = ov * obl                                        # (bv, NZW)
-        fwin = f_ref[start + w, 0, pl.ds(z0i, NZW)]          # (NZW,)
-        rv = jax.lax.dot_general(precision.cast_like(Wz, fwin), fwin[:, None],
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)[:, 0]
-        acc = acc + wu[:, w][:, None] * rv[None, :]
+                         - jnp.maximum(vlo, elv), 0.0) / dv  # (bv, nz)
+        obl = jnp.sqrt(1.0 + (zs * zs) / jnp.maximum(rt2[:, w:w + 1], 1e-9))
+        fw = win[w:w + 1, :]                                 # (1, nz)
+        rv = precision.mxu_dot(fw, precision.cast_like(ov * obl, fw),
+                               ((1,), (1,)))                 # (1, bv)
+        acc = acc + wu[:, w:w + 1] * rv
     precision.store_tile(out_ref, 0, acc)
 
 
-def _fp_window_sizes(geom: CTGeometry, bu: int, bv: int, ng: int, nz: int,
-                     mag_min: float, mag_max: float) -> Tuple[int, int]:
+def _fp_window_size(geom: CTGeometry, bu: int, mag_min: float,
+                    mag_max: float) -> int:
+    """Gathered-axis window covering one u-tile, before alignment."""
     vol = geom.vol
-    du, dv = geom.pixel_width, geom.pixel_height
+    du = geom.pixel_width
     span = bu * du * math.sqrt(2.0) / (vol.dx * mag_min)
     margin = 2.0 * (math.sqrt(2.0) * vol.dx * mag_max + du) \
         / (vol.dx * mag_min) + 4.0
-    W = min(int(math.ceil(span + 2 * margin)) + 2, ng)
-    NZW = min(int(math.ceil(bv * dv / (mag_min * vol.dz))) + 6, nz)
-    return W, NZW
+    return int(math.ceil(span + 2 * margin)) + 2
 
 
 def _run_fp_group(fb, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
@@ -331,33 +317,35 @@ def _run_fp_group(fb, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
     if params.shape[0] == 0:
         return None
     vol = geom.vol
-    if not gathered_x:
-        fb = jnp.swapaxes(fb, 1, 2)
-    B, ng, nl, nz = fb.shape
-    fs = fb.reshape(B * ng, nl, nz)
+    B = fb.shape[0]
+    fs, ng = _line_major_batch(fb, gathered_x)
+    nl, _, nz = fs.shape
     na = params.shape[0]
-    nup = _round_up(geom.n_cols, bu)
-    nvp = _round_up(geom.n_rows, bv)
-    W, NZW = _fp_window_sizes(geom, bu, bv, ng, nz, mag_min, mag_max)
+    nup = tune.round_up(geom.n_cols, bu)
+    nvp = tune.round_up(geom.n_rows, bv)
+    align = tune.sublane_tile(fs.dtype)
+    W = tune.aligned_window(_fp_window_size(geom, bu, mag_min, mag_max),
+                            ng, align)
     kernel = functools.partial(
-        _fp_modular_kernel, W=W, NZW=NZW,
+        _fp_modular_kernel, W=W,
         u0=float(geom.u_coords()[0]), du=geom.pixel_width,
         v0=float(geom.v_coords()[0]), dv=geom.pixel_height,
         z0c=float(vol.z_coords()[0]), dz=vol.dz,
-        sdd_ref=sdd_ref, dxv=vol.dx, ng=ng, nz=nz, bu=bu, bv=bv, nav=na)
+        sdd_ref=sdd_ref, dxv=vol.dx, ng=ng, nz=nz, bu=bu, bv=bv, nav=na,
+        align=align)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B * na, nup // bu, nvp // bv, nl),
-            in_specs=[pl.BlockSpec((ng, 1, nz),
-                                   lambda a, ub, vb, l, *_: (a // na, l, 0))],
+            in_specs=[pl.BlockSpec((None, ng, nz),
+                                   lambda a, ub, vb, l, *_: (l, a // na, 0))],
             out_specs=pl.BlockSpec((1, bu, bv),
                                    lambda a, ub, vb, l, *_: (a, ub, vb)),
         ),
         # output buffer is the cross-step accumulator: always f32
         out_shape=jax.ShapeDtypeStruct((B * na, nup, nvp), jnp.float32),
-        interpret=_interpret(),
+        interpret=tune._interpret(),
     )(jnp.asarray(params), fs)
     return out.reshape(B, na, nup, nvp)
 
@@ -406,11 +394,11 @@ def fp_modular_sf_pallas(f, geom: CTGeometry, bu: Optional[int] = None,
 # --------------------------------------------------------------------------- #
 def _bp_modular_kernel(params_ref,     # SMEM (n_views, 24)
                        q_ref,          # VMEM (bab, NU, bv) u-major sino stripes
-                       out_ref,        # VMEM (bg, 1, nz) volume tile (z lanes)
+                       out_ref,        # VMEM (bg, nz) volume tile (z lanes)
                        *, Wu: int, u0: float, du: float, v0: float, dv: float,
                        z0c: float, dz: float, sdd_ref: float, dxv: float,
                        nu: int, nz: int, bg: int, bv: int, bab: int,
-                       ngb: int):
+                       ngb: int, align: int):
     """Exact transpose of ``_fp_modular_kernel`` — the cone BP kernel with
     the per-view frame read from the 24-float prefetch row: the same
     corner-projected breakpoints contracted in the transposed direction,
@@ -427,13 +415,12 @@ def _bp_modular_kernel(params_ref,     # SMEM (n_views, 24)
         out_ref[...] = jnp.zeros_like(out_ref)
 
     lif = li.astype(jnp.float32)
-    gi0 = jax.lax.rem(gall, ngb) * bg        # batch folded into gathered axis
-    gi_abs = gi0.astype(jnp.float32) + jax.lax.broadcasted_iota(
-        jnp.float32, (bg, 1), 0)                             # (bg, 1)
-    v_first = v0 + (vb * bv) * dv
-    elv = v_first - dv / 2.0 + dv * jax.lax.broadcasted_iota(
-        jnp.float32, (bv, 1), 0)                             # (bv, 1)
-    zt = z0c + dz * jax.lax.broadcasted_iota(jnp.float32, (1, nz), 1)
+    # batch folded into the gathered axis
+    gi0 = (jax.lax.rem(gall, ngb) * bg).astype(jnp.float32)
+    gi_abs = gi0 + tune.iota_f32((bg, 1), 0)                 # (bg, 1)
+    elv = v0 + (vb * bv) * dv - dv / 2.0 + dv * tune.iota_f32((bv, 1), 0)
+    zt = z0c + dz * tune.iota_f32((1, nz), 1)                # (1, nz)
+    row = jax.lax.broadcasted_iota(jnp.int32, (bg, 1), 0)
 
     acc = jnp.zeros((bg, nz), jnp.float32)
     for j in range(bab):
@@ -450,80 +437,78 @@ def _bp_modular_kernel(params_ref,     # SMEM (n_views, 24)
             lg = jnp.maximum(Al * gi + l0, _EPS)
             return sdd_ref * qg / lg
 
-        uc_a = uc_of(gi0.astype(jnp.float32))
-        uc_b = uc_of((gi0 + bg - 1).astype(jnp.float32))
-        ustart = jnp.floor(
-            (jnp.minimum(uc_a, uc_b) - u0) / du).astype(jnp.int32) - (
-            Wu - jnp.abs(jnp.ceil((uc_b - uc_a) / du)).astype(jnp.int32)) // 2
-        ustart = jnp.clip(ustart, 0, max(nu - Wu, 0))
+        uc_a = uc_of(gi0)
+        uc_b = uc_of(gi0 + (bg - 1))
+        ustart = tune.window_start((uc_a - u0) / du, (uc_b - u0) / du,
+                                   Wu, nu, align)
 
         qwin = q_ref[j, pl.ds(ustart, Wu), :]                # (Wu, bv)
         t0, t1, t2, t3, h, rt2 = _corner_trapezoid(
             P, gi_abs, q0, l0, lif, sdd_ref, dxv)            # (bg, 1)
-        uk = u0 + (ustart.astype(jnp.float32)
-                   + jax.lax.broadcasted_iota(jnp.float32, (1, Wu), 1)) * du
+        uk = u0 + (ustart + tune.iota_f32((1, Wu), 1)) * du
         el = uk - du / 2.0                                   # (1, Wu)
         wgt = trapezoid_pixel_weight(el, el + du, t0, t1, t2, t3, h)
-        rows = jax.lax.dot_general(precision.cast_like(wgt, qwin),
-                                   qwin,                     # (bg, bv)
-                                   (((1,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-        zcols = []
+        rows = precision.mxu_dot(precision.cast_like(wgt, qwin),
+                                 qwin)                       # (bg, bv)
+        # Row g of the tile is contracted alone by masking the others (see
+        # the exact cone BP kernel).
+        mag = mags / jnp.maximum(Al * gi_abs + l0, _EPS)     # (bg, 1)
+        zs = zt - sz                                         # (1, nz)
+        obl = jnp.sqrt(1.0 + (zs * zs) / jnp.maximum(rt2, _EPS))  # (bg, nz)
         for g in range(bg):
-            ell_g = jnp.maximum(Al * gi_abs[g, 0] + l0, _EPS)
-            mag_g = mags / ell_g
-            va = (zt - dz / 2.0 - sz) * mag_g + cv           # (1, nz)
-            vb_ = (zt + dz / 2.0 - sz) * mag_g + cv
+            mag_g = mag[g:g + 1, :]                          # (1, 1)
+            va = (zs - dz / 2.0) * mag_g + cv                # (1, nz)
+            vb_ = (zs + dz / 2.0) * mag_g + cv
             vlo = jnp.minimum(va, vb_)
             vhi = jnp.maximum(va, vb_)
             ov = jnp.maximum(jnp.minimum(vhi, elv + dv)
                              - jnp.maximum(vlo, elv), 0.0) / dv   # (bv, nz)
-            obl = jnp.sqrt(1.0 + ((zt - sz) * (zt - sz))
-                           / jnp.maximum(rt2[g, 0], _EPS))
-            Wz = ov * obl                                    # (bv, nz)
-            zcols.append(jax.lax.dot_general(
-                rows[g][None, :], Wz, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))         # (1, nz)
-        acc = acc + jnp.concatenate(zcols, axis=0)
-    precision.store_tile(out_ref, (slice(None), 0, slice(None)), acc)
+            acc = acc + precision.mxu_dot(
+                jnp.where(row == g, rows, 0.0),
+                ov * obl[g:g + 1, :])                        # (bg, nz)
+    precision.store_tile(out_ref, slice(None), acc)
 
 
-def _u_window_size_modular(geom: CTGeometry, bg: int, nu: int,
-                           mag_max: float) -> int:
+def _u_window_size_modular(geom: CTGeometry, bg: int, mag_max: float) -> int:
+    """Detector-column window covering one bg voxel tile, before
+    alignment."""
     du, dx = geom.pixel_width, geom.vol.dx
     span = bg * dx * math.sqrt(2.0) * mag_max / du
     margin = 2.0 * math.sqrt(2.0) * dx * mag_max / du + 4.0
-    w = int(math.ceil(span + 2 * margin)) + 2
-    return min(_round_up(max(w, 8), 8), nu)
+    return max(int(math.ceil(span + 2 * margin)) + 2, 8)
 
 
 def _run_bp_group(q, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
                   bg: int, bv: int, bab: int, sdd_ref: float, mag_max: float):
     """q: (B, na_group, n_cols, n_rows) u-major sino slice.  Batch folded
     into the gathered-output grid axis (the transpose of the FP's view-axis
-    folding).  Returns (B, NG, NL, nz)."""
+    folding).  Returns this group's volume accumulator (B, nx, ny, nz)."""
     vol = geom.vol
     ng, nl = (vol.nx, vol.ny) if gathered_x else (vol.ny, vol.nx)
     nz = vol.nz
+    align = tune.sublane_tile(q.dtype)
+    q = _pad_cols(q, align)
     B, na, nu_, nv_ = q.shape
     bab = max(1, min(bab, na))
-    nap = _round_up(na, bab)
+    nap = tune.round_up(na, bab)
     if nap != na:
         params = np.concatenate([params, np.repeat(params[-1:],
                                                    nap - na, 0)], 0)
         q = jnp.pad(q, ((0, 0), (0, nap - na), (0, 0), (0, 0)))
-    nvp = _round_up(nv_, bv)
+    nvp = tune.round_up(nv_, bv)
     q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, nvp - nv_)))
     qs = q.reshape(B * nap, nu_, nvp)
-    ngp = _round_up(ng, bg)
+    ngp = tune.round_up(ng, bg)
     ngb, nab = ngp // bg, nap // bab
-    Wu = _u_window_size_modular(geom, bg, nu_, mag_max)
+    Wu = tune.aligned_window(_u_window_size_modular(geom, bg, mag_max),
+                             nu_, align)
     kernel = functools.partial(
         _bp_modular_kernel, Wu=Wu,
         u0=float(geom.u_coords()[0]), du=geom.pixel_width,
         v0=float(geom.v_coords()[0]), dv=geom.pixel_height,
         z0c=float(vol.z_coords()[0]), dz=vol.dz, sdd_ref=sdd_ref,
-        dxv=vol.dx, nu=nu_, nz=nz, bg=bg, bv=bv, bab=bab, ngb=ngb)
+        dxv=vol.dx, nu=nu_, nz=nz, bg=bg, bv=bv, bab=bab, ngb=ngb,
+        align=align)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -532,14 +517,14 @@ def _run_bp_group(q, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
             in_specs=[pl.BlockSpec((bab, nu_, bv),
                                    lambda gall, l, vb, ab, *_:
                                    (gall // ngb * nab + ab, 0, vb))],
-            out_specs=pl.BlockSpec((bg, 1, nz),
-                                   lambda gall, l, vb, ab, *_: (gall, l, 0)),
+            out_specs=pl.BlockSpec((None, bg, nz),
+                                   lambda gall, l, vb, ab, *_: (l, gall, 0)),
         ),
         # output buffer is the cross-step accumulator: always f32
-        out_shape=jax.ShapeDtypeStruct((B * ngp, nl, nz), jnp.float32),
-        interpret=_interpret(),
+        out_shape=jax.ShapeDtypeStruct((nl, B * ngp, nz), jnp.float32),
+        interpret=tune._interpret(),
     )(jnp.asarray(params), qs)
-    return out.reshape(B, ngp, nl, nz)[:, :ng]
+    return _volume_from_line_major(out, B, ng, gathered_x)
 
 
 def bp_modular_sf_pallas(sino, geom: CTGeometry, bg: Optional[int] = None,
@@ -574,9 +559,8 @@ def bp_modular_sf_pallas(sino, geom: CTGeometry, bg: Optional[int] = None,
         acc = acc + _run_bp_group(q[:, :nax], px, geom, True,
                                   cfg.bg, cfg.bv, cfg.bab, sdd_ref, mag_max)
     if py.shape[0]:
-        accy = _run_bp_group(q[:, nax:], py, geom, False,
-                             cfg.bg, cfg.bv, cfg.bab, sdd_ref, mag_max)
-        acc = acc + jnp.swapaxes(accy, 1, 2)
+        acc = acc + _run_bp_group(q[:, nax:], py, geom, False,
+                                  cfg.bg, cfg.bv, cfg.bab, sdd_ref, mag_max)
     acc = acc.astype(out_dtype)
     return acc if batched else acc[0]
 

@@ -53,14 +53,6 @@ from repro.kernels.footprint import trapezoid_pixel_weight
 from repro.kernels.ref import _z_overlap_matrix
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def _round_up(n: int, m: int) -> int:
-    return ((n + m - 1) // m) * m
-
-
 # --------------------------------------------------------------------------- #
 # Per-view affine coefficients (static, numpy)
 # --------------------------------------------------------------------------- #
@@ -96,8 +88,7 @@ def _window_size(geom: CTGeometry, bu: int) -> int:
     du, dx = geom.pixel_width, geom.vol.dx
     span = bu * du * math.sqrt(2.0) / dx
     margin = 2.0 * (math.sqrt(2.0) / 2.0 * dx + du) / dx + 2.0
-    w = int(math.ceil(span + 2 * margin)) + 2
-    return _round_up(max(w, 8), 8)
+    return max(int(math.ceil(span + 2 * margin)) + 2, 8)
 
 
 def _pad_views(params: np.ndarray, block: int, q=None):
@@ -120,10 +111,10 @@ def _pad_views(params: np.ndarray, block: int, q=None):
 # Forward kernel
 # --------------------------------------------------------------------------- #
 def _fp_kernel(params_ref,            # SMEM (n_views, 6)
-               g_ref,                 # VMEM (NG, 1, bv) volume line
+               g_ref,                 # VMEM (NG, bv) volume line
                out_ref,               # VMEM (ba, bu, bv) sino tile
                *, W: int, u0: float, du: float, ng: int, bu: int, bv: int,
-               ba: int):
+               ba: int, align: int):
     """One program: for ``ba`` consecutive views, contract a (bu, W) footprint
     tile against the same (W, bv) volume window on the MXU.
 
@@ -153,21 +144,17 @@ def _fp_kernel(params_ref,            # SMEM (n_views, 6)
 
         gi_a = (u_first - R - Q * lif) / P
         gi_b = (u_last - R - Q * lif) / P
-        start = jnp.floor(jnp.minimum(gi_a, gi_b)).astype(jnp.int32) - (
-            W - jnp.abs(jnp.ceil(gi_b - gi_a)).astype(jnp.int32)) // 2
-        start = jnp.clip(start, 0, max(ng - W, 0))
+        start = tune.window_start(gi_a, gi_b, W, ng, align)
 
-        win = g_ref[pl.ds(start, W), 0, :]                 # (W, bv)
-        gi_abs = start.astype(jnp.float32) + jax.lax.broadcasted_iota(
-            jnp.float32, (1, W), 1)                        # (1, W)
+        win = g_ref[pl.ds(start, W), :]                    # (W, bv)
+        gi_abs = start + tune.iota_f32((1, W), 1)          # (1, W)
         uc = P * gi_abs + Q * lif + R                      # (1, W)
-        uk = u_first + du * jax.lax.broadcasted_iota(jnp.float32, (bu, 1), 0)
+        uk = u_first + du * tune.iota_f32((bu, 1), 0)
         el = uk - du / 2.0                                 # (bu, 1)
         wgt = trapezoid_pixel_weight(el, el + du,
                                      uc - hs, uc - hd, uc + hd, uc + hs, h)
-        precision.store_tile(out_ref, j, jax.lax.dot_general(
-            precision.cast_like(wgt, win), win, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32))
+        precision.store_tile(out_ref, j, precision.mxu_dot(
+            precision.cast_like(wgt, win), win))
 
 
 def _run_fp_group(g, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
@@ -179,41 +166,53 @@ def _run_fp_group(g, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
         raise ValueError(
             "empty view group reached the parallel Pallas kernel; callers "
             "(_fp_core/_bp_core) must skip groups with no views")
-    if not gathered_x:
-        g = jnp.swapaxes(g, 0, 1)
-    ng, nl, nvp = g.shape
+    g = _line_major(g, gathered_x)
+    nl, ng, nvp = g.shape
     na = params.shape[0]
     params, _, ba = _pad_views(params, ba)   # padded views dropped after
     nap = params.shape[0]
-    nup = _round_up(geom.n_cols, bu)
-    W = min(_window_size(geom, bu), ng)
+    nup = tune.round_up(geom.n_cols, bu)
+    align = tune.sublane_tile(g.dtype)
+    W = tune.aligned_window(_window_size(geom, bu), ng, align)
     u0 = float(geom.u_coords()[0])
     grid = (nap // ba, nup // bu, nvp // bv, nl)
     kernel = functools.partial(_fp_kernel, W=W, u0=u0, du=geom.pixel_width,
-                               ng=ng, bu=bu, bv=bv, ba=ba)
+                               ng=ng, bu=bu, bv=bv, ba=ba, align=align)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
-            in_specs=[pl.BlockSpec((ng, 1, bv),
-                                   lambda ab, ub, vb, l, *_: (0, l, vb))],
+            in_specs=[pl.BlockSpec((None, ng, bv),
+                                   lambda ab, ub, vb, l, *_: (l, 0, vb))],
             out_specs=pl.BlockSpec((ba, bu, bv),
                                    lambda ab, ub, vb, l, *_: (ab, ub, vb)),
         ),
         # The output buffer is the cross-step accumulator: always f32
         # regardless of the tile dtype (the caller restores its dtype once).
         out_shape=jax.ShapeDtypeStruct((nap, nup, nvp), jnp.float32),
-        interpret=_interpret(),
+        interpret=tune._interpret(),
     )(jnp.asarray(params), g)
     return out[:na]
+
+
+def _line_major(g, gathered_x: bool):
+    """(nx, ny, NV) -> (NL, NG, NV): loop axis first, so a kernel block is
+    one whole (NG, bv) line plane — Mosaic tiles the last two block dims
+    and refuses a 1 in the second-minor place.  The gathered axis is padded
+    with zeros to the sublane tile of its dtype."""
+    if gathered_x:
+        g = jnp.swapaxes(g, 0, 1)
+    ng = g.shape[1]
+    ngp = tune.round_up(ng, tune.sublane_tile(g.dtype))
+    return jnp.pad(g, ((0, 0), (0, ngp - ng), (0, 0)))
 
 
 def _fp_core(g, geom: CTGeometry, cfg: tune.KernelConfig):
     """g: (nx, ny, NV) lane-packed axial-footprint volume (NV lanes carry
     batch x n_rows).  Returns the u-major sinogram (n_angles, n_cols, NV)."""
     nv_lanes = g.shape[2]
-    nvp = _round_up(nv_lanes, cfg.bv)
+    nvp = tune.round_up(nv_lanes, cfg.bv)
     g = jnp.pad(g, ((0, 0), (0, 0), (0, nvp - nv_lanes)))
     px, py, order = _view_params(geom)
     outs = []
@@ -263,9 +262,9 @@ def fp_parallel_sf_pallas(f, geom: CTGeometry, bu: Optional[int] = None,
 # --------------------------------------------------------------------------- #
 def _bp_kernel(params_ref,            # SMEM (n_views, 6)
                q_ref,                 # VMEM (bab, NU, bv) sino stripes (u-major)
-               out_ref,               # VMEM (bs*bg, 1, bv) volume tile
+               out_ref,               # VMEM (bs*bg, bv) volume tile
                *, Wu: int, u0: float, du: float, nu: int, bg: int, bv: int,
-               bab: int, bs: int):
+               bab: int, bs: int, align: int):
     """One program: accumulate ``bab`` views into ``bs`` consecutive
     (bg, bv) volume sub-tiles.
 
@@ -298,50 +297,55 @@ def _bp_kernel(params_ref,            # SMEM (n_views, 6)
         h = params_ref[a, 5]
 
         for sj in range(bs):
-            gi0 = (gb * bs + sj) * bg
-            gi_abs = gi0 + jax.lax.broadcasted_iota(jnp.float32, (bg, 1), 0)
+            gi0 = ((gb * bs + sj) * bg).astype(jnp.float32)
+            gi_abs = gi0 + tune.iota_f32((bg, 1), 0)
             uc_a = P * gi0 + Q * lif + R
             uc_b = P * (gi0 + bg - 1) + Q * lif + R
-            ustart = jnp.floor(
-                (jnp.minimum(uc_a, uc_b) - u0) / du).astype(jnp.int32) - (
-                Wu - jnp.abs(jnp.ceil((uc_b - uc_a) / du)).astype(
-                    jnp.int32)) // 2
-            ustart = jnp.clip(ustart, 0, max(nu - Wu, 0))
+            ustart = tune.window_start((uc_a - u0) / du, (uc_b - u0) / du,
+                                       Wu, nu, align)
 
             qwin = q_ref[j, pl.ds(ustart, Wu), :]          # (Wu, bv)
             uc = P * gi_abs + Q * lif + R                  # (bg, 1)
-            uk = u0 + (ustart.astype(jnp.float32)
-                       + jax.lax.broadcasted_iota(
-                           jnp.float32, (1, Wu), 1)) * du
+            uk = u0 + (ustart + tune.iota_f32((1, Wu), 1)) * du
             el = uk - du / 2.0                             # (1, Wu)
             wgt = trapezoid_pixel_weight(el, el + du,
                                          uc - hs, uc - hd, uc + hd, uc + hs,
                                          h)
-            subs[sj] = subs[sj] + jax.lax.dot_general(
-                precision.cast_like(wgt, qwin), qwin, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            subs[sj] = subs[sj] + precision.mxu_dot(
+                precision.cast_like(wgt, qwin), qwin)
     acc = subs[0] if bs == 1 else jnp.concatenate(subs, axis=0)
-    precision.store_tile(out_ref, (slice(None), 0, slice(None)), acc)
+    precision.store_tile(out_ref, slice(None), acc)
+
+
+def _pad_cols(q, align: int):
+    """Zero-pad the detector-column axis (axis -2) of a u-major sinogram
+    to a multiple of ``align``, so aligned u-windows stay inside it."""
+    nu = q.shape[-2]
+    pad = [(0, 0)] * q.ndim
+    pad[-2] = (0, tune.round_up(nu, align) - nu)
+    return jnp.pad(q, pad)
 
 
 def _run_bp_group(q, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
                   bg: int, bv: int, bab: int = 1, bs: int = 1):
-    """q: (na_group, NUp, NVp) u-major sino slice for this view group.
-    Returns the gathered-axis-major volume accumulator (NG, NL, NVp)."""
+    """q: (na_group, NU, NVp) u-major sino slice for this view group.
+    Returns the loop-axis-major volume accumulator (NL, NG, NVp)."""
     ng, nl = ((geom.vol.nx, geom.vol.ny) if gathered_x
               else (geom.vol.ny, geom.vol.nx))
+    align = tune.sublane_tile(q.dtype)
+    q = _pad_cols(q, align)
     na, nup, nvp = q.shape
     params, q, bab = _pad_views(params, bab, q)
     nap = params.shape[0]
     bs = max(1, min(bs, max(1, ng // bg)))    # don't block past the axis
     bstr = bg * bs                            # gathered voxels per program
-    ngp = _round_up(ng, bstr)
+    ngp = tune.round_up(ng, bstr)
     du, dx = geom.pixel_width, geom.vol.dx
-    Wu = min(_round_up(int(math.ceil(bg * dx / du)) + 8, 8), nup)
+    Wu = tune.aligned_window(int(math.ceil(bg * dx / du)) + 8, nup, align)
     u0 = float(geom.u_coords()[0])
     grid = (ngp // bstr, nl, nvp // bv, nap // bab)
     kernel = functools.partial(_bp_kernel, Wu=Wu, u0=u0, du=du, nu=nup,
-                               bg=bg, bv=bv, bab=bab, bs=bs)
+                               bg=bg, bv=bv, bab=bab, bs=bs, align=align)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -349,14 +353,14 @@ def _run_bp_group(q, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
             grid=grid,
             in_specs=[pl.BlockSpec((bab, nup, bv),
                                    lambda gb, l, vb, ab, *_: (ab, 0, vb))],
-            out_specs=pl.BlockSpec((bstr, 1, bv),
-                                   lambda gb, l, vb, ab, *_: (gb, l, vb)),
+            out_specs=pl.BlockSpec((None, bstr, bv),
+                                   lambda gb, l, vb, ab, *_: (l, gb, vb)),
         ),
         # f32 cross-step accumulator regardless of the stripe dtype.
-        out_shape=jax.ShapeDtypeStruct((ngp, nl, nvp), jnp.float32),
-        interpret=_interpret(),
+        out_shape=jax.ShapeDtypeStruct((nl, ngp, nvp), jnp.float32),
+        interpret=tune._interpret(),
     )(jnp.asarray(params), q)
-    return out[:ng]
+    return out[:, :ng]
 
 
 def _bp_core(q, geom: CTGeometry, cfg: tune.KernelConfig):
@@ -364,19 +368,18 @@ def _bp_core(q, geom: CTGeometry, cfg: tune.KernelConfig):
     transaxial volume accumulator (nx, ny, NV) — axial transpose not yet
     applied."""
     nv_lanes = q.shape[2]
-    nvp = _round_up(nv_lanes, cfg.bv)
+    nvp = tune.round_up(nv_lanes, cfg.bv)
     q = jnp.pad(q, ((0, 0), (0, 0), (0, nvp - nv_lanes)))
     px, py, order = _view_params(geom)
     q = q[order]                                           # group-major order
     nax = px.shape[0]
     acc = jnp.zeros((geom.vol.nx, geom.vol.ny, nvp), jnp.float32)
-    if nax:
-        acc = acc + _run_bp_group(q[:nax], px, geom, True,
+    if nax:                                 # (ny, nx, NVp): transpose back
+        acc = acc + jnp.swapaxes(_run_bp_group(
+            q[:nax], px, geom, True, cfg.bg, cfg.bv, cfg.bab, cfg.bs), 0, 1)
+    if py.shape[0]:                         # (nx, ny, NVp) already
+        acc = acc + _run_bp_group(q[nax:], py, geom, False,
                                   cfg.bg, cfg.bv, cfg.bab, cfg.bs)
-    if py.shape[0]:
-        accy = _run_bp_group(q[nax:], py, geom, False,
-                             cfg.bg, cfg.bv, cfg.bab, cfg.bs)
-        acc = acc + jnp.swapaxes(accy, 0, 1)
     return acc[:, :, :nv_lanes]
 
 

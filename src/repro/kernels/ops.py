@@ -156,7 +156,9 @@ def _make_pair(raw_fp: Callable, raw_bp: Callable) -> Tuple[Callable, Callable]:
 
 def _use_pallas(geom: CTGeometry, model: str, backend: str) -> bool:
     # "auto": use the Pallas kernels on TPU; the pure-jnp path elsewhere
-    # (interpret-mode Pallas is for correctness tests, not production CPU use).
+    # (interpret-mode Pallas is for correctness tests, not production CPU use)
+    # and for geometries the registered kernel does not support.  Callers
+    # that must run on the kernels check :func:`resolve_backend`.
     if backend == "pallas":
         return True
     entry = _KERNEL_TABLE.get((geom.geom_type, model))
@@ -201,6 +203,22 @@ def resolve_mode(geom, model: str = "sf", backend: str = "auto",
         geom, model, backend, mode = (geom.geom, geom.model, geom.backend,
                                       geom.mode)
     return _resolve_mode(geom, model, mode, _use_pallas(geom, model, backend))
+
+
+def resolve_backend(spec_or_geom, model: str = "sf",
+                    backend: str = "auto") -> str:
+    """The backend ("pallas" | "ref") that ``forward_project`` /
+    ``back_project`` would dispatch: ``backend="auto"`` resolves to "ref"
+    off the TPU and for geometries outside a kernel's ``supports`` gate, so
+    a caller that must run on the chip's kernels asserts on this.  Accepts
+    a ProjectorSpec or a geometry (a read-only probe, like
+    :func:`resolve_mode`)."""
+    if isinstance(spec_or_geom, ProjectorSpec):
+        geom, model, backend = (spec_or_geom.geom, spec_or_geom.model,
+                                spec_or_geom.backend)
+    else:
+        geom = spec_or_geom
+    return "pallas" if _use_pallas(geom, model, backend) else "ref"
 
 
 def _build(geom: CTGeometry, model: str, backend: str,

@@ -21,6 +21,7 @@ input's dtype" (f32 in -> f32 tiles, bf16 in -> bf16 tiles + f32 accum).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 # bfloat16 has an 8-bit significand (incl. the hidden bit): one quantization
@@ -86,6 +87,19 @@ def cast_like(w, tile):
     the MXU contraction runs operand-matched (bf16 x bf16 with
     ``preferred_element_type=f32`` accumulation).  No-op on the f32 path."""
     return w.astype(tile.dtype)
+
+
+def mxu_dot(a, b, contract=((1,), (0,))):
+    """In-kernel MXU contraction of two same-dtype tiles with f32
+    accumulation.  The precision is explicit: full f32 for f32 tiles (what
+    the f32 oracles are matched against), native for bf16 ones.  Left
+    implicit, an enclosing ``jax.default_matmul_precision("highest")``
+    would ask Mosaic for an f32 contraction of bf16 operands, which it
+    refuses."""
+    prec = (jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+    return jax.lax.dot_general(a, b, (contract, ((), ())), precision=prec,
+                               preferred_element_type=jnp.float32)
 
 
 def store_tile(out_ref, idx, acc):

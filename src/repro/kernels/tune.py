@@ -52,10 +52,12 @@ import json
 import os
 import pathlib
 import time
+import warnings
 from typing import Dict, Iterable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from repro.core.geometry import CTGeometry
 
@@ -110,10 +112,6 @@ class KernelConfig:
 def _bucket(n: int) -> int:
     """Round up to the next power of two (coarse size bucketing)."""
     return 1 << max(0, int(n - 1).bit_length())
-
-
-def _round_up8(n: int) -> int:
-    return ((n + _SUBLANE - 1) // _SUBLANE) * _SUBLANE
 
 
 def shape_class(geom: CTGeometry, batch: int = 1,
@@ -251,6 +249,57 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _interpret() -> bool:
+    """Pallas interpret mode: everywhere but the TPU.  The one switch every
+    kernel module reads (as ``tune._interpret()``), so a test that compiles
+    for a described chip patches this module and nothing else."""
+    return not _on_tpu()
+
+
+# --------------------------------------------------------------------------- #
+# Layout helpers shared by the kernels (Mosaic tiling rules)
+# --------------------------------------------------------------------------- #
+def round_up(n: int, m: int) -> int:
+    return ((n + m - 1) // m) * m
+
+
+def sublane_tile(dtype) -> int:
+    """Rows of one VMEM tile: 8 for 32-bit data, 16 for packed bf16.  A
+    dynamic window along the second-minor axis starts on a multiple of it."""
+    return _SUBLANE * 4 // jnp.dtype(dtype).itemsize
+
+
+def aligned_window(w: int, n: int, align: int) -> int:
+    """Static width of a window whose dynamic start is rounded down to a
+    multiple of ``align`` (see :func:`window_start`): wide enough to hold
+    any ``w``-wide window, capped at the axis length ``n`` (itself a
+    multiple of ``align``)."""
+    return min(round_up(w, align) + align, n)
+
+
+def window_start(a, b, w_al: int, n: int, align: int):
+    """In-kernel start of a ``w_al``-wide window (from
+    :func:`aligned_window`) over an axis of length ``n``, covering the
+    footprint whose ends sit at fractional indices ``a`` and ``b`` (f32
+    scalars, either order).  The ``w_al - align`` window centred on the
+    footprint is clipped into the axis and its start rounded down to
+    ``align``, so the ``w_al``-wide slice still holds all of it and Mosaic
+    can prove the slice tile-aligned (which it requires of packed bf16)."""
+    if w_al >= n:
+        return 0
+    span = jnp.abs(jnp.ceil(b - a)).astype(jnp.int32)
+    lo = jnp.floor(jnp.minimum(a, b)).astype(jnp.int32) - (
+        w_al - align - span) // 2
+    s = jnp.bitwise_and(jnp.clip(lo, 0, n - w_al), -align)
+    return pl.multiple_of(s, align)
+
+
+def iota_f32(shape, dim: int):
+    """Float index ramp along ``dim``.  Mosaic has only an integer iota, so
+    it is built in int32 and cast."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim).astype(jnp.float32)
+
+
 def _autotune_enabled(flag: Optional[bool]) -> bool:
     if flag is not None:
         return flag
@@ -279,7 +328,7 @@ def heuristic_config(geom: CTGeometry, batch: int = 1,
         # Cone/modular kernels tile *physical* detector rows on the v axis
         # (no lane packing; the BP's lane axis is z) — pad rows to the
         # sublane multiple instead of a full 128-lane tile.
-        bv = min(_round_up8(max(geom.n_rows, 1)), LANE)
+        bv = min(round_up(max(geom.n_rows, 1), _SUBLANE), LANE)
     elif geom.geom_type == "fan":
         # Fan is lane-packed like parallel, but its gathered-axis window is
         # magnified by sdd/(sod - R) — halve the column tile so the W-wide
@@ -387,6 +436,28 @@ def _time_call(fn, *args, reps: int = 3) -> float:
     return (time.perf_counter() - t0) / reps
 
 
+def _sweep(fn, geom: CTGeometry, x, reps: int, what: str, grid: Dict):
+    """Time ``fn`` under every candidate config of ``grid`` (knob tuple ->
+    KernelConfig) and return the fastest knob tuple.  A candidate the chip
+    refuses (a tiling Mosaic rejects, a window past VMEM) is reported as a
+    warning and skipped; a sweep in which every candidate fails raises."""
+    best, t_best, errors = None, float("inf"), []
+    for knobs, cfg in grid.items():
+        try:
+            t = _time_call(lambda a: fn(a, geom, config=cfg), x, reps=reps)
+        except Exception as e:                        # noqa: BLE001
+            errors.append(f"{cfg}: {type(e).__name__}: {e}")
+            warnings.warn(f"autotune {geom.geom_type} {what}: candidate "
+                          f"{errors[-1]}", RuntimeWarning, stacklevel=2)
+            continue
+        if t < t_best:
+            best, t_best = knobs, t
+    if best is None:
+        raise RuntimeError(f"autotune {geom.geom_type} {what}: every "
+                           f"candidate failed:\n" + "\n".join(errors))
+    return best
+
+
 def default_candidates(geom: CTGeometry) -> Iterable[KernelConfig]:
     """The measured sweep grid: small, but covers the axes that matter."""
     na = geom.n_angles
@@ -460,35 +531,13 @@ def autotune(geom: CTGeometry, batch: int = 1, dtype=jnp.float32,
     sshape = ((batch,) if batch > 1 else ()) + geom.sino_shape
     y = jnp.ones(sshape, dtype)
 
-    heur = heuristic_config(geom, batch, dtype, packed)
-    best_fp, t_fp = None, float("inf")
-    for bu, ba in fp_grid:
-        cfg = KernelConfig(bu=bu, ba=ba)
-        try:
-            t = _time_call(lambda x: fp_fn(x, geom, config=cfg), f, reps=reps)
-        except Exception:                             # noqa: BLE001
-            continue                                  # invalid tiling — skip
-        if t < t_fp:
-            best_fp, t_fp = (bu, ba), t
-
-    best_bp, t_bp = None, float("inf")
-    for bg, bab, bs in bp_grid:
-        cfg = KernelConfig(bg=bg, bab=bab, bs=bs)
-        try:
-            t = _time_call(lambda p: bp_fn(p, geom, config=cfg), y, reps=reps)
-        except Exception:                             # noqa: BLE001
-            continue
-        if t < t_bp:
-            best_bp, t_bp = (bg, bab, bs), t
-
-    # Never cache an unmeasured candidate: if a sweep produced no successful
-    # run, fall back to the heuristic for that kernel.
-    cfg = KernelConfig(
-        bu=best_fp[0] if best_fp else heur.bu,
-        ba=best_fp[1] if best_fp else heur.ba,
-        bg=best_bp[0] if best_bp else heur.bg,
-        bab=best_bp[1] if best_bp else heur.bab,
-        bs=best_bp[2] if best_bp else heur.bs)
+    bu, ba = _sweep(fp_fn, geom, f, reps, "FP",
+                    {(bu, ba): KernelConfig(bu=bu, ba=ba)
+                     for bu, ba in fp_grid})
+    bg, bab, bs = _sweep(bp_fn, geom, y, reps, "BP",
+                         {(bg, bab, bs): KernelConfig(bg=bg, bab=bab, bs=bs)
+                          for bg, bab, bs in bp_grid})
+    cfg = KernelConfig(bu=bu, ba=ba, bg=bg, bab=bab, bs=bs)
     _AUTOTUNED[key] = cfg
     save_tuned(key, cfg)
     return cfg
@@ -507,27 +556,13 @@ def _autotune_viewfold(geom: CTGeometry, batch: int, dtype, cand, reps: int,
     f = jnp.ones(shape, dtype)
     sshape = ((batch,) if batch > 1 else ()) + geom.sino_shape
     y = jnp.ones(sshape, dtype)
-    best_bu, t_best = base.bu, float("inf")
-    for bu in sorted({c.bu for c in cand}):
-        cfg = base.replace(bu=bu, ba=1)
-        try:
-            t = _time_call(lambda x: fp_fn(x, geom, config=cfg), f, reps=reps)
-        except Exception:                             # noqa: BLE001
-            continue
-        if t < t_best:
-            best_bu, t_best = bu, t
-    best_bp, t_bp = None, float("inf")
-    for bg, bab in sorted({(c.bg, c.bab) for c in cand}):
-        cfg = base.replace(bg=bg, bab=bab)
-        try:
-            t = _time_call(lambda p: bp_fn(p, geom, config=cfg), y, reps=reps)
-        except Exception:                             # noqa: BLE001
-            continue
-        if t < t_bp:
-            best_bp, t_bp = (bg, bab), t
-    cfg = base.replace(bu=best_bu, ba=1,
-                       bg=best_bp[0] if best_bp else base.bg,
-                       bab=best_bp[1] if best_bp else base.bab)
+    bu = _sweep(fp_fn, geom, f, reps, "FP",
+                {bu: base.replace(bu=bu, ba=1)
+                 for bu in sorted({c.bu for c in cand})})
+    bg, bab = _sweep(bp_fn, geom, y, reps, "BP",
+                     {(bg, bab): base.replace(bg=bg, bab=bab)
+                      for bg, bab in sorted({(c.bg, c.bab) for c in cand})})
+    cfg = base.replace(bu=bu, ba=1, bg=bg, bab=bab)
     _AUTOTUNED[key] = cfg
     save_tuned(key, cfg)
     return cfg

@@ -24,7 +24,11 @@ protocol geometries.  The server
     ``repro.kernels.ops.cache_stats`` and ``repro.kernels.tune.sweep_count``);
   * **isolates failures** per request: a request that fails validation or
     crashes its executor is answered with ``ok=False`` and its error
-    message — batch mates are re-run individually and still succeed.
+    message — batch mates are re-run individually and still succeed.  The
+    failed packed dispatch stays in :attr:`CTServer.dispatch_log` with its
+    error, and every re-run is logged as its own size-1 dispatch, so a
+    packed executor that never works (a compile failure) cannot pass as
+    success.
 
     >>> srv = CTServer(max_batch=16)
     >>> srv.warm(spec, "fbp")
@@ -129,9 +133,11 @@ class CTServer:
         self._executors: Dict[Tuple, Any] = {}
         self._responses: Dict[int, ReconResponse] = {}
         self._next_rid = 0
-        #: one record per packed dispatch: {"bucket", "tier", "solver",
-        #: "rids", "size_class", "wall_s"} — tests assert heterogeneous
-        #: specs never appear in one record.
+        #: one record per dispatch: {"bucket", "tier", "solver", "rids",
+        #: "size_class", "wall_s", "error", "rerun"} — tests assert
+        #: heterogeneous specs never appear in one record.  ``error`` is
+        #: the failed packed dispatch's message (None when it ran);
+        #: ``rerun`` marks the size-1 re-runs that follow such a failure.
         self.dispatch_log: List[Dict[str, Any]] = []
 
     # -- admission ---------------------------------------------------------- #
@@ -263,11 +269,16 @@ class CTServer:
                             residual_history=hist[i])
                 for i in range(n)]
             errors: List[Optional[str]] = [None] * n
-        except Exception:                         # noqa: BLE001
+            batch_error = None
+        except Exception as e:                    # noqa: BLE001
+            batch_error = f"{type(e).__name__}: {e}"
+        self._log(key, reqs, size, t0, batch_error)
+        if batch_error is not None:
             # Per-request isolation: re-run the batch members one by one so
             # a single poisoned request cannot take down its batch mates.
             results, errors = [], []
             for r in reqs:
+                t0 = time.perf_counter()
                 try:
                     out = self._executor(key, 1)(
                         jnp.asarray(r.sino)[None])
@@ -279,17 +290,23 @@ class CTServer:
                 except Exception as e:            # noqa: BLE001
                     results.append(None)
                     errors.append(f"{type(e).__name__}: {e}")
+                self._log(key, [r], 1, t0, errors[-1], rerun=True)
         t1 = time.perf_counter()
-        self.dispatch_log.append({
-            "bucket": key[2], "tier": tier, "solver": solver,
-            "rids": [r.rid for r in reqs], "size_class": size,
-            "wall_s": t1 - t0})
         for r, ts, res, err in zip(reqs, t_sub, results, errors):
             self._responses[r.rid] = ReconResponse(
                 rid=r.rid, ok=err is None, tier=tier, solver=solver,
-                result=res, error=err, bucket=key[2], batch_size=n,
+                result=res, error=err, bucket=key[2],
+                batch_size=n if batch_error is None else 1,
                 latency_s=t1 - ts)
         return True
+
+    def _log(self, key: Tuple, reqs: List[ReconRequest], size: int,
+             t0: float, error: Optional[str], rerun: bool = False) -> None:
+        self.dispatch_log.append({
+            "bucket": key[2], "tier": key[0], "solver": key[1],
+            "rids": [r.rid for r in reqs], "size_class": size,
+            "wall_s": time.perf_counter() - t0, "error": error,
+            "rerun": rerun})
 
     def drain(self) -> Dict[int, ReconResponse]:
         """Run steps until every queued request is answered; returns all

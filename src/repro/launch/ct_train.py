@@ -72,6 +72,7 @@ from repro.optim import (adamw, apply_updates, ema_init, ema_params,
                          ema_update, warmup_cosine)
 from repro.recon.completion import complete_and_refine, projection_residual
 from repro.runtime import checkpoint as CKPT
+from repro.runtime import compile_cache
 
 __all__ = ["GEOMETRIES", "TrainConfig", "CTTrainer", "build_geometry",
            "smoke_config", "main"]
@@ -457,6 +458,7 @@ def main(argv=None) -> int:
                     help="exit 1 unless loss decreases and DC refinement "
                          "improves PSNR on held-out phantoms (CI gate)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     overrides = {}
     for field, name in (("steps", "steps"), ("n", "size"),

@@ -17,6 +17,8 @@ from __future__ import annotations
 import jax
 import numpy as np
 
+from repro import compat
+
 
 def make_production_mesh(*, multi_pod: bool = False, shape=None):
     """Default production mesh is (16,16) / (2,16,16).  ``shape`` overrides
@@ -25,14 +27,14 @@ def make_production_mesh(*, multi_pod: bool = False, shape=None):
     if shape is None:
         shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model")[-len(shape):]
-    return jax.make_mesh(shape, axes)
+    return compat.make_mesh(shape, axes)
 
 
 def make_local_mesh(model_axis: int = 1):
     """Tiny mesh over whatever devices exist (tests / CPU smoke training)."""
     n = jax.device_count()
     data = n // model_axis
-    return jax.make_mesh((data, model_axis), ("data", "model"))
+    return compat.make_mesh((data, model_axis), ("data", "model"))
 
 
 def data_axes(mesh) -> tuple:

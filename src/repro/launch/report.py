@@ -28,10 +28,13 @@ def cell_row(arch: str, shape_name: str, rec: dict, tp=16, dp=16):
     shape = SHAPES[shape_name]
     a = analysis.analytic_cell(cfg, shape, tp=tp, dp=dp)
     n = rec.get("n_devices", 256)
-    t = roofline.terms(a["flops"], a["hbm_bytes"], a["collective_bytes"], n)
+    kind = roofline.DRYRUN_DEVICE_KIND
+    t = roofline.terms(a["flops"], a["hbm_bytes"], a["collective_bytes"], n,
+                       kind)
     mf = roofline.model_flops(cfg, shape, a["kind"])
     t["useful_fraction"] = mf / max(a["flops"], 1.0)
-    t["mfu"] = mf / (n * roofline.PEAK_FLOPS * max(t["step_s"], 1e-12))
+    t["mfu"] = mf / (n * roofline.peaks(kind)["flops"]
+                     * max(t["step_s"], 1e-12))
     # validation: reconstruct what cost_analysis should see (loops counted once)
     meas = (rec.get("cost", {}).get("flops") or 0.0) * n
     pred = analysis.hlo_counted_flops(cfg, shape)

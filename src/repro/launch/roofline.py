@@ -1,14 +1,13 @@
 """Roofline analysis from compiled dry-run artifacts (no hardware needed).
 
-Hardware model: TPU v5e —
-    peak bf16 compute : 197 TFLOP/s per chip
-    HBM bandwidth     : 819 GB/s per chip
-    ICI link bandwidth: ~50 GB/s per link
+Hardware model: the published per-chip peaks in :data:`PEAKS`, keyed by
+the ``device_kind`` JAX reports.  A device that is not in the table is an
+error, never a default.
 
 Terms (seconds, per step, per chip):
-    compute    = HLO_flops        / (chips * PEAK_FLOPS)
-    memory     = HLO_bytes        / (chips * HBM_BW)
-    collective = collective_bytes / (chips * ICI_BW)
+    compute    = HLO_flops        / (chips * peak bf16 FLOP/s)
+    memory     = HLO_bytes        / (chips * HBM bandwidth)
+    collective = collective_bytes / (chips * ICI link bandwidth)
 
 HLO flops/bytes come from ``compiled.cost_analysis()``.  Collective bytes are
 *not* in cost_analysis: we parse the optimized HLO text and sum operand sizes
@@ -26,14 +25,30 @@ ops.  Two accounting subtleties, both handled here:
 """
 from __future__ import annotations
 
-import json
-import os
 import re
 from typing import Dict
 
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # B/s per chip
-ICI_BW = 50e9                # B/s per link
+# Published peaks of one chip, keyed by ``jax.Device.device_kind``.
+# "TPU v5 lite" is the TPU v5e (Google Cloud documentation, "TPU v5e":
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip; the
+# per-link figure is that total over four links).
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+# The chip the production-mesh dry-runs (launch/dryrun.py) compile for.
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Peaks of one ``device_kind`` chip; raises on a device not in
+    :data:`PEAKS` (a wrong peak silently mis-scales every roofline share)."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; add them to roofline.PEAKS "
+                         f"with their source") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -115,10 +130,11 @@ def collective_bytes_from_hlo(hlo_text: str, n_devices: int = 512,
 # Roofline terms
 # --------------------------------------------------------------------------- #
 def terms(flops: float, bytes_hbm: float, coll_bytes: float,
-          n_chips: int) -> Dict:
-    t_c = flops / (n_chips * PEAK_FLOPS)
-    t_m = bytes_hbm / (n_chips * HBM_BW)
-    t_x = coll_bytes / (n_chips * ICI_BW)
+          n_chips: int, device_kind: str) -> Dict:
+    pk = peaks(device_kind)
+    t_c = flops / (n_chips * pk["flops"])
+    t_m = bytes_hbm / (n_chips * pk["hbm_bw"])
+    t_x = coll_bytes / (n_chips * pk["ici_bw"])
     dom = max(("compute", t_c), ("memory", t_m), ("collective", t_x),
               key=lambda kv: kv[1])
     return {"compute_s": t_c, "memory_s": t_m, "collective_s": t_x,
@@ -135,31 +151,3 @@ def model_flops(cfg, shape, kind: str) -> float:
     if kind == "prefill":
         return 2.0 * n_active * shape.global_batch * shape.seq_len
     return 2.0 * n_active * shape.global_batch     # decode: 1 token/seq
-
-
-def summarize(rec: dict, cfg, shape) -> dict:
-    """Combine a dry-run record into the roofline row."""
-    n = rec.get("n_devices", 512)
-    flops = rec.get("cost", {}).get("flops") or 0.0
-    bts = rec.get("cost", {}).get("bytes accessed") or 0.0
-    coll = rec.get("collectives", {}).get("total_bytes", 0.0)
-    # cost_analysis is per-program = per-device under SPMD
-    t = terms(flops * n, bts * n, coll * n, n)
-    mf = model_flops(cfg, shape, SHAPE_KIND[shape.name])
-    t["model_flops"] = mf
-    t["hlo_flops_total"] = flops * n
-    t["useful_fraction"] = mf / max(flops * n, 1.0)
-    t["mfu_at_roofline"] = mf / (n * PEAK_FLOPS * max(t["step_s"], 1e-12))
-    return t
-
-
-SHAPE_KIND = {"train_4k": "train", "prefill_32k": "prefill",
-              "decode_32k": "decode", "long_500k": "decode"}
-
-
-def load_results(out_dir: str):
-    rows = []
-    for fn in sorted(os.listdir(out_dir)):
-        if fn.endswith(".json"):
-            rows.append(json.load(open(os.path.join(out_dir, fn))))
-    return rows

@@ -22,10 +22,12 @@ the shim:
         -> compat.tree_map_with_path
     compiled.cost_analysis()
         -> compat.cost_analysis_dict (list-of-dicts vs dict return drift)
+    jax.make_mesh
+        -> compat.make_mesh          (default axis type became Explicit)
 
-A direct spelling works today and breaks on the next jax pin bump — the
-jax-drift CI job catches it a release late, after the code has forked into
-two spellings.  Routing through compat keeps one seam to patch.
+A direct spelling works today and breaks on the next jax pin bump, after
+the code has forked into two spellings.  Routing through compat keeps one
+seam to patch.
 
 Fix: `from repro import compat` and call the shim.  compat.py itself is
 the only file allowed to touch the raw APIs.
@@ -39,6 +41,7 @@ _FORBIDDEN: Dict[str, str] = {
     "jax.tree.flatten_with_path": "compat.tree_flatten_with_path",
     "jax.tree_util.tree_map_with_path": "compat.tree_map_with_path",
     "jax.tree.map_with_path": "compat.tree_map_with_path",
+    "jax.make_mesh": "compat.make_mesh",
 }
 
 

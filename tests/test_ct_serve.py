@@ -147,6 +147,13 @@ def test_executor_failure_isolates_poisoned_request(world):
                                    rtol=1e-5, atol=1e-7)
     assert not done[poisoned].ok
     assert "poisoned" in done[poisoned].error
+    # the failed packed dispatch and every size-1 re-run stay visible
+    packed = [rec for rec in srv.dispatch_log if not rec["rerun"]]
+    assert len(packed) == 1 and "blew up" in packed[0]["error"]
+    reruns = [rec for rec in srv.dispatch_log if rec["rerun"]]
+    assert all(rec["size_class"] == 1 for rec in reruns)
+    assert sorted(r for rec in reruns for r in rec["rids"]) == \
+        sorted(good + [poisoned])
 
 
 def test_warm_server_compiles_nothing_on_request_path(world, monkeypatch):

@@ -138,3 +138,21 @@ def test_resume_without_checkpoint_is_fresh_start(tmp_path):
     cfg = tiny("sparse_fan", ckpt_dir=str(tmp_path / "never_written"))
     t = CTTrainer(cfg)
     assert t.resume() == 0 and t.step == 0
+
+
+def test_ct_path_keeps_dryrun_xla_flags_out():
+    """The CT entry points never import ``launch/dryrun.py``, which rewrites
+    ``XLA_FLAGS`` (512 forced host devices) when imported.  Checked in a
+    fresh interpreter, so other tests' imports cannot mask it."""
+    import os
+    import subprocess
+    import sys
+    code = ("import sys\n"
+            "import repro.launch.ct_train, repro.launch.ct_serve\n"
+            "import repro.core.distributed, repro.recon\n"
+            "assert 'repro.launch.dryrun' not in sys.modules\n")
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["JAX_PLATFORMS"] = "cpu"
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]

@@ -33,21 +33,21 @@ needs8 = pytest.mark.skipif(
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return compat.make_mesh((1, 1), ("data", "model"))
 
 
 @pytest.fixture(scope="module")
 def mesh42():
     if jax.device_count() < 8:
         pytest.skip("needs 8 devices")
-    return jax.make_mesh((4, 2), ("data", "model"))
+    return compat.make_mesh((4, 2), ("data", "model"))
 
 
 @pytest.fixture(scope="module")
 def mesh24():
     if jax.device_count() < 8:
         pytest.skip("needs 8 devices")
-    return jax.make_mesh((2, 4), ("data", "model"))
+    return compat.make_mesh((2, 4), ("data", "model"))
 
 
 def _dot_rel(dp, geom, seed=0):

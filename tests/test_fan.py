@@ -66,12 +66,14 @@ def test_fan_windowed_gather_matches_oracle(det):
     axis (W < ng in FP, Wu < nup in BP): exercises the window-start
     inversion — incl. the curved-detector tan inversion — that full-axis
     shapes skip.  Guarded by assertions on the actual window sizes."""
-    from repro.kernels import fp_fan
-    g = fan_beam(4, 1, 128, VolumeGeometry(48, 48, 1), sod=200.0, sdd=220.0,
+    from repro.kernels import fp_fan, tune
+    g = fan_beam(4, 1, 128, VolumeGeometry(64, 64, 1), sod=200.0, sdd=220.0,
                  pixel_width=1.0, detector_type=det)
     cfg = KernelConfig(bu=8, bg=8)
-    assert fp_fan._window_size_fan(g, cfg.bu, g.vol.nx) < g.vol.nx
-    assert fp_fan._u_window_size_div(g, cfg.bg, g.n_cols) < g.n_cols
+    assert tune.aligned_window(fp_fan._window_size_fan(g, cfg.bu),
+                               g.vol.nx, 8) < g.vol.nx
+    assert tune.aligned_window(fp_fan._u_window_size_div(g, cfg.bg),
+                               g.n_cols, 8) < g.n_cols
     f = jax.random.normal(jax.random.PRNGKey(0), g.vol.shape)
     y = jax.random.normal(jax.random.PRNGKey(1), g.sino_shape)
     _assert_close(fp_fan_sf_pallas(f, g, config=cfg), ref.forward(f, g, "sf"))

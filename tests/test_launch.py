@@ -104,6 +104,20 @@ ENTRY %main (a: f32[8,128]) -> f32[8,128] {
     assert abs(out["per_op_bytes"]["all-gather"] - 4 * 256 * 2 * 7 / 8) < 1
 
 
+def test_roofline_peaks_keyed_by_device_kind():
+    """Peaks come from the table for the device's kind; an unknown device
+    is an error, never the v5e's numbers by default."""
+    from repro.launch import roofline
+    v5e = roofline.peaks("TPU v5 lite")
+    assert v5e["flops"] == 197e12 and v5e["hbm_bw"] == 819e9
+    t = roofline.terms(197e12, 0.0, 0.0, 1, "TPU v5 lite")
+    assert t["bound"] == "compute" and abs(t["step_s"] - 1.0) < 1e-12
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.peaks("cpu")
+    with pytest.raises(ValueError):
+        roofline.terms(1.0, 1.0, 0.0, 1, "TPU v9 imaginary")
+
+
 @pytest.mark.slow
 def test_elastic_remesh_plan_compiles(tmp_path):
     """Lose a pod's worth of chips -> plan_remesh shrinks the data axis ->
@@ -128,8 +142,9 @@ import jax
 # XLA_FLAGS write forces 512 host devices for the CLI use case, and a
 # 512-device CPU client is most of what made this test time out.
 assert jax.device_count() == 32
+from repro import compat
 from repro.launch.dryrun import lower_cell
-mesh = jax.make_mesh({new_shape!r}, ("data", "model"))
+mesh = compat.make_mesh({new_shape!r}, ("data", "model"))
 lowered, reason = lower_cell("qwen3-0.6b", "train_4k", mesh,
                              cfg_overrides={{"n_layers": 2}})
 assert reason is None

@@ -142,3 +142,23 @@ def test_token_pipeline_shards_and_learnable_structure():
     np.testing.assert_array_equal(b[:, span:2 * span], b[:, :span])
     tp2 = TokenPipeline(1000, 64, 8, seed=0, shard_index=1, shard_count=2)
     assert not np.array_equal(tp2.batch(0), b[:4])
+
+
+def test_compile_cache_dir_is_fixed(monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX keeps the setting; without
+    it, the cache goes to the checkout's fixed .jax_cache directory."""
+    import jax
+    from repro.runtime import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache.enable() == "/elsewhere/cache"
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = compile_cache.enable()
+        assert jax.config.jax_compilation_cache_dir == path
+        assert path == str(compile_cache.CHECKOUT_CACHE)
+        assert (compile_cache.CHECKOUT_CACHE.parent / "src" / "repro").is_dir()
+        assert compile_cache.enable() == path           # same every call
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

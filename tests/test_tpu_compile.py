@@ -1,0 +1,104 @@
+"""The matched Pallas pairs compile for a TPU v5e at the paper's widths.
+
+Every other test runs the kernels in interpret mode, which accepts layouts
+the chip's compiler (Mosaic) refuses: a 1 in a block's second-minor place,
+a float iota, a dynamic bf16 window start it cannot prove tile-aligned.
+These tests compile FP and BP of all five pairs (parallel, fan, exact cone,
+packed cone, modular/helical) for one chip of a described, not attached,
+``v5e:2x2`` topology, unbatched and batched, in f32 and bf16, with the TPU
+heuristic kernel configs.  Nothing runs; a refusal fails the test.
+The bf16 cases compile under ``jax.default_matmul_precision("highest")``,
+the setting an oracle comparison runs under: a kernel that leaves its MXU
+precision implicit then asks Mosaic for an f32 contraction of bf16 tiles.
+
+The topology is described inside a module fixture (never at import): only
+one process at a time may load the TPU library, and it keeps it until it
+exits, so every compile happens in this test process.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.leap_ct import limited_angle_geometry, table1_geometries
+from repro.core.spec import ProjectorSpec
+from repro.kernels import ops, tune
+from repro.launch.ct_train import TrainConfig, build_geometry
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    saved = os.environ.get("TPU_LOG_DIR")
+    os.environ["TPU_LOG_DIR"] = "disabled"       # no compiler logs under /tmp
+    cache = jax.config.jax_enable_compilation_cache
+    # A compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip: keep the cache out of it.
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                         # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache)
+    if saved is None:
+        os.environ.pop("TPU_LOG_DIR", None)
+    else:
+        os.environ["TPU_LOG_DIR"] = saved
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Lower the kernels for the chip (not the interpreter) with the TPU
+    heuristic configs (view blocking ba=8, bab=4, stripe reuse bs=2)."""
+    monkeypatch.setattr(tune, "_interpret", lambda: False)
+    monkeypatch.setattr(tune, "_on_tpu", lambda: True)
+
+
+def _t1(name):
+    return lambda: table1_geometries()[name]
+
+
+def _train(geometry, **kw):
+    return lambda: build_geometry(TrainConfig(geometry=geometry, n=512, **kw))
+
+
+# name -> (geometry, leading batch, kernel mode, compute dtype)
+CASES = {
+    "parallel-512-180": (_t1("parallel_512_180"), 0, "exact", None),
+    "parallel-512-180-bf16": (_t1("parallel_512_180"), 0, "exact",
+                              "bfloat16"),
+    "cone-exact-512-180-bf16": (_t1("cone_512_180"), 0, "exact",
+                                "bfloat16"),
+    "parallel-limited-angle-b8": (limited_angle_geometry, 8, "exact", None),
+    "fan-512-b8": (_train("sparse_fan"), 8, "exact", None),
+    "cone-exact-512-180": (_t1("cone_512_180"), 0, "exact", None),
+    "cone-exact-512-180-b2": (_t1("cone_512_180"), 2, "exact", None),
+    "cone-packed-512-180": (_t1("cone_512_180"), 0, "packed", None),
+    "cone-packed-512-180-b2": (_t1("cone_512_180"), 2, "packed", None),
+    "helical-512-nz64": (_train("helical", nz=64), 0, "exact", None),
+    "helical-512-nz64-b2": (_train("helical", nz=64), 2, "exact", None),
+}
+
+
+@pytest.mark.parametrize("direction", ["fp", "bp"])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_pair_compiles_for_v5e(case, direction, one_chip, mosaic):
+    make, batch, mode, cdt = CASES[case]
+    geom = make()
+    spec = ProjectorSpec(geom, backend="pallas", mode=mode,
+                         compute_dtype=cdt)
+    assert ops.resolve_mode(spec) == mode
+    lead = (batch,) if batch else ()
+    if direction == "fp":
+        fn, shape = (lambda x: ops.forward_project(x, spec)), geom.vol.shape
+    else:
+        fn, shape = (lambda p: ops.back_project(p, spec)), geom.sino_shape
+    arg = jax.ShapeDtypeStruct(lead + tuple(shape), jnp.float32,
+                               sharding=one_chip)
+    with jax.default_matmul_precision("highest" if cdt else None):
+        compiled = jax.jit(fn).lower(arg).compile()
+    assert "tpu_custom_call" in compiled.as_text()

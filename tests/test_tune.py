@@ -70,6 +70,48 @@ def test_autotune_off_tpu_returns_heuristic_and_caches():
     assert tune.get_config(g) is cfg          # cached under the shape class
 
 
+def test_sweep_reports_refused_candidates_and_raises_when_all_fail():
+    """A candidate the chip refuses is skipped with a warning, never in
+    silence; a sweep with no candidate left raises instead of falling back
+    to an unmeasured config."""
+    g = _geom()
+    x = jnp.ones(g.vol.shape)
+
+    def fp(a, geom, config):
+        if config.bu == 8:
+            raise ValueError("tiling refused")
+        return a * config.bu
+
+    grid = {8: KernelConfig(bu=8), 16: KernelConfig(bu=16)}
+    with pytest.warns(RuntimeWarning, match="tiling refused"):
+        assert tune._sweep(fp, g, x, 1, "FP", grid) == 16
+    with pytest.warns(RuntimeWarning), \
+            pytest.raises(RuntimeError, match="every candidate failed"):
+        tune._sweep(fp, g, x, 1, "FP", {8: KernelConfig(bu=8)})
+
+
+def test_resolve_backend_reports_what_dispatches():
+    """``backend="auto"`` picks the jnp oracle off the TPU, and for a
+    geometry outside a kernel's ``supports`` gate even on it;
+    ``resolve_backend`` makes that visible instead of silent."""
+    from repro.core import ProjectorSpec, modular_beam
+    g = _geom()
+    on_tpu = jax.default_backend() == "tpu"
+    assert ops.resolve_backend(ProjectorSpec(g)) == \
+        ("pallas" if on_tpu else "ref")
+    assert ops.resolve_backend(ProjectorSpec(g, backend="pallas")) == "pallas"
+    assert ops.resolve_backend(ProjectorSpec(g, backend="ref")) == "ref"
+    # a tilted detector frame: no SF kernel covers it
+    na = 4
+    tilt = np.array([[0.0, 0.6, 0.8]] * na)
+    tilted = modular_beam(
+        source_pos=np.array([[-50.0, 0.0, 0.0]] * na),
+        det_center=np.array([[50.0, 0.0, 0.0]] * na),
+        det_u=np.array([[0.0, 1.0, 0.0]] * na), det_v=tilt,
+        n_rows=4, n_cols=8, vol=VolumeGeometry(8, 8, 4))
+    assert ops.resolve_backend(ProjectorSpec(tilted)) == "ref"
+
+
 def test_pinned_config_produces_correct_kernels():
     g = _geom()
     tune.register_config(tune.shape_class(g), KernelConfig(bu=8, ba=3, bab=2))
