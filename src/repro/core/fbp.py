@@ -10,6 +10,12 @@ gradients but not for the FBP inversion formula.
 For non-equispaced angles the per-view quadrature weight is half the angular
 distance between its neighbours (trapezoid rule), matching the paper's
 "non-equispaced projection angles" support.
+
+Building the host tables (coordinates, interpolation matrix, quadrature
+weights) is the span ``fbp.tables``.  On the device, ``fbp_parallel``'s
+stages run under the scopes ``fbp_filter`` (the ramp filter), and in each
+view of the scan ``fbp_interp`` (the two gathers and the lerp),
+``fbp_zcontract`` (the detector-row to z contraction) and ``fbp_accum``.
 """
 from __future__ import annotations
 
@@ -21,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.core.filters import filter_sinogram
 from repro.core.geometry import CTGeometry
+from repro.runtime import obs
 
 _EPS = 1e-9
 
@@ -62,26 +69,32 @@ def fbp_parallel(sino, geom: CTGeometry, filter_name: str = "ramp"):
     v = geom.vol
     nx, ny, nz = v.shape
     nu, nv = geom.n_cols, geom.n_rows
-    q = filter_sinogram(sino, geom.pixel_width, filter_name)     # (na, nv, nu)
-    X = jnp.asarray(np.repeat(v.x_coords(), ny))                 # (nxy,)
-    Y = jnp.asarray(np.tile(v.y_coords(), nx))
-    u0, du = float(geom.u_coords()[0]), geom.pixel_width
-    Lz = jnp.asarray(_lerp_matrix(geom.v_coords(), v.z_coords()))  # (nv, nz)
-    wts = jnp.asarray(_angle_weights(geom.angles_array(), np.pi))
-    angs = jnp.asarray(geom.angles_array())
+    with jax.named_scope("fbp_filter"):
+        q = filter_sinogram(sino, geom.pixel_width, filter_name)  # (na, nv, nu)
+    with obs.span("fbp.tables"):
+        X = jnp.asarray(np.repeat(v.x_coords(), ny))             # (nxy,)
+        Y = jnp.asarray(np.tile(v.y_coords(), nx))
+        u0, du = float(geom.u_coords()[0]), geom.pixel_width
+        Lz = jnp.asarray(_lerp_matrix(geom.v_coords(), v.z_coords()))
+        wts = jnp.asarray(_angle_weights(geom.angles_array(), np.pi))
+        angs = jnp.asarray(geom.angles_array())
 
     def one(acc, inp):
         ang, w, qa = inp                                         # qa (nv, nu)
-        c, s = jnp.cos(ang), jnp.sin(ang)
-        ui = (Y * c - X * s - u0) / du                           # (nxy,)
-        j = jnp.floor(ui).astype(jnp.int32)
-        t = ui - j
-        ok0 = (j >= 0) & (j < nu)
-        ok1 = (j + 1 >= 0) & (j + 1 < nu)
-        g0 = jnp.take(qa, jnp.clip(j, 0, nu - 1), axis=1)        # (nv, nxy)
-        g1 = jnp.take(qa, jnp.clip(j + 1, 0, nu - 1), axis=1)
-        S = g0 * jnp.where(ok0, 1 - t, 0.0) + g1 * jnp.where(ok1, t, 0.0)
-        return acc + w * jnp.einsum("vq,vz->qz", S, Lz).reshape(nx, ny, nz), 0
+        with jax.named_scope("fbp_interp"):
+            c, s = jnp.cos(ang), jnp.sin(ang)
+            ui = (Y * c - X * s - u0) / du                       # (nxy,)
+            j = jnp.floor(ui).astype(jnp.int32)
+            t = ui - j
+            ok0 = (j >= 0) & (j < nu)
+            ok1 = (j + 1 >= 0) & (j + 1 < nu)
+            g0 = jnp.take(qa, jnp.clip(j, 0, nu - 1), axis=1)    # (nv, nxy)
+            g1 = jnp.take(qa, jnp.clip(j + 1, 0, nu - 1), axis=1)
+            S = g0 * jnp.where(ok0, 1 - t, 0.0) + g1 * jnp.where(ok1, t, 0.0)
+        with jax.named_scope("fbp_zcontract"):
+            vol = jnp.einsum("vq,vz->qz", S, Lz).reshape(nx, ny, nz)
+        with jax.named_scope("fbp_accum"):
+            return acc + w * vol, 0
 
     acc0 = jnp.zeros(v.shape, sino.dtype)
     acc, _ = jax.lax.scan(one, acc0, (angs, wts, q))
@@ -136,8 +149,9 @@ def fbp_fan(sino, geom: CTGeometry, filter_name: str = "ramp",
     nu, nv = geom.n_cols, geom.n_rows
     sod, sdd = geom.sod, geom.sdd
     curved = geom.detector_type == "curved"
-    gamma = _fan_gamma(geom)
-    cw = jnp.asarray(np.cos(gamma).astype(np.float32))       # cosine pre-weight
+    with obs.span("fbp.tables"):
+        gamma = _fan_gamma(geom)
+        cw = jnp.asarray(np.cos(gamma).astype(np.float32))   # cosine pre-weight
 
     ang = np.asarray(geom.angles_array(), np.float64)
     n = len(ang)
@@ -159,11 +173,12 @@ def fbp_fan(sino, geom: CTGeometry, filter_name: str = "ramp",
         # by the magnification sdd/sod (same rescale as FDK).
         q = q * (sdd / sod)
 
-    X = jnp.asarray(np.repeat(v.x_coords(), ny))             # (nxy,)
-    Y = jnp.asarray(np.tile(v.y_coords(), nx))
-    u0, du = float(geom.u_coords()[0]), geom.pixel_width
-    Lz = jnp.asarray(_lerp_matrix(geom.v_coords(), v.z_coords()))  # (nv, nz)
-    angs = jnp.asarray(geom.angles_array())
+    with obs.span("fbp.tables"):
+        X = jnp.asarray(np.repeat(v.x_coords(), ny))         # (nxy,)
+        Y = jnp.asarray(np.tile(v.y_coords(), nx))
+        u0, du = float(geom.u_coords()[0]), geom.pixel_width
+        Lz = jnp.asarray(_lerp_matrix(geom.v_coords(), v.z_coords()))
+        angs = jnp.asarray(geom.angles_array())
 
     def one(acc, inp):
         ang_, w, qa = inp                                    # qa (nv, nu)
@@ -206,14 +221,15 @@ def fbp_cone(sino, geom: CTGeometry, filter_name: str = "ramp"):
     # The ramp filter acts at detector scale; frequencies at the isocenter are
     # higher by the magnification sdd/sod, so rescale the filtered data.
     q = q * (sdd / sod)
-    X = jnp.asarray(np.repeat(v.x_coords(), ny))
-    Y = jnp.asarray(np.tile(v.y_coords(), nx))
-    Z = jnp.asarray(v.z_coords())
-    u0, du = float(geom.u_coords()[0]), geom.pixel_width
-    v0, dv = float(geom.v_coords()[0]), geom.pixel_height
-    rng = 2 * np.pi
-    wts = jnp.asarray(_angle_weights(geom.angles_array(), rng)) / 2.0
-    angs = jnp.asarray(geom.angles_array())
+    with obs.span("fbp.tables"):
+        X = jnp.asarray(np.repeat(v.x_coords(), ny))
+        Y = jnp.asarray(np.tile(v.y_coords(), nx))
+        Z = jnp.asarray(v.z_coords())
+        u0, du = float(geom.u_coords()[0]), geom.pixel_width
+        v0, dv = float(geom.v_coords()[0]), geom.pixel_height
+        wts = jnp.asarray(_angle_weights(geom.angles_array(),
+                                         2 * np.pi)) / 2.0
+        angs = jnp.asarray(geom.angles_array())
 
     def one(acc, inp):
         ang, w, qa = inp

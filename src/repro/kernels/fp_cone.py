@@ -46,6 +46,11 @@ approximation carries a derived per-geometry error bound
 ``repro.kernels.ops`` (see docs/KERNELS.md "Packed cone pair").
 
 Tile sizes come from :mod:`repro.kernels.tune` (``KernelConfig``).
+
+Device names, as in ``fp_par.py``: the exact pair's stages run under
+``sf_cone_{fp,bp}_pre`` and ``_post`` scopes and its kernels are named
+``sf_cone_{fp,bp}_g0`` (x-gathered views) and ``_g1``; the packed pair's
+axial pre-resample runs under ``sf_cone_packed_{fp,bp}_axial``.
 """
 from __future__ import annotations
 
@@ -62,6 +67,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.geometry import CTGeometry
 from repro.kernels import fp_par, precision, ref, tune
 from repro.kernels.footprint import trapezoid_pixel_weight
+from repro.runtime import obs
 
 
 _EPS = 1e-9
@@ -271,7 +277,8 @@ def _run_group(fb, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
         return None
     vol = geom.vol
     B = fb.shape[0]
-    fs, ng = _line_major_batch(fb, gathered_x)
+    with jax.named_scope("sf_cone_fp_pre"):
+        fs, ng = _line_major_batch(fb, gathered_x)
     nl, _, nz = fs.shape
     na = params.shape[0]
     nup = tune.round_up(geom.n_cols, bu)
@@ -302,10 +309,13 @@ def _run_group(fb, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
         # output buffer is the cross-step accumulator: always f32
         out_shape=jax.ShapeDtypeStruct((B * na, nup, nvp), jnp.float32),
         interpret=tune._interpret(),
+        name=f"sf_cone_fp_g{0 if gathered_x else 1}",
     )(jnp.asarray(params), fs)
-    return out.reshape(B, na, nup, nvp)
+    with jax.named_scope("sf_cone_fp_post"):
+        return out.reshape(B, na, nup, nvp)
 
 
+@obs.spanned("kernels.fp_cone")
 def fp_cone_sf_pallas(f, geom: CTGeometry, bu: Optional[int] = None,
                       bv: Optional[int] = None,
                       config: Optional[tune.KernelConfig] = None,
@@ -323,7 +333,8 @@ def fp_cone_sf_pallas(f, geom: CTGeometry, bu: Optional[int] = None,
     batched = f.ndim == 4
     out_dtype = f.dtype
     cdt = precision.resolve(compute_dtype, f.dtype)
-    fb = precision.cast_in(f if batched else f[None], cdt)
+    with jax.named_scope("sf_cone_fp_pre"):
+        fb = precision.cast_in(f if batched else f[None], cdt)
     cfg = tune.resolve_config(geom, fb.shape[0], config, dtype=cdt,
                               bu=bu, bv=bv)
     px, py, order = _view_params_cone(geom)
@@ -334,11 +345,12 @@ def fp_cone_sf_pallas(f, geom: CTGeometry, bu: Optional[int] = None,
     o2 = _run_group(fb, py, geom, False, cfg.bu, cfg.bv)
     if o2 is not None:
         outs.append(o2)
-    out = jnp.concatenate(outs, axis=1)                    # (B, na, NUp, NVp)
-    out = out[:, :, :geom.n_cols, :geom.n_rows]
-    inv = np.argsort(order)
-    out = jnp.swapaxes(out[:, inv], 2, 3).astype(out_dtype)  # (B, na, nv, nu)
-    return out if batched else out[0]
+    with jax.named_scope("sf_cone_fp_post"):
+        out = jnp.concatenate(outs, axis=1)                # (B, na, NUp, NVp)
+        out = out[:, :, :geom.n_cols, :geom.n_rows]
+        inv = np.argsort(order)
+        out = jnp.swapaxes(out[:, inv], 2, 3).astype(out_dtype)
+        return out if batched else out[0]                  # (B, na, nv, nu)
 
 
 # --------------------------------------------------------------------------- #
@@ -435,17 +447,18 @@ def _run_bp_group(q, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
     ng, nl = (vol.nx, vol.ny) if gathered_x else (vol.ny, vol.nx)
     nz = vol.nz
     align = tune.sublane_tile(q.dtype)
-    q = fp_par._pad_cols(q, align)
-    B, na, nu_, nv_ = q.shape
-    bab = max(1, min(bab, na))
-    nap = tune.round_up(na, bab)
-    if nap != na:
-        params = np.concatenate([params, np.repeat(params[-1:],
-                                                   nap - na, 0)], 0)
-        q = jnp.pad(q, ((0, 0), (0, nap - na), (0, 0), (0, 0)))
-    nvp = tune.round_up(nv_, bv)
-    q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, nvp - nv_)))
-    qs = q.reshape(B * nap, nu_, nvp)
+    with jax.named_scope("sf_cone_bp_pre"):
+        q = fp_par._pad_cols(q, align)
+        B, na, nu_, nv_ = q.shape
+        bab = max(1, min(bab, na))
+        nap = tune.round_up(na, bab)
+        if nap != na:
+            params = np.concatenate([params, np.repeat(params[-1:],
+                                                       nap - na, 0)], 0)
+            q = jnp.pad(q, ((0, 0), (0, nap - na), (0, 0), (0, 0)))
+        nvp = tune.round_up(nv_, bv)
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, nvp - nv_)))
+        qs = q.reshape(B * nap, nu_, nvp)
     ngp = tune.round_up(ng, bg)
     ngb, nab = ngp // bg, nap // bab
     Wu = tune.aligned_window(_u_window_size_div(geom, bg), nu_, align)
@@ -469,10 +482,13 @@ def _run_bp_group(q, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
         # output buffer is the cross-step accumulator: always f32
         out_shape=jax.ShapeDtypeStruct((nl, B * ngp, nz), jnp.float32),
         interpret=tune._interpret(),
+        name=f"sf_cone_bp_g{0 if gathered_x else 1}",
     )(jnp.asarray(params), qs)
-    return _volume_from_line_major(out, B, ng, gathered_x)
+    with jax.named_scope("sf_cone_bp_post"):
+        return _volume_from_line_major(out, B, ng, gathered_x)
 
 
+@obs.spanned("kernels.bp_cone")
 def bp_cone_sf_pallas(sino, geom: CTGeometry, bg: Optional[int] = None,
                       bv: Optional[int] = None, bab: Optional[int] = None,
                       config: Optional[tune.KernelConfig] = None,
@@ -499,18 +515,23 @@ def bp_cone_sf_pallas(sino, geom: CTGeometry, bg: Optional[int] = None,
     cfg = tune.resolve_config(geom, qb.shape[0], config, dtype=cdt,
                               bg=bg, bv=bv, bab=bab)
     px, py, order = _view_params_cone(geom)
-    q = jnp.swapaxes(qb, 2, 3)                             # (B, na, nu, nv)
-    q = precision.cast_in(q[:, order], cdt)                # group-major views
     nax = px.shape[0]
-    acc = jnp.zeros((qb.shape[0],) + geom.vol.shape, jnp.float32)
+    with jax.named_scope("sf_cone_bp_pre"):
+        q = jnp.swapaxes(qb, 2, 3)                         # (B, na, nu, nv)
+        q = precision.cast_in(q[:, order], cdt)            # group-major views
+        acc = jnp.zeros((qb.shape[0],) + geom.vol.shape, jnp.float32)
     if nax:
-        acc = acc + _run_bp_group(q[:, :nax], px, geom, True,
-                                  cfg.bg, cfg.bv, cfg.bab)
+        o = _run_bp_group(q[:, :nax], px, geom, True, cfg.bg, cfg.bv, cfg.bab)
+        with jax.named_scope("sf_cone_bp_post"):
+            acc = acc + o
     if py.shape[0]:
-        acc = acc + _run_bp_group(q[:, nax:], py, geom, False,
-                                  cfg.bg, cfg.bv, cfg.bab)
-    acc = acc.astype(out_dtype)
-    return acc if batched else acc[0]
+        o = _run_bp_group(q[:, nax:], py, geom, False, cfg.bg, cfg.bv,
+                          cfg.bab)
+        with jax.named_scope("sf_cone_bp_post"):
+            acc = acc + o
+    with jax.named_scope("sf_cone_bp_post"):
+        acc = acc.astype(out_dtype)
+        return acc if batched else acc[0]
 
 
 # --------------------------------------------------------------------------- #
@@ -602,6 +623,7 @@ def cone_packed_error_bound(geom: CTGeometry) -> float:
     return 2.0 * s + obl
 
 
+@obs.spanned("kernels.fp_cone_packed")
 def fp_cone_packed(f, geom: CTGeometry, bu: Optional[int] = None,
                    bv: Optional[int] = None, ba: Optional[int] = None,
                    config: Optional[tune.KernelConfig] = None,
@@ -628,16 +650,19 @@ def fp_cone_packed(f, geom: CTGeometry, bu: Optional[int] = None,
                               bu=bu, bv=bv, ba=ba, packed=True)
     Fz = jnp.asarray(_z_overlap_cone_packed(geom))             # (nz, nv)
     if f.ndim == 3:
-        g = jnp.einsum("xyz,zv->xyv", f, Fz)                   # pre-resample
+        with jax.named_scope("sf_cone_packed_fp_axial"):
+            g = jnp.einsum("xyz,zv->xyv", f, Fz)               # pre-resample
         out = fp_fan._fp_core(precision.cast_in(g, cdt), geom, cfg)
         return jnp.swapaxes(out, 1, 2).astype(out_dtype)       # (na, nv, nu)
-    g = jnp.einsum("bxyz,zv->xybv", f, Fz)                     # (nx, ny, B, nv)
+    with jax.named_scope("sf_cone_packed_fp_axial"):
+        g = jnp.einsum("bxyz,zv->xybv", f, Fz)                 # (nx, ny, B, nv)
     g = g.reshape(geom.vol.nx, geom.vol.ny, batch * geom.n_rows)
     out = fp_fan._fp_core(precision.cast_in(g, cdt), geom, cfg)
     out = out.reshape(geom.n_angles, geom.n_cols, batch, geom.n_rows)
     return jnp.transpose(out, (2, 0, 3, 1)).astype(out_dtype)  # (B, na, nv, nu)
 
 
+@obs.spanned("kernels.bp_cone_packed")
 def bp_cone_packed(sino, geom: CTGeometry, bg: Optional[int] = None,
                    bv: Optional[int] = None, bab: Optional[int] = None,
                    bs: Optional[int] = None,
@@ -662,12 +687,14 @@ def bp_cone_packed(sino, geom: CTGeometry, bg: Optional[int] = None,
     if sino.ndim == 3:
         q = precision.cast_in(jnp.swapaxes(sino, 1, 2), cdt)   # (na, nu, nv)
         acc = fp_fan._bp_core(q, geom, cfg)                    # (nx, ny, nv)
-        return jnp.einsum("xyv,zv->xyz", acc, Fz).astype(out_dtype)
+        with jax.named_scope("sf_cone_packed_bp_axial"):
+            return jnp.einsum("xyv,zv->xyz", acc, Fz).astype(out_dtype)
     q = jnp.transpose(sino, (1, 3, 0, 2))                      # (na, nu, B, nv)
     q = q.reshape(geom.n_angles, geom.n_cols, batch * geom.n_rows)
     acc = fp_fan._bp_core(precision.cast_in(q, cdt), geom, cfg)
     acc = acc.reshape(geom.vol.nx, geom.vol.ny, batch, geom.n_rows)
-    return jnp.einsum("xybv,zv->bxyz", acc, Fz).astype(out_dtype)
+    with jax.named_scope("sf_cone_packed_bp_axial"):
+        return jnp.einsum("xybv,zv->bxyz", acc, Fz).astype(out_dtype)
 
 
 def fp_cone_packed_ref(f, geom: CTGeometry):

@@ -50,6 +50,7 @@ from repro.kernels.fp_cone import (_corner_trapezoid, _mag_bounds,
                                    _u_window_size_div, _view_params_cone)
 from repro.kernels.fp_par import _line_major, _pad_cols, _pad_views
 from repro.kernels.ref import _z_overlap_matrix
+from repro.runtime import obs
 
 _EPS = 1e-9
 
@@ -207,6 +208,7 @@ def _fp_core(g, geom: CTGeometry, cfg: tune.KernelConfig):
     return out[inv]
 
 
+@obs.spanned("kernels.fp_fan")
 def fp_fan_sf_pallas(f, geom: CTGeometry, bu: Optional[int] = None,
                      bv: Optional[int] = None, ba: Optional[int] = None,
                      config: Optional[tune.KernelConfig] = None,
@@ -355,6 +357,7 @@ def _bp_core(q, geom: CTGeometry, cfg: tune.KernelConfig):
     return acc[:, :, :nv_lanes]
 
 
+@obs.spanned("kernels.bp_fan")
 def bp_fan_sf_pallas(sino, geom: CTGeometry, bg: Optional[int] = None,
                      bv: Optional[int] = None, bab: Optional[int] = None,
                      bs: Optional[int] = None,

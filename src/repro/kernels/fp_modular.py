@@ -68,6 +68,7 @@ from repro.kernels.footprint import trapezoid_pixel_weight
 from repro.kernels.fp_cone import (_corner_trapezoid, _line_major_batch,
                                    _volume_from_line_major)
 from repro.kernels.fp_par import _pad_cols
+from repro.runtime import obs
 
 _EPS = 1e-9
 _AXIAL_TOL = 1e-4
@@ -350,6 +351,7 @@ def _run_fp_group(fb, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
     return out.reshape(B, na, nup, nvp)
 
 
+@obs.spanned("kernels.fp_modular")
 def fp_modular_sf_pallas(f, geom: CTGeometry, bu: Optional[int] = None,
                          bv: Optional[int] = None,
                          config: Optional[tune.KernelConfig] = None,
@@ -527,6 +529,7 @@ def _run_bp_group(q, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
     return _volume_from_line_major(out, B, ng, gathered_x)
 
 
+@obs.spanned("kernels.bp_modular")
 def bp_modular_sf_pallas(sino, geom: CTGeometry, bg: Optional[int] = None,
                          bv: Optional[int] = None, bab: Optional[int] = None,
                          config: Optional[tune.KernelConfig] = None,

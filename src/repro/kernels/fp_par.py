@@ -34,6 +34,12 @@ the old hard-coded ``BU``/``BV`` module constants are gone.
 Both kernels share the weight math; the backprojector is the exact transpose
 of the forward (same coefficients, transposed contraction), so the pair is
 *matched* in the paper's sense.
+
+Device names.  Each stage of a wrapper runs under a ``jax.named_scope``
+(``sf_par_{fp,bp}_axial``, ``_pre`` for the transposes and padding before a
+kernel, ``_post`` for the slicing, reordering and sums after it), and each
+kernel is named ``sf_par_{fp,bp}_g0`` (x-gathered views) or ``_g1``
+(y-gathered), so a profile's ops read by program stage.
 """
 from __future__ import annotations
 
@@ -51,6 +57,7 @@ from repro.core.geometry import CTGeometry
 from repro.kernels import precision, tune
 from repro.kernels.footprint import trapezoid_pixel_weight
 from repro.kernels.ref import _z_overlap_matrix
+from repro.runtime import obs
 
 
 # --------------------------------------------------------------------------- #
@@ -166,7 +173,8 @@ def _run_fp_group(g, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
         raise ValueError(
             "empty view group reached the parallel Pallas kernel; callers "
             "(_fp_core/_bp_core) must skip groups with no views")
-    g = _line_major(g, gathered_x)
+    with jax.named_scope("sf_par_fp_pre"):
+        g = _line_major(g, gathered_x)
     nl, ng, nvp = g.shape
     na = params.shape[0]
     params, _, ba = _pad_views(params, ba)   # padded views dropped after
@@ -192,8 +200,10 @@ def _run_fp_group(g, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
         # regardless of the tile dtype (the caller restores its dtype once).
         out_shape=jax.ShapeDtypeStruct((nap, nup, nvp), jnp.float32),
         interpret=tune._interpret(),
+        name=f"sf_par_fp_g{0 if gathered_x else 1}",
     )(jnp.asarray(params), g)
-    return out[:na]
+    with jax.named_scope("sf_par_fp_post"):
+        return out[:na]
 
 
 def _line_major(g, gathered_x: bool):
@@ -213,19 +223,22 @@ def _fp_core(g, geom: CTGeometry, cfg: tune.KernelConfig):
     batch x n_rows).  Returns the u-major sinogram (n_angles, n_cols, NV)."""
     nv_lanes = g.shape[2]
     nvp = tune.round_up(nv_lanes, cfg.bv)
-    g = jnp.pad(g, ((0, 0), (0, 0), (0, nvp - nv_lanes)))
+    with jax.named_scope("sf_par_fp_pre"):
+        g = jnp.pad(g, ((0, 0), (0, 0), (0, nvp - nv_lanes)))
     px, py, order = _view_params(geom)
     outs = []
     if px.shape[0]:
         outs.append(_run_fp_group(g, px, geom, True, cfg.bu, cfg.bv, cfg.ba))
     if py.shape[0]:
         outs.append(_run_fp_group(g, py, geom, False, cfg.bu, cfg.bv, cfg.ba))
-    out = jnp.concatenate(outs, axis=0)                    # (na, NUp, NVp)
-    out = out[:, :geom.n_cols, :nv_lanes]
-    inv = np.argsort(order)
-    return out[inv]
+    with jax.named_scope("sf_par_fp_post"):
+        out = jnp.concatenate(outs, axis=0)                # (na, NUp, NVp)
+        out = out[:, :geom.n_cols, :nv_lanes]
+        inv = np.argsort(order)
+        return out[inv]
 
 
+@obs.spanned("kernels.fp_par")
 def fp_parallel_sf_pallas(f, geom: CTGeometry, bu: Optional[int] = None,
                           bv: Optional[int] = None, ba: Optional[int] = None,
                           config: Optional[tune.KernelConfig] = None,
@@ -244,17 +257,23 @@ def fp_parallel_sf_pallas(f, geom: CTGeometry, bu: Optional[int] = None,
                               bu=bu, bv=bv, ba=ba)
     Fz = jnp.asarray(_z_overlap_matrix(geom))              # (nz, nv)
     if f.ndim == 3:
-        g = jnp.einsum("xyz,zv->xyv", f, Fz)               # axial footprint
-        g = precision.cast_in(g, cdt)
+        with jax.named_scope("sf_par_fp_axial"):
+            g = jnp.einsum("xyz,zv->xyv", f, Fz)           # axial footprint
+        with jax.named_scope("sf_par_fp_pre"):
+            g = precision.cast_in(g, cdt)
         out = _fp_core(g, geom, cfg)                       # (na, nu, nv) f32
-        return jnp.swapaxes(out, 1, 2).astype(out_dtype)   # (na, nv, nu)
+        with jax.named_scope("sf_par_fp_post"):
+            return jnp.swapaxes(out, 1, 2).astype(out_dtype)  # (na, nv, nu)
     # Lane-packed batch: (B, nx, ny, nz) -> lanes = B * n_rows
-    g = jnp.einsum("bxyz,zv->xybv", f, Fz)                 # (nx, ny, B, nv)
-    g = g.reshape(geom.vol.nx, geom.vol.ny, batch * geom.n_rows)
-    g = precision.cast_in(g, cdt)
+    with jax.named_scope("sf_par_fp_axial"):
+        g = jnp.einsum("bxyz,zv->xybv", f, Fz)             # (nx, ny, B, nv)
+    with jax.named_scope("sf_par_fp_pre"):
+        g = g.reshape(geom.vol.nx, geom.vol.ny, batch * geom.n_rows)
+        g = precision.cast_in(g, cdt)
     out = _fp_core(g, geom, cfg)                           # (na, nu, B*nv)
-    out = out.reshape(geom.n_angles, geom.n_cols, batch, geom.n_rows)
-    return jnp.transpose(out, (2, 0, 3, 1)).astype(out_dtype)
+    with jax.named_scope("sf_par_fp_post"):
+        out = out.reshape(geom.n_angles, geom.n_cols, batch, geom.n_rows)
+        return jnp.transpose(out, (2, 0, 3, 1)).astype(out_dtype)
 
 
 # --------------------------------------------------------------------------- #
@@ -333,9 +352,10 @@ def _run_bp_group(q, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
     ng, nl = ((geom.vol.nx, geom.vol.ny) if gathered_x
               else (geom.vol.ny, geom.vol.nx))
     align = tune.sublane_tile(q.dtype)
-    q = _pad_cols(q, align)
-    na, nup, nvp = q.shape
-    params, q, bab = _pad_views(params, bab, q)
+    with jax.named_scope("sf_par_bp_pre"):
+        q = _pad_cols(q, align)
+        na, nup, nvp = q.shape
+        params, q, bab = _pad_views(params, bab, q)
     nap = params.shape[0]
     bs = max(1, min(bs, max(1, ng // bg)))    # don't block past the axis
     bstr = bg * bs                            # gathered voxels per program
@@ -359,8 +379,10 @@ def _run_bp_group(q, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
         # f32 cross-step accumulator regardless of the stripe dtype.
         out_shape=jax.ShapeDtypeStruct((nl, ngp, nvp), jnp.float32),
         interpret=tune._interpret(),
+        name=f"sf_par_bp_g{0 if gathered_x else 1}",
     )(jnp.asarray(params), q)
-    return out[:, :ng]
+    with jax.named_scope("sf_par_bp_post"):
+        return out[:, :ng]
 
 
 def _bp_core(q, geom: CTGeometry, cfg: tune.KernelConfig):
@@ -369,20 +391,27 @@ def _bp_core(q, geom: CTGeometry, cfg: tune.KernelConfig):
     applied."""
     nv_lanes = q.shape[2]
     nvp = tune.round_up(nv_lanes, cfg.bv)
-    q = jnp.pad(q, ((0, 0), (0, 0), (0, nvp - nv_lanes)))
     px, py, order = _view_params(geom)
-    q = q[order]                                           # group-major order
     nax = px.shape[0]
-    acc = jnp.zeros((geom.vol.nx, geom.vol.ny, nvp), jnp.float32)
+    with jax.named_scope("sf_par_bp_pre"):
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, nvp - nv_lanes)))
+        q = q[order]                                       # group-major order
+        acc = jnp.zeros((geom.vol.nx, geom.vol.ny, nvp), jnp.float32)
     if nax:                                 # (ny, nx, NVp): transpose back
-        acc = acc + jnp.swapaxes(_run_bp_group(
-            q[:nax], px, geom, True, cfg.bg, cfg.bv, cfg.bab, cfg.bs), 0, 1)
+        o = _run_bp_group(q[:nax], px, geom, True, cfg.bg, cfg.bv, cfg.bab,
+                          cfg.bs)
+        with jax.named_scope("sf_par_bp_post"):
+            acc = acc + jnp.swapaxes(o, 0, 1)
     if py.shape[0]:                         # (nx, ny, NVp) already
-        acc = acc + _run_bp_group(q[nax:], py, geom, False,
-                                  cfg.bg, cfg.bv, cfg.bab, cfg.bs)
-    return acc[:, :, :nv_lanes]
+        o = _run_bp_group(q[nax:], py, geom, False, cfg.bg, cfg.bv, cfg.bab,
+                          cfg.bs)
+        with jax.named_scope("sf_par_bp_post"):
+            acc = acc + o
+    with jax.named_scope("sf_par_bp_post"):
+        return acc[:, :, :nv_lanes]
 
 
+@obs.spanned("kernels.bp_par")
 def bp_parallel_sf_pallas(sino, geom: CTGeometry, bg: Optional[int] = None,
                           bv: Optional[int] = None, bab: Optional[int] = None,
                           bs: Optional[int] = None,
@@ -402,16 +431,20 @@ def bp_parallel_sf_pallas(sino, geom: CTGeometry, bg: Optional[int] = None,
                               bg=bg, bv=bv, bab=bab, bs=bs)
     Fz = jnp.asarray(_z_overlap_matrix(geom))              # (nz, nv)
     if sino.ndim == 3:
-        q = jnp.swapaxes(sino, 1, 2)                       # (na, nu, nv)
-        q = precision.cast_in(q, cdt)
+        with jax.named_scope("sf_par_bp_pre"):
+            q = jnp.swapaxes(sino, 1, 2)                   # (na, nu, nv)
+            q = precision.cast_in(q, cdt)
         acc = _bp_core(q, geom, cfg)                       # (nx, ny, nv) f32
-        return jnp.einsum("xyv,zv->xyz", acc, Fz).astype(out_dtype)
-    q = jnp.transpose(sino, (1, 3, 0, 2))                  # (na, nu, B, nv)
-    q = q.reshape(geom.n_angles, geom.n_cols, batch * geom.n_rows)
-    q = precision.cast_in(q, cdt)
+        with jax.named_scope("sf_par_bp_axial"):
+            return jnp.einsum("xyv,zv->xyz", acc, Fz).astype(out_dtype)
+    with jax.named_scope("sf_par_bp_pre"):
+        q = jnp.transpose(sino, (1, 3, 0, 2))              # (na, nu, B, nv)
+        q = q.reshape(geom.n_angles, geom.n_cols, batch * geom.n_rows)
+        q = precision.cast_in(q, cdt)
     acc = _bp_core(q, geom, cfg)                           # (nx, ny, B*nv)
-    acc = acc.reshape(geom.vol.nx, geom.vol.ny, batch, geom.n_rows)
-    return jnp.einsum("xybv,zv->bxyz", acc, Fz).astype(out_dtype)
+    with jax.named_scope("sf_par_bp_axial"):
+        acc = acc.reshape(geom.vol.nx, geom.vol.ny, batch, geom.n_rows)
+        return jnp.einsum("xybv,zv->bxyz", acc, Fz).astype(out_dtype)
 
 
 def register():

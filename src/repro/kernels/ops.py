@@ -52,7 +52,9 @@ fixed config never retraces).  The op cache is a bounded LRU keyed on
 plus model/backend/config/resolved-mode and the dtype pair (normalized
 compute policy, input dtype) — so equal geometries share ops and evicted
 entries release both the traced functions and the geometry they close over.
-:func:`cache_stats` exposes size/hit/miss counters; the serving layer's
+:func:`cache_stats` exposes the size and the hit and miss counters
+(``ops.cache_hit`` and ``ops.cache_miss`` in :mod:`repro.runtime.obs`,
+beside the spans ``ops.get_bundle`` and ``ops.build``); the serving layer's
 warm-path guarantee ("a primed server never compiles on the request path")
 is asserted against them.
 """
@@ -67,6 +69,7 @@ import jax.numpy as jnp
 from repro.core.geometry import CTGeometry
 from repro.core.spec import ProjectorSpec, as_spec
 from repro.kernels import precision, ref, tune
+from repro.runtime import obs
 
 
 class _KernelEntry(NamedTuple):
@@ -221,6 +224,7 @@ def resolve_backend(spec_or_geom, model: str = "sf",
     return "pallas" if _use_pallas(geom, model, backend) else "ref"
 
 
+@obs.spanned("ops.build")
 def _build(geom: CTGeometry, model: str, backend: str,
            config: Optional[tune.KernelConfig], use_pallas: bool,
            mode: str, compute_dtype) -> Ops:
@@ -266,12 +270,10 @@ def _build(geom: CTGeometry, model: str, backend: str,
 # their closures.
 _OPS_CACHE: "OrderedDict[Tuple, Ops]" = OrderedDict()
 _OPS_CACHE_SIZE = 256
-_CACHE_HITS = 0
-_CACHE_MISSES = 0
 
 
+@obs.spanned("ops.get_bundle")
 def _get_bundle(spec: ProjectorSpec, in_dtype=None) -> Ops:
-    global _CACHE_HITS, _CACHE_MISSES
     if spec.shard is not None:
         raise ValueError(
             "spec carries a ShardSpec — the local op cache cannot realize "
@@ -295,10 +297,10 @@ def _get_bundle(spec: ProjectorSpec, in_dtype=None) -> Ops:
     key = spec.cache_key(rmode, idt)
     hit = _OPS_CACHE.get(key)
     if hit is not None:
-        _CACHE_HITS += 1
+        obs.count("ops.cache_hit")
         _OPS_CACHE.move_to_end(key)
         return hit
-    _CACHE_MISSES += 1
+    obs.count("ops.cache_miss")
     bundle = _build(geom, spec.model, spec.backend, spec.config, use_pallas,
                     rmode, spec.compute_dtype)
     _OPS_CACHE[key] = bundle
@@ -317,8 +319,8 @@ def cache_stats() -> Dict[str, int]:
 
     The serving layer's warm-path guarantee is checked against these — on a
     warm server, request traffic must add zero entries and zero misses."""
-    return {"size": len(_OPS_CACHE), "hits": _CACHE_HITS,
-            "misses": _CACHE_MISSES}
+    return {"size": len(_OPS_CACHE), "hits": obs.counter("ops.cache_hit"),
+            "misses": obs.counter("ops.cache_miss")}
 
 
 def get_ops(spec_or_geom, model: str = "sf", backend: str = "auto",

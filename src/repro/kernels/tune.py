@@ -60,6 +60,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.geometry import CTGeometry
+from repro.runtime import obs
 
 __all__ = [
     "KernelConfig",
@@ -143,13 +144,13 @@ def shape_class(geom: CTGeometry, batch: int = 1,
 # --------------------------------------------------------------------------- #
 _REGISTRY: Dict[Tuple, KernelConfig] = {}       # explicit + autotuned entries
 _AUTOTUNED: Dict[Tuple, KernelConfig] = {}      # measured results only
-_SWEEPS = 0                                     # autotune() invocations
 
 
 def sweep_count() -> int:
-    """Number of ``autotune`` invocations this process (warm-path probe:
-    a primed serving instance must answer traffic without sweeping)."""
-    return _SWEEPS
+    """Number of ``autotune`` invocations this process, the counter
+    ``tune.sweep`` (warm-path probe: a primed serving instance must answer
+    traffic without sweeping)."""
+    return obs.counter("tune.sweep")
 
 
 def register_config(cls_key: Tuple, cfg: KernelConfig) -> None:
@@ -374,6 +375,7 @@ def get_config(geom: CTGeometry, batch: int = 1, dtype=jnp.float32,
     return heuristic_config(geom, batch, dtype, packed)
 
 
+@obs.spanned("tune.resolve_config")
 def resolve_config(geom: CTGeometry, batch: int,
                    config: Optional[KernelConfig],
                    dtype=jnp.float32, packed: bool = False,
@@ -481,8 +483,7 @@ def autotune(geom: CTGeometry, batch: int = 1, dtype=jnp.float32,
     without measuring.  FP and BP are timed independently and the best
     (bu, ba) is combined with the best (bg, bab).
     """
-    global _SWEEPS
-    _SWEEPS += 1
+    obs.count("tune.sweep")
     key = shape_class(geom, batch, dtype, packed)
     if not _on_tpu():
         cfg = heuristic_config(geom, batch, dtype, packed)

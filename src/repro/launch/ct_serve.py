@@ -35,6 +35,11 @@ protocol geometries.  The server
     >>> rid = srv.submit(ReconRequest(spec=spec, sino=y, solver="fbp"))
     >>> done = srv.drain()
     >>> done[rid].image
+
+Spans (:mod:`repro.runtime.obs`): ``serve.submit`` (with the request's
+``rid``), ``serve.step``, ``serve.dispatch`` (with its ``rids``; its
+length is the dispatch log's ``wall_s``) and ``serve.queue_wait``, each
+request's wait from submit to dispatch (with its ``rid``).
 """
 from __future__ import annotations
 
@@ -52,6 +57,7 @@ from repro.core.spec import ProjectorSpec
 from repro.recon import cgls, fista_tv, sirt
 from repro.recon.fista_tv import power_iteration
 from repro.recon.result import ReconResult
+from repro.runtime import obs
 
 __all__ = ["ReconRequest", "ReconResponse", "CTServer", "TIERS",
            "TIER_SOLVERS", "solver_tier"]
@@ -152,7 +158,12 @@ class CTServer:
         (``ok=False``) without ever reaching a batch."""
         rid = self._next_rid if req.rid is None else req.rid
         self._next_rid = max(self._next_rid, rid) + 1
-        req = dataclasses.replace(req, rid=rid)
+        with obs.span("serve.submit", rid=rid):
+            self._admit(dataclasses.replace(req, rid=rid))
+        return rid
+
+    def _admit(self, req: ReconRequest) -> None:
+        rid = req.rid
         try:
             tier = solver_tier(req.solver)
             if not isinstance(req.spec, ProjectorSpec):
@@ -167,10 +178,9 @@ class CTServer:
             self._responses[rid] = ReconResponse(
                 rid=rid, ok=False, tier="?", solver=req.solver,
                 error=f"{type(e).__name__}: {e}")
-            return rid
+            return
         self._queues.setdefault(key, []).append((req, time.perf_counter()))
         self._bucket_meta.setdefault(key, req)
-        return rid
 
     def pending(self) -> int:
         return sum(len(q) for q in self._queues.values())
@@ -245,6 +255,11 @@ class CTServer:
         key = self._pick_bucket()
         if key is None:
             return False
+        with obs.span("serve.step"):
+            self._step(key)
+        return True
+
+    def _step(self, key: Tuple) -> None:
         q = self._queues[key]
         take, q[:] = q[:self.max_batch], q[self.max_batch:]
         reqs = [r for r, _ in take]
@@ -257,40 +272,45 @@ class CTServer:
         # eat the batching win; one numpy stack + a single transfer is flat.
         sinos = [np.asarray(r.sino) for r in reqs]
         batch = np.stack(sinos + [np.zeros_like(sinos[0])] * (size - n))
-        t0 = time.perf_counter()
-        try:
-            out = self._executor(key, size)(batch)
-            # Unpack on the host: per-index device gathers would each
-            # compile a tiny executable and poke holes in the warm path.
-            img = np.asarray(out.image)
-            hist = np.asarray(out.residual_history)
-            results: List[Optional[ReconResult]] = [
-                ReconResult(image=img[i], iterations=out.iterations,
-                            residual_history=hist[i])
-                for i in range(n)]
-            errors: List[Optional[str]] = [None] * n
-            batch_error = None
-        except Exception as e:                    # noqa: BLE001
-            batch_error = f"{type(e).__name__}: {e}"
-        self._log(key, reqs, size, t0, batch_error)
+        t_disp = time.perf_counter()
+        for r, ts in take:
+            obs.record("serve.queue_wait", ts, t_disp, rid=r.rid)
+        with obs.span("serve.dispatch", rids=[r.rid for r in reqs]) as sp:
+            try:
+                out = self._executor(key, size)(batch)
+                # Unpack on the host: per-index device gathers would each
+                # compile a tiny executable and poke holes in the warm path.
+                img = np.asarray(out.image)
+                hist = np.asarray(out.residual_history)
+                results: List[Optional[ReconResult]] = [
+                    ReconResult(image=img[i], iterations=out.iterations,
+                                residual_history=hist[i])
+                    for i in range(n)]
+                errors: List[Optional[str]] = [None] * n
+                batch_error = None
+            except Exception as e:                # noqa: BLE001
+                batch_error = f"{type(e).__name__}: {e}"
+        self._log(key, reqs, size, sp.seconds, batch_error)
         if batch_error is not None:
             # Per-request isolation: re-run the batch members one by one so
             # a single poisoned request cannot take down its batch mates.
             results, errors = [], []
             for r in reqs:
-                t0 = time.perf_counter()
-                try:
-                    out = self._executor(key, 1)(
-                        jnp.asarray(r.sino)[None])
-                    results.append(ReconResult(
-                        image=np.asarray(out.image)[0],
-                        iterations=out.iterations,
-                        residual_history=np.asarray(out.residual_history)[0]))
-                    errors.append(None)
-                except Exception as e:            # noqa: BLE001
-                    results.append(None)
-                    errors.append(f"{type(e).__name__}: {e}")
-                self._log(key, [r], 1, t0, errors[-1], rerun=True)
+                with obs.span("serve.dispatch", rids=[r.rid],
+                              rerun=True) as sp:
+                    try:
+                        out = self._executor(key, 1)(
+                            jnp.asarray(r.sino)[None])
+                        results.append(ReconResult(
+                            image=np.asarray(out.image)[0],
+                            iterations=out.iterations,
+                            residual_history=np.asarray(
+                                out.residual_history)[0]))
+                        errors.append(None)
+                    except Exception as e:        # noqa: BLE001
+                        results.append(None)
+                        errors.append(f"{type(e).__name__}: {e}")
+                self._log(key, [r], 1, sp.seconds, errors[-1], rerun=True)
         t1 = time.perf_counter()
         for r, ts, res, err in zip(reqs, t_sub, results, errors):
             self._responses[r.rid] = ReconResponse(
@@ -298,15 +318,14 @@ class CTServer:
                 result=res, error=err, bucket=key[2],
                 batch_size=n if batch_error is None else 1,
                 latency_s=t1 - ts)
-        return True
 
     def _log(self, key: Tuple, reqs: List[ReconRequest], size: int,
-             t0: float, error: Optional[str], rerun: bool = False) -> None:
+             wall_s: float, error: Optional[str],
+             rerun: bool = False) -> None:
         self.dispatch_log.append({
             "bucket": key[2], "tier": key[0], "solver": key[1],
             "rids": [r.rid for r in reqs], "size_class": size,
-            "wall_s": time.perf_counter() - t0, "error": error,
-            "rerun": rerun})
+            "wall_s": wall_s, "error": error, "rerun": rerun})
 
     def drain(self) -> Dict[int, ReconResponse]:
         """Run steps until every queued request is answered; returns all

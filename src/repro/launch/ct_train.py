@@ -72,7 +72,7 @@ from repro.optim import (adamw, apply_updates, ema_init, ema_params,
                          ema_update, warmup_cosine)
 from repro.recon.completion import complete_and_refine, projection_residual
 from repro.runtime import checkpoint as CKPT
-from repro.runtime import compile_cache
+from repro.runtime import compile_cache, obs
 
 __all__ = ["GEOMETRIES", "TrainConfig", "CTTrainer", "build_geometry",
            "smoke_config", "main"]
@@ -354,13 +354,14 @@ class CTTrainer:
         losses = []
         t0 = time.time()
         for i in range(start, cfg.steps):
-            imgs, masks = self.pipe.batch(i)
-            gt_vol = self._as_volume(imgs)
-            sino = self.proj(gt_vol)
-            self.params, self.opt_state, self.ema, loss = self._step_fn(
-                self.params, self.opt_state, self.ema, sino,
-                jnp.asarray(masks), gt_vol)
-            loss = float(loss)
+            with obs.span("train.step", step=i):
+                imgs, masks = self.pipe.batch(i)
+                gt_vol = self._as_volume(imgs)
+                sino = self.proj(gt_vol)
+                self.params, self.opt_state, self.ema, loss = self._step_fn(
+                    self.params, self.opt_state, self.ema, sino,
+                    jnp.asarray(masks), gt_vol)
+                loss = float(loss)
             losses.append(loss)
             self.step = i + 1
             if on_step is not None:

@@ -47,18 +47,19 @@ def cgls(spec_or_projector, y, n_iters: int = 30, x0=None,
     gamma = _dot(s, s)
 
     def body(carry, _):
-        x, r, p, gamma = carry
-        q = A(p)
-        delta = _dot(q, q) + damp * _dot(p, p)
-        alpha = gamma / jnp.maximum(delta, 1e-30)
-        x = x + alpha * p
-        r = r - alpha * q
-        s = AT(r) - damp * x
-        gamma_new = _dot(s, s)
-        beta = gamma_new / jnp.maximum(gamma, 1e-30)
-        p = s + beta * p
-        res = jnp.sqrt(jnp.sum(jnp.square(r), axis=_IMG_AXES))
-        return (x, r, p, gamma_new), res
+        with jax.named_scope("cgls_iter"):
+            x, r, p, gamma = carry
+            q = A(p)
+            delta = _dot(q, q) + damp * _dot(p, p)
+            alpha = gamma / jnp.maximum(delta, 1e-30)
+            x = x + alpha * p
+            r = r - alpha * q
+            s = AT(r) - damp * x
+            gamma_new = _dot(s, s)
+            beta = gamma_new / jnp.maximum(gamma, 1e-30)
+            p = s + beta * p
+            res = jnp.sqrt(jnp.sum(jnp.square(r), axis=_IMG_AXES))
+            return (x, r, p, gamma_new), res
 
     (x, _, _, _), hist = jax.lax.scan(body, (x, r, p, gamma), None,
                                       length=n_iters)

@@ -100,16 +100,18 @@ def fista_tv(spec_or_projector, y, n_iters: int = 50, beta: float = 1e-3,
 
     def body(carry, _):
         x, z, t = carry
-        r = projector(z) - y
-        if mask is not None:
-            r = r * mask
-        g = projector.T(r)
-        xn = tv_prox(z - step * g, beta * step, tv_inner)
-        if nonneg:
-            xn = jnp.maximum(xn, 0.0)
-        tn = (1.0 + jnp.sqrt(1.0 + 4.0 * t * t)) / 2.0
-        zn = xn + ((t - 1.0) / tn) * (xn - x)
-        return (xn, zn, tn), jnp.sqrt(jnp.sum(jnp.square(r), axis=_IMG_AXES))
+        with jax.named_scope("fista_tv_iter"):
+            r = projector(z) - y
+            if mask is not None:
+                r = r * mask
+            g = projector.T(r)
+            xn = tv_prox(z - step * g, beta * step, tv_inner)
+            if nonneg:
+                xn = jnp.maximum(xn, 0.0)
+            tn = (1.0 + jnp.sqrt(1.0 + 4.0 * t * t)) / 2.0
+            zn = xn + ((t - 1.0) / tn) * (xn - x)
+            res = jnp.sqrt(jnp.sum(jnp.square(r), axis=_IMG_AXES))
+            return (xn, zn, tn), res
 
     (x, _, _), hist = jax.lax.scan(body, (x, z, t), None, length=n_iters)
     return ReconResult(image=x, iterations=n_iters,

@@ -53,13 +53,14 @@ def sirt(spec_or_projector, y, n_iters: int = 50, x0=None, lam: float = 1.0,
          if x0 is None else x0)
 
     def body(x, _):
-        r = y - projector(x)
-        if mask is not None:
-            r = r * mask
-        x = x + lam * cinv * projector.T(rinv * r)
-        if nonneg:
-            x = jnp.maximum(x, 0.0)
-        return x, _res_norm(r)
+        with jax.named_scope("sirt_iter"):
+            r = y - projector(x)
+            if mask is not None:
+                r = r * mask
+            x = x + lam * cinv * projector.T(rinv * r)
+            if nonneg:
+                x = jnp.maximum(x, 0.0)
+            return x, _res_norm(r)
 
     x, hist = jax.lax.scan(body, x, None, length=n_iters)
     return ReconResult(image=x, iterations=n_iters,

@@ -10,6 +10,7 @@ from repro.kernels import ops, tune
 from repro.launch.ct_serve import (CTServer, ReconRequest, TIER_SOLVERS,
                                    solver_tier, _size_class)
 from repro.recon import sirt
+from repro.runtime import obs
 
 
 @pytest.fixture(scope="module")
@@ -158,8 +159,10 @@ def test_executor_failure_isolates_poisoned_request(world):
 
 def test_warm_server_compiles_nothing_on_request_path(world, monkeypatch):
     """The warm-path guarantee: after warm(), traffic across every batch
-    size class triggers zero autotune sweeps and zero new op-cache entries
-    (with the tune disk cache enabled, as in production)."""
+    size class triggers zero autotune sweeps, zero new op-cache entries
+    and zero lowerings or compilations at all, of the system's programs
+    or of eager device ops (with the tune disk cache enabled, as in
+    production)."""
     monkeypatch.setenv("REPRO_TUNE_CACHE", "1")
     (s_par, y_par), (s_fan, y_fan) = world["par"], world["fan"]
     srv = CTServer(max_batch=4)
@@ -170,6 +173,7 @@ def test_warm_server_compiles_nothing_on_request_path(world, monkeypatch):
     sweeps0 = tune.sweep_count()
     stats0 = ops.cache_stats()
     executors0 = set(srv._executors)
+    compiled0 = obs.snapshot()["compile"]
 
     rids = []
     for n in (1, 2, 3, 4, 4):          # every size class, twice the largest
@@ -188,5 +192,41 @@ def test_warm_server_compiles_nothing_on_request_path(world, monkeypatch):
     assert tune.sweep_count() == sweeps0, "autotune swept on the request path"
     stats1 = ops.cache_stats()
     assert stats1["size"] == stats0["size"], "new op-cache entry built"
-    assert stats1["misses"] == stats0["misses"], "op-cache miss on request path"
+    assert stats1["misses"] == stats0["misses"], \
+        "op-cache miss on request path"
     assert set(srv._executors) == executors0, "new executor compiled"
+    compiled1 = obs.snapshot()["compile"]
+    assert compiled0["compiles"] > 0, "warm() compiled no system program"
+    assert compiled1["lowerings"] == compiled0["lowerings"], \
+        "a system program was lowered on the request path"
+    assert compiled1["compiles"] == compiled0["compiles"], \
+        "a system program was compiled on the request path"
+    other0, other1 = compiled0["other"], compiled1["other"]
+    assert other1["lowerings"] == other0["lowerings"], \
+        "an eager op or other jit was lowered on the request path"
+    assert other1["compiles"] == other0["compiles"], \
+        "an eager op or other jit was compiled on the request path"
+
+
+def test_serving_spans_carry_request_ids(world, monkeypatch):
+    """Submit, queue wait and dispatch are spans keyed by the request's
+    id, and the dispatch log's ``wall_s`` is the dispatch span's length."""
+    rec = obs.Recorder()
+    monkeypatch.setattr(obs, "_REC", rec)
+    spec, y = world["par"]
+    srv = CTServer(max_batch=4)
+    rids = [srv.submit(ReconRequest(spec=spec, sino=y, solver="fbp"))
+            for _ in range(3)]
+    srv.drain()
+    recent = obs.snapshot()["recent"]
+    by = {}
+    for r in recent:
+        by.setdefault(r["name"], []).append(r)
+    assert sorted(r["attrs"]["rid"] for r in by["serve.submit"]) == rids
+    assert sorted(r["attrs"]["rid"] for r in by["serve.queue_wait"]) == rids
+    (disp,) = by["serve.dispatch"]
+    (step,) = by["serve.step"]
+    assert sorted(disp["attrs"]["rids"]) == rids
+    assert disp["parent"] == step["id"]
+    assert srv.dispatch_log[0]["wall_s"] == disp["end"] - disp["start"]
+    assert all(w["end"] <= disp["start"] for w in by["serve.queue_wait"])

@@ -102,3 +102,75 @@ def test_pair_compiles_for_v5e(case, direction, one_chip, mosaic):
     with jax.default_matmul_precision("highest" if cdt else None):
         compiled = jax.jit(fn).lower(arg).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# The harness's own scope names: a program scope must never equal one, or
+# a reader matching whole op_name parts would take it for the harness's.
+HARNESS_SCOPES = ("fp", "residual", "bp", "fbp")
+SCOPED = {
+    "parallel": {"geom_type": "parallel",
+                 "volume": {"nx": 8, "ny": 8, "nz": 8},
+                 "n_angles": 8, "n_rows": 8, "n_cols": 16,
+                 "angular_range": 180.0},
+    "cone": {"geom_type": "cone", "volume": {"nx": 8, "ny": 8, "nz": 8},
+             "n_angles": 2, "n_rows": 8, "n_cols": 16,
+             "pixel_width": 2.0, "pixel_height": 2.0, "sod": 32.0,
+             "sdd": 64.0, "detector_type": "flat",
+             "angles": [0.6283185307179586, 1.2566370614359172]},
+}
+STAGES = {
+    "parallel": {f"sf_par_{d}_{s}" for d in ("fp", "bp")
+                 for s in ("axial", "pre", "g0", "g1", "post")},
+    "cone": {f"sf_cone_{d}_{s}" for d in ("fp", "bp")
+             for s in ("pre", "g0", "g1", "post")},
+    "fbp": {"fbp_filter", "fbp_interp", "fbp_zcontract", "fbp_accum"},
+}
+
+
+@pytest.mark.parametrize("kind", sorted(STAGES))
+def test_device_scopes_name_the_stages(kind, one_chip, mosaic):
+    """The chip's compiled program of a toy projector step (under the
+    harness's scopes ``fp``, ``residual``, ``bp``) and of a toy FBP (under
+    ``fbp``) carries each program stage's scope in its ops' ``op_name``,
+    names each kernel by its pair, direction and view group, and has no
+    program scope equal to a harness scope."""
+    import re
+    from repro.core.fbp import fbp
+    from repro.core.geometry import from_config
+    from repro.core.projector import Projector
+    geom = from_config(SCOPED["parallel" if kind == "fbp" else kind])
+    if kind == "fbp":
+        def fn(p):
+            with jax.named_scope("fbp"):
+                return fbp(p, geom)
+        args = (geom.sino_shape,)
+    else:
+        proj = Projector(ProjectorSpec(geom, backend="pallas",
+                                       mode="exact"))
+
+        def fn(x, b):
+            with jax.named_scope("fp"):
+                s = proj(x)
+            with jax.named_scope("residual"):
+                r = s - b
+            with jax.named_scope("bp"):
+                return r, proj.T(r)
+        args = (geom.vol.shape, geom.sino_shape)
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in args]
+    with jax.default_matmul_precision("highest"):
+        hlo = jax.jit(fn).lower(*args).compile().as_text()
+    parts = set()
+    names = re.findall(r'op_name="([^"]*)"', hlo)
+    for op_name in (n for joined in names for n in joined.split(";")):
+        split = op_name.split("/")
+        first = [i for i, p in enumerate(split) if p in HARNESS_SCOPES]
+        below = split[first[0] + 1:] if first else []   # else an argument
+        assert not set(below) & set(HARNESS_SCOPES), op_name
+        parts.update(below)
+    assert STAGES[kind] <= parts, STAGES[kind] - parts
+    kernels = re.findall(r'^\s*(?:ROOT\s+)?%?([\w\-]+)\.\d+ = \S+ custom-call'
+                         r'.*custom_call_target="tpu_custom_call"', hlo,
+                         re.M)
+    assert sorted(kernels) == sorted(s for s in STAGES[kind]
+                                     if s[-2:] in ("g0", "g1"))
