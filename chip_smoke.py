@@ -59,7 +59,7 @@ ROOT = pathlib.Path(__file__).resolve().parent
 ORACLE_VIEWS = 4
 # Every VIEW_STRIDE-th view of these geometries runs on the kernels: the
 # full view counts would take most of the smoke's time budget (the exact
-# cone FP at 512^3 takes ~3 s per view on a v5e).
+# cone FP and BP at 512^3 take ~0.5 s per view on a v5e).
 VIEW_STRIDE = {"cone": 18, "helical": 4}
 # The cone oracles' adjoint on ORACLE_VIEWS views needs 9.4 GB at 256^3 and
 # more than the chip's 16 GB from 320^3 up: both cone pairs are compared at

@@ -1,30 +1,32 @@
 """Pallas TPU kernel: cone-beam (flat detector) Separable-Footprint forward
-projection.
+projection and its exact transpose.
 
-Same TPU-native pattern as the parallel kernel (``fp_par.py``): per program a
-``(BU columns) x (BV rows)`` output tile for one view; loop over the volume
-loop-axis; per step, a W-wide window along the gathered axis.  Two cone-beam
-specifics:
+Both kernels walk the volume in the BP's order: per program one
+``(bg gathered elements, nz)`` tile of a volume line ``li`` and one view's
+``bv`` detector rows.  Two cone-beam specifics:
 
 * the transaxial footprint is the *exact corner projection* trapezoid —
   ``u = sdd * q / ell`` with q, ell affine in the voxel index, evaluated for
-  the four voxel corners and sorted with min/max ops (all vectorized over W);
-* the axial footprint magnifies per gathered element: for each window
-  element w, the BV detector rows pull from a z-window of the volume line
-  via an on-the-fly (BV x NZW) rect-overlap matrix (iota-built) and one MXU
-  matvec — this is the per-element axial resample that makes cone beams
-  non-separable on TPU (DESIGN.md §2).
+  the four voxel corners and sorted with min/max ops (``_corner_trapezoid``,
+  shared with the fan kernels), over a ``Wu``-wide detector-column window;
+* the axial footprint magnifies per gathered element: each element's
+  ``(bv, nz)`` rect-overlap matrix (iota-built, times the obliquity) maps
+  its z line onto the ``bv`` detector rows on the MXU — the per-element
+  axial resample that makes cone beams non-separable on TPU (DESIGN.md §2).
 
-Backprojection (``bp_cone_sf_pallas``) is the *exact transpose* of the
-forward kernel, so the registered pair is matched on-kernel end to end:
+``_tile_footprint`` and ``_axial_overlap`` derive both for the forward and
+the backprojection kernel, so the registered pair is matched on-kernel
+term by term:
 
-* transaxial: the same corner-projected trapezoid breakpoints
-  (``_corner_trapezoid``, shared between FP/BP and the fan kernels),
-  contracted in the transposed direction — a (BG, Wu) weight tile against a
-  (Wu, BV) sinogram window gathered with a scalar-prefetched ``pl.ds``;
-* axial: the per-element rect-overlap matvec runs in the adjoint direction —
-  each gathered element's (BV, nz) overlap matrix maps its u-contracted
-  detector rows back onto the volume's z lanes on the MXU.
+* FP (``fp_cone_sf_pallas``): the axial matrices contract each element's z
+  line into detector rows, then the (bg, Wu) weights scatter the rows into
+  the view's resident (NU, bv) detector stripe;
+* BP (``bp_cone_sf_pallas``): the weights gather a (Wu, bv) sinogram
+  window into each element's rows, then the axial matrices map the rows
+  back onto the z lanes.
+
+Each axial matrix is built once per (view, line, gathered element, v tile)
+in either direction.
 
 ``bp_cone_sf_ref`` (the jnp-oracle adjoint) is kept as the cross-check
 oracle for ``tests/test_kernels.py``.
@@ -45,7 +47,8 @@ approximation carries a derived per-geometry error bound
 (``cone_packed_error_bound``) that gates ``mode="auto"`` dispatch in
 ``repro.kernels.ops`` (see docs/KERNELS.md "Packed cone pair").
 
-Tile sizes come from :mod:`repro.kernels.tune` (``KernelConfig``).
+Tile sizes come from :mod:`repro.kernels.tune` (``KernelConfig``; the exact
+pair shares ``bg`` and ``bv``).
 
 Device names, as in ``fp_par.py``: the exact pair's stages run under
 ``sf_cone_{fp,bp}_pre`` and ``_post`` scopes and its kernels are named
@@ -83,8 +86,8 @@ def _mag_bounds(geom: CTGeometry) -> Tuple[float, float]:
 
 def _u_window_size_div(geom: CTGeometry, bg: int) -> int:
     """Static bound on the detector-column window covering one bg voxel tile
-    for a *divergent* (fan / cone transaxial) beam (BP kernels), before
-    alignment (``tune.aligned_window``).
+    for a *divergent* (fan / cone transaxial) beam (the BP kernels and the
+    exact cone FP), before alignment (``tune.aligned_window``).
     |duc/dgi| <= sqrt(2) * dx * mag_max and one voxel footprint spans
     <= sqrt(2) * dx * mag_max; curved (fan) footprints are never wider."""
     du, dx = geom.pixel_width, geom.vol.dx
@@ -199,76 +202,109 @@ def _view_params_cone(geom: CTGeometry) -> Tuple[np.ndarray, np.ndarray,
     return px[idx_x], py[idx_y], np.concatenate([idx_x, idx_y])
 
 
-def _fp_cone_kernel(params_ref,        # SMEM (n_views, 20)
-                    f_ref,             # VMEM (NG, NZ) volume line plane
-                    out_ref,           # VMEM (1, BU, BV) sino tile
-                    *, W: int, u0: float, du: float,
-                    v0: float, dv: float, z0c: float, dz: float,
-                    sdd: float, dxv: float, ng: int, nz: int,
-                    bu: int, bv: int, nav: int, align: int):
-    a = pl.program_id(0)
-    ub = pl.program_id(1)
-    vb = pl.program_id(2)
-    li = pl.program_id(3)
+def _tile_footprint(P, gi0, lif, *, bg: int, Wu: int, u0: float, du: float,
+                    sdd: float, dxv: float, zt, nu: int, align: int):
+    """Everything the exact cone FP and BP derive for one (view, line li,
+    gathered tile starting at ``gi0``), from one set of expressions so the
+    pair stays an exact transpose:
 
-    @pl.when(li == 0)
+    * ``ustart``: start of the ``Wu``-wide detector-column window that holds
+      the tile's footprints (centre projections of its first and last
+      voxel, aligned by ``tune.window_start``);
+    * ``wgt`` (bg, Wu): the corner-projected trapezoid weights of each
+      gathered element over the window's columns;
+    * ``mag`` (bg, 1) and ``obl`` (bg, nz): each element's axial
+      magnification and the obliquity of each of its z voxels."""
+    Aq, Bq, Cq, Al, Bl, Cl = P[:6]
+    q0 = Bq * lif + Cq
+    l0 = Bl * lif + Cl
+
+    def uc_of(gi):
+        qg = Aq * gi + q0
+        lg = jnp.maximum(Al * gi + l0, _EPS)
+        return sdd * qg / lg
+
+    ustart = tune.window_start((uc_of(gi0) - u0) / du,
+                               (uc_of(gi0 + (bg - 1)) - u0) / du,
+                               Wu, nu, align)
+    gi_abs = gi0 + tune.iota_f32((bg, 1), 0)                 # (bg, 1)
+    t0, t1, t2, t3, h, rt2 = _corner_trapezoid(
+        P, gi_abs, q0, l0, lif, sdd, dxv)                    # (bg, 1)
+    uk = u0 + (ustart + tune.iota_f32((1, Wu), 1)) * du
+    el = uk - du / 2.0                                       # (1, Wu)
+    wgt = trapezoid_pixel_weight(el, el + du, t0, t1, t2, t3, h)
+    mag = sdd / jnp.maximum(Al * gi_abs + l0, _EPS)          # (bg, 1)
+    obl = jnp.sqrt(1.0 + (zt * zt) / jnp.maximum(rt2, _EPS))  # (bg, nz)
+    return ustart, wgt, mag, obl
+
+
+def _axial_overlap(mag_g, obl_g, zt, elv, dz: float, dv: float):
+    """(bv, nz) axial resample of one gathered element: the rect overlap of
+    each z voxel, magnified by ``mag_g`` (1, 1), with each of the tile's
+    ``bv`` detector rows (lower edges ``elv`` (bv, 1)), times the voxel's
+    obliquity ``obl_g`` (1, nz).  The overlap spans the whole z line
+    (lanes): Mosaic slices lanes only at static or 128-aligned offsets."""
+    vlo = (zt - dz / 2.0) * mag_g                            # (1, nz)
+    vhi = (zt + dz / 2.0) * mag_g
+    ov = jnp.maximum(jnp.minimum(vhi, elv + dv)
+                     - jnp.maximum(vlo, elv), 0.0) / dv      # (bv, nz)
+    return ov * obl_g
+
+
+def _fp_cone_kernel(params_ref,        # SMEM (n_views, 20)
+                    f_ref,             # VMEM (bg, nz) volume tile (z lanes)
+                    out_ref,           # VMEM (1, NU, bv) u-major sino stripe
+                    *, Wu: int, u0: float, du: float, v0: float, dv: float,
+                    z0c: float, dz: float, sdd: float, dxv: float,
+                    nu: int, nz: int, bg: int, bv: int, nav: int,
+                    align: int):
+    """One program: scatter one (bg gathered elements, nz) volume tile of
+    line ``li`` into one view's resident (NU, bv) detector stripe — the
+    exact transpose of ``_bp_cone_kernel``:
+
+    * axial: each gathered element's (bv, nz) overlap matrix (built once
+      per view, v tile and element, as in the BP) maps its z line onto the
+      tile's ``bv`` detector rows on the MXU;
+    * transaxial: the (bg, Wu) corner-projected weights, contracted over
+      the gathered elements, add the rows into the ``Wu``-column window.
+    """
+    a = pl.program_id(0)
+    vb = pl.program_id(1)
+    li = pl.program_id(2)
+    gt = pl.program_id(3)
+
+    @pl.when((li == 0) & (gt == 0))
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
     # Batched runs fold the batch into the view grid axis; the params table
     # stays (n_views, 20) in SMEM and the view index wraps per sample.
-    av = jax.lax.rem(a, nav)
-    P = [params_ref[av, i] for i in range(20)]
-    Aq, Bq, Cq, Al, Bl, Cl = P[:6]
+    P = [params_ref[jax.lax.rem(a, nav), i] for i in range(20)]
     lif = li.astype(jnp.float32)
-    u_first = u0 + (ub * bu) * du
-    u_last = u_first + (bu - 1) * du
-
-    # window start: invert u = sdd*(Aq*gi + q0)/(Al*gi + l0)
-    q0 = Bq * lif + Cq
-    l0 = Bl * lif + Cl
-
-    def gi_of(u):
-        den = sdd * Aq - u * Al
-        den = jnp.where(jnp.abs(den) > 1e-6, den, 1e-6)
-        return (u * l0 - sdd * q0) / den
-
-    g1, g2 = gi_of(u_first), gi_of(u_last)
-    start = tune.window_start(g1, g2, W, ng, align)
-
-    win = f_ref[pl.ds(start, W), :]                          # (W, nz)
-    gi = start + tune.iota_f32((1, W), 1)                    # (1, W)
-    # corner projections -> sorted trapezoid breakpoints (shared with BP)
-    t0, t1, t2, t3, h, rt2 = _corner_trapezoid(P, gi, q0, l0, lif, sdd, dxv)
-
-    uk = u_first + du * tune.iota_f32((bu, 1), 0)
-    el = uk - du / 2.0
-    wu = trapezoid_pixel_weight(el, el + du, t0, t1, t2, t3, h)  # (bu, W)
-
-    ell = jnp.maximum(Al * gi + l0, _EPS)
-    mag = sdd / ell                                          # (1, W)
+    gi0 = (gt * bg).astype(jnp.float32)
     elv = v0 + (vb * bv) * dv - dv / 2.0 + dv * tune.iota_f32((bv, 1), 0)
     zt = z0c + dz * tune.iota_f32((1, nz), 1)                # (1, nz)
+    ustart, wgt, mag, obl = _tile_footprint(
+        P, gi0, lif, bg=bg, Wu=Wu, u0=u0, du=du, sdd=sdd, dxv=dxv, zt=zt,
+        nu=nu, align=align)
 
-    acc = jnp.zeros((bu, bv), jnp.float32)
-    for w in range(W):
-        # The axial overlap spans the whole z line (lanes): Mosaic slices
-        # lanes only at static or 128-aligned offsets.
-        mag_w = mag[:, w:w + 1]                              # (1, 1)
-        vlo = (zt - dz / 2.0) * mag_w                        # (1, nz)
-        vhi = (zt + dz / 2.0) * mag_w
-        ov = jnp.maximum(jnp.minimum(vhi, elv + dv)
-                         - jnp.maximum(vlo, elv), 0.0) / dv  # (bv, nz)
-        obl = jnp.sqrt(1.0 + (zt * zt) / jnp.maximum(rt2[:, w:w + 1], 1e-9))
-        fw = win[w:w + 1, :]                                 # (1, nz)
-        rv = precision.mxu_dot(fw, precision.cast_like(ov * obl, fw),
-                               ((1,), (1,)))                 # (1, bv)
-        acc = acc + wu[:, w:w + 1] * rv
-    precision.store_tile(out_ref, 0, acc)
+    # Per-element axial resample; row g of the tile is contracted alone by
+    # masking the others.
+    fv = f_ref[...]                                          # (bg, nz)
+    row = jax.lax.broadcasted_iota(jnp.int32, (bg, 1), 0)
+    rows = jnp.zeros((bg, bv), jnp.float32)
+    for g in range(bg):
+        ovo = _axial_overlap(mag[g:g + 1, :], obl[g:g + 1, :], zt, elv,
+                             dz, dv)                         # (bv, nz)
+        rows = rows + precision.mxu_dot(
+            jnp.where(row == g, fv, 0.0), precision.cast_like(ovo, fv),
+            ((1,), (1,)))                                    # (bg, bv)
+    precision.store_tile(out_ref, (0, pl.ds(ustart, Wu), slice(None)),
+                         precision.mxu_dot(wgt, rows, ((0,), (0,))))
 
 
 def _run_group(fb, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
-               bu: int, bv: int):
+               bg: int, bv: int):
     """fb: (B, nx, ny, nz) batch of volumes.  The batch is folded into the
     view grid axis: grid step ``a`` covers view ``a % na`` of sample
     ``a // na`` (volumes stacked along the gathered axis; the SMEM params
@@ -277,51 +313,55 @@ def _run_group(fb, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
         return None
     vol = geom.vol
     B = fb.shape[0]
+    align = tune.sublane_tile(fb.dtype)
     with jax.named_scope("sf_cone_fp_pre"):
-        fs, ng = _line_major_batch(fb, gathered_x)
+        # Pad the gathered axis to whole bg tiles (and the sublane tile, so
+        # the line-major layout adds no padding of its own).
+        ax = 1 if gathered_x else 2
+        pad = [(0, 0)] * 4
+        pad[ax] = (0, tune.round_up(fb.shape[ax], math.lcm(bg, align))
+                   - fb.shape[ax])
+        fs, ngp = _line_major_batch(jnp.pad(fb, pad), gathered_x)
     nl, _, nz = fs.shape
     na = params.shape[0]
-    nup = tune.round_up(geom.n_cols, bu)
+    ngb = ngp // bg
+    nu_ = tune.round_up(geom.n_cols, align)
     nvp = tune.round_up(geom.n_rows, bv)
-    mag_min, mag_max = _mag_bounds(geom)
-    span = bu * geom.pixel_width * math.sqrt(2.0) / (vol.dx * mag_min)
-    margin = 2.0 * (math.sqrt(2.0) * vol.dx * mag_max
-                    + geom.pixel_width) / (vol.dx * mag_min) + 4.0
-    align = tune.sublane_tile(fs.dtype)
-    W = tune.aligned_window(int(math.ceil(span + 2 * margin)) + 2, ng, align)
+    Wu = tune.aligned_window(_u_window_size_div(geom, bg), nu_, align)
     kernel = functools.partial(
-        _fp_cone_kernel, W=W,
+        _fp_cone_kernel, Wu=Wu,
         u0=float(geom.u_coords()[0]), du=geom.pixel_width,
         v0=float(geom.v_coords()[0]), dv=geom.pixel_height,
-        z0c=float(vol.z_coords()[0]), dz=vol.dz,
-        sdd=geom.sdd, dxv=vol.dx, ng=ng, nz=nz, bu=bu, bv=bv, nav=na,
-        align=align)
+        z0c=float(vol.z_coords()[0]), dz=vol.dz, sdd=geom.sdd, dxv=vol.dx,
+        nu=nu_, nz=nz, bg=bg, bv=bv, nav=na, align=align)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B * na, nup // bu, nvp // bv, nl),
-            in_specs=[pl.BlockSpec((None, ng, nz),
-                                   lambda a, ub, vb, l, *_: (l, a // na, 0))],
-            out_specs=pl.BlockSpec((1, bu, bv),
-                                   lambda a, ub, vb, l, *_: (a, ub, vb)),
+            grid=(B * na, nvp // bv, nl, ngb),
+            in_specs=[pl.BlockSpec((None, bg, nz),
+                                   lambda a, vb, l, gt, *_:
+                                   (l, a // na * ngb + gt, 0))],
+            out_specs=pl.BlockSpec((1, nu_, bv),
+                                   lambda a, vb, l, gt, *_: (a, 0, vb)),
         ),
         # output buffer is the cross-step accumulator: always f32
-        out_shape=jax.ShapeDtypeStruct((B * na, nup, nvp), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B * na, nu_, nvp), jnp.float32),
         interpret=tune._interpret(),
         name=f"sf_cone_fp_g{0 if gathered_x else 1}",
     )(jnp.asarray(params), fs)
     with jax.named_scope("sf_cone_fp_post"):
-        return out.reshape(B, na, nup, nvp)
+        return out.reshape(B, na, nu_, nvp)
 
 
 @obs.spanned("kernels.fp_cone")
-def fp_cone_sf_pallas(f, geom: CTGeometry, bu: Optional[int] = None,
+def fp_cone_sf_pallas(f, geom: CTGeometry, bg: Optional[int] = None,
                       bv: Optional[int] = None,
                       config: Optional[tune.KernelConfig] = None,
                       compute_dtype=None):
     """f: (nx, ny, nz) -> sino (n_angles, n_rows, n_cols), or batched
-    f: (batch, nx, ny, nz) -> (batch, ...).  Flat detector."""
+    f: (batch, nx, ny, nz) -> (batch, ...).  Flat detector.  ``bg`` tiles
+    the gathered volume axis, as in the BP this kernel is the transpose of."""
     if geom.geom_type != "cone" or geom.detector_type != "flat":
         raise ValueError(
             f"fp_cone_sf_pallas needs a flat-detector cone geometry, got "
@@ -336,13 +376,13 @@ def fp_cone_sf_pallas(f, geom: CTGeometry, bu: Optional[int] = None,
     with jax.named_scope("sf_cone_fp_pre"):
         fb = precision.cast_in(f if batched else f[None], cdt)
     cfg = tune.resolve_config(geom, fb.shape[0], config, dtype=cdt,
-                              bu=bu, bv=bv)
+                              bg=bg, bv=bv)
     px, py, order = _view_params_cone(geom)
     outs = []
-    o1 = _run_group(fb, px, geom, True, cfg.bu, cfg.bv)
+    o1 = _run_group(fb, px, geom, True, cfg.bg, cfg.bv)
     if o1 is not None:
         outs.append(o1)
-    o2 = _run_group(fb, py, geom, False, cfg.bu, cfg.bv)
+    o2 = _run_group(fb, py, geom, False, cfg.bg, cfg.bv)
     if o2 is not None:
         outs.append(o2)
     with jax.named_scope("sf_cone_fp_post"):
@@ -367,12 +407,10 @@ def _bp_cone_kernel(params_ref,        # SMEM (n_views, 20)
     (bg gathered elements, nz) volume tile — the exact transpose of
     ``_fp_cone_kernel``:
 
-    * transaxial: the same corner-projected breakpoints, contracted in the
-      transposed direction ((bg, Wu) weights x (Wu, bv) sinogram window);
-    * axial: each gathered element's (bv, nz) rect-overlap matrix (same
-      iota construction as the forward's z-window, evaluated over the full
-      z line since the output lanes *are* z) maps its u-contracted detector
-      rows back onto the volume line via one MXU matvec per element.
+    * transaxial: the same (bg, Wu) corner-projected weights gather the
+      (Wu, bv) sinogram window into each element's detector rows;
+    * axial: each gathered element's (bv, nz) overlap matrix maps its rows
+      back onto the volume line's z lanes via one MXU matvec per element.
     """
     gall = pl.program_id(0)
     li = pl.program_id(1)
@@ -387,53 +425,28 @@ def _bp_cone_kernel(params_ref,        # SMEM (n_views, 20)
     # Batched runs fold the batch into the gathered-output grid axis; the
     # params table stays (n_views, 20) in SMEM shared across samples.
     gi0 = (jax.lax.rem(gall, ngb) * bg).astype(jnp.float32)
-    gi_abs = gi0 + tune.iota_f32((bg, 1), 0)                 # (bg, 1)
     elv = v0 + (vb * bv) * dv - dv / 2.0 + dv * tune.iota_f32((bv, 1), 0)
     zt = z0c + dz * tune.iota_f32((1, nz), 1)                # (1, nz)
     row = jax.lax.broadcasted_iota(jnp.int32, (bg, 1), 0)
 
     acc = jnp.zeros((bg, nz), jnp.float32)
     for j in range(bab):
-        a = ab * bab + j
-        P = [params_ref[a, i] for i in range(20)]
-        Aq, Bq, Cq, Al, Bl, Cl = P[:6]
-        q0 = Bq * lif + Cq
-        l0 = Bl * lif + Cl
-
-        # window start: center projection u(gi) over the gathered tile
-        def uc_of(gi):
-            qg = Aq * gi + q0
-            lg = jnp.maximum(Al * gi + l0, _EPS)
-            return sdd * qg / lg
-
-        uc_a = uc_of(gi0)
-        uc_b = uc_of(gi0 + (bg - 1))
-        ustart = tune.window_start((uc_a - u0) / du, (uc_b - u0) / du,
-                                   Wu, nu, align)
-
+        P = [params_ref[ab * bab + j, i] for i in range(20)]
+        ustart, wgt, mag, obl = _tile_footprint(
+            P, gi0, lif, bg=bg, Wu=Wu, u0=u0, du=du, sdd=sdd, dxv=dxv, zt=zt,
+            nu=nu, align=align)
         qwin = q_ref[j, pl.ds(ustart, Wu), :]                # (Wu, bv)
-        t0, t1, t2, t3, h, rt2 = _corner_trapezoid(
-            P, gi_abs, q0, l0, lif, sdd, dxv)                # (bg, 1)
-        uk = u0 + (ustart + tune.iota_f32((1, Wu), 1)) * du
-        el = uk - du / 2.0                                   # (1, Wu)
-        wgt = trapezoid_pixel_weight(el, el + du, t0, t1, t2, t3, h)
         rows = precision.mxu_dot(precision.cast_like(wgt, qwin),
                                  qwin)                       # (bg, bv)
         # Transposed per-element axial resample: every gathered element has
         # its own magnification, so its bv u-contracted detector rows map
         # through an element-specific (bv, nz) overlap matrix onto z lanes.
         # Row g of the tile is contracted alone by masking the others.
-        mag = sdd / jnp.maximum(Al * gi_abs + l0, _EPS)      # (bg, 1)
-        obl = jnp.sqrt(1.0 + (zt * zt) / jnp.maximum(rt2, _EPS))  # (bg, nz)
         for g in range(bg):
-            mag_g = mag[g:g + 1, :]                          # (1, 1)
-            vlo = (zt - dz / 2.0) * mag_g                    # (1, nz)
-            vhi = (zt + dz / 2.0) * mag_g
-            ov = jnp.maximum(jnp.minimum(vhi, elv + dv)
-                             - jnp.maximum(vlo, elv), 0.0) / dv   # (bv, nz)
             acc = acc + precision.mxu_dot(
                 jnp.where(row == g, rows, 0.0),
-                ov * obl[g:g + 1, :])                        # (bg, nz)
+                _axial_overlap(mag[g:g + 1, :], obl[g:g + 1, :], zt, elv,
+                               dz, dv))                      # (bg, nz)
     precision.store_tile(out_ref, slice(None), acc)
 
 
@@ -496,10 +509,9 @@ def bp_cone_sf_pallas(sino, geom: CTGeometry, bg: Optional[int] = None,
     """sino: (n_angles, n_rows, n_cols) -> volume (nx, ny, nz), or batched
     sino: (batch, ...) -> (batch, nx, ny, nz).  Flat detector.
 
-    Exact transpose of ``fp_cone_sf_pallas`` (incl. the batched path): same
-    corner-projection trapezoid via the transposed contraction, and the
-    per-element axial rect-overlap matvec applied in the adjoint direction
-    (detector rows -> volume z lanes)."""
+    Exact transpose of ``fp_cone_sf_pallas`` (incl. the batched path): the
+    same window, trapezoid weights and per-element axial overlap matrices,
+    contracted in the adjoint direction (detector -> volume)."""
     if geom.geom_type != "cone" or geom.detector_type != "flat":
         raise ValueError(
             f"bp_cone_sf_pallas needs a flat-detector cone geometry, got "

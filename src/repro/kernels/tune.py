@@ -2,13 +2,15 @@
 
 The Pallas projector kernels are parameterized by six tile sizes:
 
-    bu   FP: detector-column tile (sublane axis of the output tile)
+    bu   FP: detector-column tile (sublane axis of the output tile); the
+         exact cone FP has none (it tiles the gathered axis by ``bg``)
     bv   lane tile — the 128-wide axis.  With lane packing this axis holds
          ``batch * n_rows`` detector-row lanes, so thin-z training batches
          fill the MXU instead of padding it.
     ba   FP: views per program.  The volume line (the dominant HBM stream)
          is fetched once per program and reused for ``ba`` views.
-    bg   BP: gathered-axis (voxel) tile.
+    bg   BP: gathered-axis (voxel) tile; also the exact cone FP's tile,
+         as that FP is the cone BP's transpose.
     bab  BP: views per program — one wide sinogram-stripe DMA and a single
          output-tile accumulation per ``bab`` views.
     bs   BP: stripe reuse — gathered-axis sub-tiles served per sinogram
@@ -85,10 +87,10 @@ _SUBLANE = 8
 class KernelConfig:
     """Tile/block sizes for one (geometry-shape-class, kernel, dtype)."""
 
-    bu: int = 16     # FP detector-column tile
+    bu: int = 16     # FP detector-column tile (not the exact cone FP)
     bv: int = LANE   # lane tile (packed batch * detector rows)
     ba: int = 1      # FP views per program
-    bg: int = 16     # BP gathered-axis tile
+    bg: int = 16     # BP (and exact cone FP) gathered-axis tile
     bab: int = 1     # BP views per program
     bs: int = 1      # BP gathered sub-tiles per stripe residency (reuse)
 
@@ -323,8 +325,9 @@ def heuristic_config(geom: CTGeometry, batch: int = 1,
         # pre-resampled outside): fan tiles, full 128-lane packing.
         bu = max(8, bu // 2)
     elif geom.geom_type in ("cone", "modular"):
-        # The cone/modular kernels' gathered-axis window W grows with bu and
-        # is walked by an inner loop — keep the column tile small.
+        # The modular FP's gathered-axis window W grows with bu and is
+        # walked by an inner loop — keep the column tile small.  The exact
+        # cone pair takes bg = bu below for both of its kernels.
         bu = 8
         # Cone/modular kernels tile *physical* detector rows on the v axis
         # (no lane packing; the BP's lane axis is z) — pad rows to the
@@ -500,16 +503,14 @@ def autotune(geom: CTGeometry, batch: int = 1, dtype=jnp.float32,
         from repro.kernels import fp_cone
         fp_fn, bp_fn = fp_cone.fp_cone_packed, fp_cone.bp_cone_packed
     elif geom.geom_type == "cone":
-        # Cone has no FP view-blocking knob (views fold into the grid) but
-        # a full Pallas BP: sweep the FP column tile and the BP (bg, bab).
-        from repro.kernels import fp_cone
-        return _autotune_viewfold(geom, batch, dtype, cand, reps, key,
-                                  fp_cone.fp_cone_sf_pallas,
-                                  fp_cone.bp_cone_sf_pallas)
+        # The exact cone FP is the BP's transpose and shares its gathered
+        # tile bg (views fold into the grid, no FP view block): sweep
+        # (bg, bab) on the pair.
+        return _autotune_cone(geom, batch, dtype, cand, reps, key)
     elif geom.geom_type == "modular":
-        # Modular is structurally the exact cone pair (grid-folded views,
-        # per-view frames prefetched): the same FP-bu x BP-(bg, bab) sweep
-        # on the modular entry points.
+        # Modular folds views into the grid like the exact cone pair, but
+        # its FP gathers a window per column tile: sweep FP-bu x
+        # BP-(bg, bab) on the modular entry points.
         from repro.kernels import fp_modular
         return _autotune_viewfold(geom, batch, dtype, cand, reps, key,
                                   fp_modular.fp_modular_sf_pallas,
@@ -527,11 +528,7 @@ def autotune(geom: CTGeometry, batch: int = 1, dtype=jnp.float32,
     fp_grid = sorted({(c.bu, c.ba) for c in cand})
     bp_grid = sorted({(c.bg, c.bab, c.bs) for c in cand})
 
-    shape = ((batch,) if batch > 1 else ()) + geom.vol.shape
-    f = jnp.ones(shape, dtype)
-    sshape = ((batch,) if batch > 1 else ()) + geom.sino_shape
-    y = jnp.ones(sshape, dtype)
-
+    f, y = _probe_inputs(geom, batch, dtype)
     bu, ba = _sweep(fp_fn, geom, f, reps, "FP",
                     {(bu, ba): KernelConfig(bu=bu, ba=ba)
                      for bu, ba in fp_grid})
@@ -544,19 +541,47 @@ def autotune(geom: CTGeometry, batch: int = 1, dtype=jnp.float32,
     return cfg
 
 
+def _probe_inputs(geom: CTGeometry, batch: int, dtype):
+    """A volume and a sinogram of ones (batched where ``batch > 1``) to
+    time candidate configs on."""
+    lead = (batch,) if batch > 1 else ()
+    return (jnp.ones(lead + geom.vol.shape, dtype),
+            jnp.ones(lead + geom.sino_shape, dtype))
+
+
+def _autotune_cone(geom: CTGeometry, batch: int, dtype, cand, reps: int,
+                   key: Tuple) -> KernelConfig:
+    """Sweep for the exact cone pair: its FP is the BP's transpose and
+    tiles the gathered axis by the same ``bg``, so each (bg, bab) candidate
+    is timed on FP and BP together.  ``bv`` stays on the heuristic, as in
+    :func:`_autotune_viewfold`."""
+    from repro.kernels import fp_cone                 # late: avoid cycle
+
+    def pair(xy, geom, config):
+        return (fp_cone.fp_cone_sf_pallas(xy[0], geom, config=config),
+                fp_cone.bp_cone_sf_pallas(xy[1], geom, config=config))
+
+    base = heuristic_config(geom, batch, dtype)
+    bg, bab = _sweep(pair, geom, _probe_inputs(geom, batch, dtype), reps,
+                     "FP+BP",
+                     {(bg, bab): base.replace(bg=bg, bab=bab)
+                      for bg, bab in sorted({(c.bg, c.bab) for c in cand})})
+    cfg = base.replace(ba=1, bg=bg, bab=bab)
+    _AUTOTUNED[key] = cfg
+    save_tuned(key, cfg)
+    return cfg
+
+
 def _autotune_viewfold(geom: CTGeometry, batch: int, dtype, cand, reps: int,
                        key: Tuple, fp_fn, bp_fn) -> KernelConfig:
-    """Sweep for the grid-folded-view kernels (exact cone, modular): FP
-    column tile (bu) + BP gathered tile / view block (bg, bab), mirroring
-    the fan/parallel sweep.  The row tile bv stays on the heuristic (it
+    """Sweep for the modular pair's grid-folded-view kernels: FP column
+    tile (bu) + BP gathered tile / view block (bg, bab), mirroring the
+    fan/parallel sweep.  The row tile bv stays on the heuristic (it
     tiles physical detector rows, whose count the shape class already
     encodes); there is no FP ``ba`` knob — views fold into the grid — and
     ``bs`` is not swept (the view-folded BPs ignore stripe blocking)."""
     base = heuristic_config(geom, batch, dtype)
-    shape = ((batch,) if batch > 1 else ()) + geom.vol.shape
-    f = jnp.ones(shape, dtype)
-    sshape = ((batch,) if batch > 1 else ()) + geom.sino_shape
-    y = jnp.ones(sshape, dtype)
+    f, y = _probe_inputs(geom, batch, dtype)
     bu = _sweep(fp_fn, geom, f, reps, "FP",
                 {bu: base.replace(bu=bu, ba=1)
                  for bu in sorted({c.bu for c in cand})})

@@ -215,7 +215,7 @@ def test_fp_cone_matches_oracle(shape):
     g = cone_beam(na, nv, nu, VolumeGeometry(nx, ny, nz), sod=sod, sdd=sdd,
                   pixel_width=2.0, pixel_height=2.0)
     f = jax.random.normal(jax.random.PRNGKey(0), g.vol.shape)
-    _assert_close(fp_cone_sf_pallas(f, g, bu=8, bv=8),
+    _assert_close(fp_cone_sf_pallas(f, g, bg=8, bv=8),
                   ref.forward(f, g, "sf"), tol=3e-4)
 
 
@@ -289,7 +289,7 @@ def test_cone_batched_fp_matches_vmap():
     g = cone_beam(5, 8, 24, VolumeGeometry(16, 16, 8), sod=80.0, sdd=160.0,
                   pixel_width=2.0, pixel_height=2.0)
     fb = jax.random.normal(jax.random.PRNGKey(0), (3,) + g.vol.shape)
-    batched = fp_cone_sf_pallas(fb, g, bu=8, bv=8)
+    batched = fp_cone_sf_pallas(fb, g, bg=8, bv=8)
     assert batched.shape == (3,) + g.sino_shape
     oracle = jax.vmap(lambda x: ref.forward(x, g, "sf"))(fb)
     _assert_close(batched, oracle, tol=3e-4)

@@ -115,7 +115,7 @@ def test_fp_cone_matches_oracle(shape):
                   pixel_width=2.0, pixel_height=2.0)
     f = jax.random.normal(jax.random.PRNGKey(0), vol.shape)
     p_ref = ref.forward(f, g, "sf")
-    p_pal = fp_cone_sf_pallas(f, g, bu=8, bv=8)
+    p_pal = fp_cone_sf_pallas(f, g, bg=8, bv=8)
     np.testing.assert_allclose(np.asarray(p_pal), np.asarray(p_ref),
                                rtol=3e-4, atol=3e-4)
 
@@ -151,6 +151,43 @@ def test_bp_cone_view_blocked_matches_oracle():
     b_pal = bp_cone_sf_pallas(y, g, config=KernelConfig(bg=12, bv=8, bab=2))
     np.testing.assert_allclose(np.asarray(b_pal), np.asarray(b_ref),
                                rtol=3e-4, atol=3e-4)
+
+
+def test_fp_cone_padded_gathered_tile_matches_oracle():
+    """A gathered tile that divides neither the gathered axis nor the
+    sublane tile (zero-padded voxels) is exactly the unpadded math: the FP
+    twin of ``test_bp_cone_view_blocked_matches_oracle``."""
+    from repro.core.geometry import cone_beam
+    from repro.kernels.fp_cone import fp_cone_sf_pallas
+    from repro.kernels.tune import KernelConfig
+    vol = VolumeGeometry(20, 20, 8)
+    g = cone_beam(5, 8, 24, vol, sod=80.0, sdd=160.0,
+                  pixel_width=2.0, pixel_height=2.0)
+    f = jax.random.normal(jax.random.PRNGKey(0), vol.shape)
+    p_ref = ref.forward(f, g, "sf")
+    p_pal = fp_cone_sf_pallas(f, g, config=KernelConfig(bg=12, bv=8))
+    np.testing.assert_allclose(np.asarray(p_pal), np.asarray(p_ref),
+                               rtol=3e-4, atol=3e-4)
+
+
+def test_cone_pallas_fp_is_bp_transpose():
+    """The exact cone FP is the BP's transpose term by term: the dense
+    matrices built from unit vectors agree to float32 rounding."""
+    from repro.core.geometry import cone_beam
+    from repro.kernels.fp_cone import bp_cone_sf_pallas, fp_cone_sf_pallas
+    vol = VolumeGeometry(8, 8, 4)
+    g = cone_beam(3, 8, 12, vol, sod=40.0, sdd=80.0,
+                  pixel_width=2.0, pixel_height=2.0)
+    n_vox, n_pix = int(np.prod(vol.shape)), int(np.prod(g.sino_shape))
+    eye_v = jnp.eye(n_vox, dtype=jnp.float32).reshape((n_vox,) + vol.shape)
+    eye_p = jnp.eye(n_pix, dtype=jnp.float32).reshape((n_pix,) +
+                                                      g.sino_shape)
+    a_fp = np.asarray(fp_cone_sf_pallas(eye_v, g, bg=8, bv=8)).reshape(
+        n_vox, n_pix).T                                  # A[pixel, voxel]
+    a_bp = np.asarray(bp_cone_sf_pallas(eye_p, g, bg=8, bv=8)).reshape(
+        n_pix, n_vox)                                    # (A^T)^T
+    assert np.abs(a_fp).max() > 0.1 and np.count_nonzero(a_fp) > n_pix
+    np.testing.assert_allclose(a_fp, a_bp, rtol=0, atol=1e-6)
 
 
 def test_cone_pallas_pair_matched():

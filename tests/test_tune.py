@@ -90,6 +90,38 @@ def test_sweep_reports_refused_candidates_and_raises_when_all_fail():
         tune._sweep(fp, g, x, 1, "FP", {8: KernelConfig(bu=8)})
 
 
+def test_cone_autotune_sweeps_the_pairs_shared_gathered_tile(monkeypatch):
+    """The exact cone FP tiles the gathered axis by the BP's ``bg``, so the
+    cone sweep times each (bg, bab) candidate on FP and BP together and
+    keeps the winner for both."""
+    from repro.core import cone_beam
+    from repro.kernels import fp_cone
+    g = cone_beam(3, 8, 24, VolumeGeometry(8, 8, 4), sod=80.0, sdd=160.0,
+                  pixel_width=2.0, pixel_height=2.0)
+    seen = {}
+
+    def fake_sweep(fn, geom, x, reps, what, grid):
+        seen.update(fn=fn, x=x, what=what, grid=grid)
+        return (16, 2)
+
+    monkeypatch.setattr(tune, "_on_tpu", lambda: True)
+    monkeypatch.setattr(tune, "_sweep", fake_sweep)
+    monkeypatch.setattr(tune, "save_tuned", lambda key, cfg: None)
+    cand = [KernelConfig(bg=8, bab=1), KernelConfig(bg=16, bab=2)]
+    cfg = tune.autotune(g, candidates=cand)
+    assert seen["what"] == "FP+BP"
+    assert set(seen["grid"]) == {(8, 1), (16, 2)}
+    assert (cfg.bg, cfg.bab) == (16, 2)
+    assert tune.get_config(g) is cfg
+    monkeypatch.undo()                    # back to interpret mode
+    pinned = KernelConfig(bg=8, bv=8)
+    fp, bp = seen["fn"](seen["x"], g, config=pinned)
+    np.testing.assert_array_equal(
+        fp, fp_cone.fp_cone_sf_pallas(seen["x"][0], g, config=pinned))
+    np.testing.assert_array_equal(
+        bp, fp_cone.bp_cone_sf_pallas(seen["x"][1], g, config=pinned))
+
+
 def test_resolve_backend_reports_what_dispatches():
     """``backend="auto"`` picks the jnp oracle off the TPU, and for a
     geometry outside a kernel's ``supports`` gate even on it;
