@@ -54,6 +54,7 @@ from repro import compat
 from repro.core.geometry import CTGeometry
 from repro.core.spec import ProjectorSpec, ShardSpec, _warn_legacy
 from repro.kernels import ops, tune
+from repro.runtime import obs
 
 __all__ = [
     "ShardSpec",
@@ -105,6 +106,7 @@ def halo_exchange_z(f, axis: str, halo: int):
     bwd = [((i + 1) % n, i) for i in range(n)]
     from_prev = jax.lax.ppermute(hi, axis, fwd)     # neighbour below's top
     from_next = jax.lax.ppermute(lo, axis, bwd)     # neighbour above's bottom
+    obs.count("distributed.collectives", 2)
     from_prev = jnp.where(idx == 0, 0.0, from_prev)
     from_next = jnp.where(idx == n - 1, 0.0, from_next)
     return jnp.concatenate([from_prev, f, from_next], axis=2)
@@ -136,6 +138,7 @@ def halo_reduce_z(g, axis: str, halo: int):
     bwd = [((i + 1) % n, i) for i in range(n)]
     from_next = jax.lax.ppermute(lo, axis, bwd)     # neighbour above's lo
     from_prev = jax.lax.ppermute(hi, axis, fwd)     # neighbour below's hi
+    obs.count("distributed.collectives", 2)
     from_next = jnp.where(idx == n - 1, 0.0, from_next)
     from_prev = jnp.where(idx == 0, 0.0, from_prev)
     core = core.at[:, :, -halo:].add(from_next)
@@ -299,7 +302,8 @@ def _auto_comm_blocks(per: int, lgeom: CTGeometry,
     """Comm granularity for the overlap schedule: the most blocks (<= 4)
     that keep every block a whole number of ``bab`` view-blocks — the BP
     kernels' own view-blocking is the natural unit the reduction can
-    overlap."""
+    overlap.  The caller takes one block where the angle axis has one
+    shard: there is no reduction to overlap."""
     cfg = config if config is not None else tune.heuristic_config(lgeom)
     bab = max(1, cfg.bab or 1)
     for nb in (4, 3, 2):
@@ -323,11 +327,19 @@ def _validate_mesh(shard: ShardSpec, mesh: Mesh) -> None:
                 f"{ax!r} of size {int(mesh.shape[ax])}")
 
 
+@obs.spanned("distributed.build")
 def _build_distributed(spec: ProjectorSpec, mesh: Mesh):
     """Compile the sharded matched pair for ``spec`` on ``mesh``.
 
-    Returns ``(fp, bp, spec_vol, spec_sino)`` where fp/bp are a
-    ``custom_vjp`` matched pair of ``shard_map`` programs.
+    Returns ``(fp, bp, spec_vol, spec_sino, comm_blocks)`` where fp/bp are
+    a ``custom_vjp`` matched pair of ``shard_map`` programs and
+    ``comm_blocks`` the number of BP blocks per shard.
+
+    Program counters: ``distributed.local_ops`` (local op bundles asked
+    for) and ``distributed.collectives`` (``psum`` and ``ppermute`` calls
+    traced into the pair; registered at 0 here, so a layout that emits
+    none reads 0).  The shard bodies' Python tracing is timed by the spans
+    ``distributed.fp`` and ``distributed.bp``.
     """
     shard = spec.shard
     geom = spec.geom
@@ -425,21 +437,26 @@ def _build_distributed(spec: ProjectorSpec, mesh: Mesh):
 
     # ---- local op bundles ------------------------------------------------ #
     def _local_ops(g: CTGeometry):
+        obs.count("distributed.local_ops")
         return ops.get_ops(spec.replace(geom=g, shard=None))
 
-    local_fps = [_local_ops(g)[0] for g in chunk_geoms]
+    bundles = [_local_ops(g) for g in chunk_geoms]
+    local_fps = [fp for fp, _ in bundles]
 
+    reduce = na > 1
     if shard.comm == "psum":
         nb = max(1, shard.comm_blocks) if shard.comm_blocks else 1
+    elif shard.comm_blocks:
+        nb = shard.comm_blocks
     else:
-        nb = shard.comm_blocks or _auto_comm_blocks(per, chunk_geoms[0],
-                                                    spec.config)
+        nb = _auto_comm_blocks(per, chunk_geoms[0], spec.config) \
+            if reduce else 1
     if per % nb != 0:
         raise ValueError(
             f"comm_blocks={nb} must divide the per-shard view count {per}")
     blk = per // nb
     if nb == 1:
-        local_bps = [[_local_ops(g)[1] for g in chunk_geoms]]
+        local_bps = [[bp for _, bp in bundles]]
     else:
         local_bps = [
             [_local_ops(g.subset(np.arange(b * blk, (b + 1) * blk)))[1]
@@ -453,37 +470,50 @@ def _build_distributed(spec: ProjectorSpec, mesh: Mesh):
         return ia
 
     use_halo = halo > 0 and nz > 1
+    obs.count("distributed.collectives", 0)     # 0 until one is traced
 
     @partial(compat.shard_map, mesh=mesh, in_specs=(spec_vol,),
              out_specs=spec_sino, check_vma=False)
     def _fp(f_local):
-        x = halo_exchange_z(f_local, zax, halo) if use_halo else f_local
-        return jax.lax.switch(_flat_idx(), local_fps, x)
+        with obs.span("distributed.fp"):
+            x = f_local
+            if use_halo:
+                with jax.named_scope("dist_halo_exchange"):
+                    x = halo_exchange_z(f_local, zax, halo)
+            with jax.named_scope("dist_local_fp"):
+                return jax.lax.switch(_flat_idx(), local_fps, x)
 
     @partial(compat.shard_map, mesh=mesh, in_specs=(spec_sino,),
              out_specs=spec_vol, check_vma=False)
     def _bp(p_local):
-        idx = _flat_idx()
         # Overlap-communication schedule: one psum per comm block, issued
         # between the per-block Pallas backprojections — block b's
         # all-reduce is independent of block b+1's compute, so the XLA
         # async collectives hide the reduction behind the kernels.  With
         # comm="psum" (nb=1) this degenerates to the legacy synchronous
-        # single psum after the whole local backprojection.
-        acc = None
-        for b in range(nb):
-            pb = p_local[b * blk:(b + 1) * blk] if nb > 1 else p_local
-            part = jax.lax.switch(idx, local_bps[b], pb)
-            part = jax.lax.psum(part, aax)
-            acc = part if acc is None else acc + part
-        if use_halo:
-            acc = halo_reduce_z(acc, zax, halo)
-        return acc
+        # single psum after the whole local backprojection; with one
+        # angle shard there is nothing to reduce and no psum.
+        with obs.span("distributed.bp"):
+            idx = _flat_idx()
+            acc = None
+            for b in range(nb):
+                pb = p_local[b * blk:(b + 1) * blk] if nb > 1 else p_local
+                with jax.named_scope("dist_local_bp"):
+                    part = jax.lax.switch(idx, local_bps[b], pb)
+                if reduce:
+                    with jax.named_scope("dist_reduce"):
+                        part = jax.lax.psum(part, aax)
+                    obs.count("distributed.collectives")
+                acc = part if acc is None else acc + part
+            if use_halo:
+                with jax.named_scope("dist_halo_reduce"):
+                    acc = halo_reduce_z(acc, zax, halo)
+            return acc
 
     # jit *inside* the custom_vjp pair: an eager shard_map re-traces the
     # whole mesh program on every call, which dominates any real workload.
     fp, bp = ops._make_pair(jax.jit(_fp), jax.jit(_bp))
-    return fp, bp, spec_vol, spec_sino
+    return fp, bp, spec_vol, spec_sino, nb
 
 
 # --------------------------------------------------------------------------- #
@@ -519,8 +549,8 @@ class DistributedProjector:
                 "distribute(spec, mesh, ...)")
         self.spec = spec
         self.mesh = mesh
-        self.fp, self.bp, self._spec_vol, self._spec_sino = \
-            _build_distributed(spec, mesh)
+        (self.fp, self.bp, self._spec_vol, self._spec_sino,
+         self.comm_blocks) = _build_distributed(spec, mesh)
 
     # -- Projector-compatible surface -------------------------------------- #
     @property

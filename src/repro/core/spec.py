@@ -81,7 +81,8 @@ class ShardSpec:
         comm_blocks:   number of comm blocks for ``comm="overlap"``; 0 means
                        auto (largest divisor of the per-shard view count
                        that is <= 4, aligned with the kernels' ``bab``
-                       view-blocking granularity).
+                       view-blocking granularity; 1 with one angle shard,
+                       where there is nothing to reduce and no psum).
     """
 
     mesh_axes: Tuple[Optional[str], ...] = ("data", "model")

@@ -385,3 +385,105 @@ def test_dp_train_step_decreases_loss(mesh42):
     # matched pair, the pmean syncs shards, and every step improves
     assert all(b < a for a, b in zip(losses, losses[1:]))
     assert losses[-1] < losses[0]
+
+
+# --------------------------------------------------------------------------- #
+# Four devices, in a child process (tier-1)
+# --------------------------------------------------------------------------- #
+def _four_device_child():
+    """Runs in a child process with four host devices: builds and traces the
+    pairs the tests below read, and prints what they read as JSON."""
+    import json
+    from repro.runtime import obs
+    mesh = compat.make_mesh((1, 4), ("data", "model"))
+    out = {"devices": jax.device_count()}
+
+    obs._REC = obs.Recorder()
+    vol = VolumeGeometry(8, 8, 8)
+    g = parallel_beam(16, 8, 16, vol)
+
+    def pair(**kw):
+        shard = ShardSpec(("data", "model"), angle_shards=1, z_shards=4,
+                          **kw)
+        return DistributedProjector(
+            ProjectorSpec(g, backend="pallas", shard=shard), mesh)
+
+    one = pair()
+    out["one_local_ops"] = obs.counter("distributed.local_ops")
+    four = pair(comm_blocks=4)
+    out["four_local_ops"] = obs.counter("distributed.local_ops") - \
+        out["one_local_ops"]
+    out["comm_blocks"] = [one.comm_blocks, four.comm_blocks]
+    kx, ky = jax.random.split(jax.random.PRNGKey(3))
+    x = jax.random.normal(kx, vol.shape)
+    y = jax.random.normal(ky, g.sino_shape)
+    s1, s4 = one(one.shard_volume(x)), four(four.shard_volume(x))
+    b1, b4 = one.T(one.shard_sino(y)), four.T(four.shard_sino(y))
+    out["fp_rel"] = float(jnp.max(jnp.abs(s1 - s4)) / jnp.max(jnp.abs(s1)))
+    out["bp_rel"] = float(jnp.max(jnp.abs(b1 - b4)) / jnp.max(jnp.abs(b1)))
+    fp_ref, bp_ref = ops.get_ops(one.spec.replace(shard=None))
+    out["bp_vs_local"] = float(jnp.max(jnp.abs(b1 - bp_ref(y)))
+                               / jnp.max(jnp.abs(b1)))
+    snap = obs.snapshot()
+    out["par_collectives"] = snap["counters"].get("distributed.collectives")
+    out["spans"] = {k: v["count"] for k, v in snap["spans"].items()}
+
+    obs._REC = obs.Recorder()
+    deep = VolumeGeometry(24, 24, 32)
+    gc = cone_beam(8, 40, 32, deep, sod=60.0, sdd=80.0)
+    halo = suggest_halo(gc, 4)
+    dc = DistributedProjector(ProjectorSpec(gc, shard=ShardSpec(
+        ("data", "model"), angle_shards=1, z_shards=4, halo=halo)), mesh)
+    f = jax.random.normal(kx, deep.shape)
+    jax.block_until_ready(dc.T(dc(dc.shard_volume(f))))
+    out["cone_halo"] = halo
+    out["cone_collectives"] = obs.counter("distributed.collectives")
+    print(json.dumps(out), flush=True)
+
+
+@pytest.fixture(scope="module")
+def four_devices():
+    import json
+    import os
+    import pathlib
+    import subprocess
+    import sys
+    here = pathlib.Path(__file__).resolve().parent
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join([str(here.parent / "src"),
+                                           str(here)]))
+    code = "from test_distributed_ct import _four_device_child as c; c()"
+    out = subprocess.run([sys.executable, "-c", code], cwd=here.parent,
+                         env=env, capture_output=True, text=True,
+                         timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_one_angle_shard_builds_one_bp_bundle_and_no_psum(four_devices):
+    """With one angle shard there is nothing to reduce: one comm block (the
+    FP and BP share the chunk's bundle), no collective traced, and the
+    result equals the four-block schedule to float32 rounding."""
+    r = four_devices
+    assert r["devices"] == 4
+    assert r["comm_blocks"] == [1, 4]
+    assert r["one_local_ops"] == 1           # one chunk, one bundle
+    assert r["four_local_ops"] == 1 + 4      # the FP's, and one per block
+    assert r["par_collectives"] == 0
+    assert r["fp_rel"] == 0.0
+    assert r["bp_rel"] < 1e-6
+    assert r["bp_vs_local"] < 1e-5
+
+
+def test_cone_halo_zslab_counts_its_ppermutes(four_devices):
+    r = four_devices
+    assert 1 <= r["cone_halo"] < 32 // 4
+    assert r["cone_collectives"] == 4        # exchange and reduce: 2 each
+
+
+def test_distributed_spans_recorded(four_devices):
+    spans = four_devices["spans"]
+    assert spans["distributed.build"] == 2
+    for name in ("distributed.fp", "distributed.bp"):
+        assert spans[name] >= 2, spans

@@ -29,7 +29,7 @@ from repro.launch.ct_train import TrainConfig, build_geometry
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     saved = os.environ.get("TPU_LOG_DIR")
     os.environ["TPU_LOG_DIR"] = "disabled"       # no compiler logs under /tmp
@@ -42,12 +42,17 @@ def one_chip():
                                             topology_name="v5e:2x2")
     except Exception as e:                         # noqa: BLE001
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", cache)
     if saved is None:
         os.environ.pop("TPU_LOG_DIR", None)
     else:
         os.environ["TPU_LOG_DIR"] = saved
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 @pytest.fixture
@@ -124,29 +129,49 @@ STAGES = {
     "cone": {f"sf_cone_{d}_{s}" for d in ("fp", "bp")
              for s in ("pre", "g0", "g1", "post")},
     "fbp": {"fbp_filter", "fbp_interp", "fbp_zcontract", "fbp_accum"},
+    # The sharded pair's stages: the toy cone on a (2, 2) mesh of the four
+    # described chips, two angle shards and two z-slabs with a halo.
+    "sharded": {"dist_local_fp", "dist_local_bp", "dist_reduce",
+                "dist_halo_exchange", "dist_halo_reduce"},
 }
 
 
 @pytest.mark.parametrize("kind", sorted(STAGES))
-def test_device_scopes_name_the_stages(kind, one_chip, mosaic):
+def test_device_scopes_name_the_stages(kind, topo, one_chip, mosaic):
     """The chip's compiled program of a toy projector step (under the
-    harness's scopes ``fp``, ``residual``, ``bp``) and of a toy FBP (under
-    ``fbp``) carries each program stage's scope in its ops' ``op_name``,
-    names each kernel by its pair, direction and view group, and has no
-    program scope equal to a harness scope."""
+    harness's scopes ``fp``, ``residual``, ``bp``), of the same step through
+    the sharded pair on four chips, and of a toy FBP (under ``fbp``)
+    carries each program stage's scope in its ops' ``op_name``, names each
+    kernel by its pair, direction and view group, and has no program scope
+    equal to a harness scope."""
     import re
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro import compat
+    from repro.core.distributed import DistributedProjector, suggest_halo
     from repro.core.fbp import fbp
     from repro.core.geometry import from_config
     from repro.core.projector import Projector
-    geom = from_config(SCOPED["parallel" if kind == "fbp" else kind])
+    from repro.core.spec import ShardSpec
+    geom = from_config(SCOPED[{"fbp": "parallel", "sharded": "cone"}.get(
+        kind, kind)])
+    shardings = [one_chip, one_chip]
     if kind == "fbp":
         def fn(p):
             with jax.named_scope("fbp"):
                 return fbp(p, geom)
         args = (geom.sino_shape,)
     else:
-        proj = Projector(ProjectorSpec(geom, backend="pallas",
-                                       mode="exact"))
+        spec = ProjectorSpec(geom, backend="pallas", mode="exact")
+        if kind == "sharded":
+            mesh = compat.make_mesh((2, 2), ("data", "model"),
+                                    devices=topo.devices)
+            shard = ShardSpec(("data", "model"), angle_shards=2, z_shards=2,
+                              halo=suggest_halo(geom, 2))
+            proj = DistributedProjector(spec.replace(shard=shard), mesh)
+            shardings = [NamedSharding(mesh, P(None, None, "model")),
+                         NamedSharding(mesh, P("data", "model", None))]
+        else:
+            proj = Projector(spec)
 
         def fn(x, b):
             with jax.named_scope("fp"):
@@ -156,8 +181,8 @@ def test_device_scopes_name_the_stages(kind, one_chip, mosaic):
             with jax.named_scope("bp"):
                 return r, proj.T(r)
         args = (geom.vol.shape, geom.sino_shape)
-    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
-            for s in args]
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=sh)
+            for s, sh in zip(args, shardings)]
     with jax.default_matmul_precision("highest"):
         hlo = jax.jit(fn).lower(*args).compile().as_text()
     parts = set()
@@ -172,5 +197,8 @@ def test_device_scopes_name_the_stages(kind, one_chip, mosaic):
     kernels = re.findall(r'^\s*(?:ROOT\s+)?%?([\w\-]+)\.\d+ = \S+ custom-call'
                          r'.*custom_call_target="tpu_custom_call"', hlo,
                          re.M)
-    assert sorted(kernels) == sorted(s for s in STAGES[kind]
-                                     if s[-2:] in ("g0", "g1"))
+    if kind == "sharded":       # each shard's branch has its own kernels
+        assert kernels and set(kernels) <= STAGES["cone"], kernels
+    else:
+        assert sorted(kernels) == sorted(s for s in STAGES[kind]
+                                         if s[-2:] in ("g0", "g1"))
