@@ -288,14 +288,16 @@ def _bp_kernel(params_ref,            # SMEM (n_views, 6)
     (bg, bv) volume sub-tiles.
 
     View-blocking (bab > 1) mirrors the forward kernel's ``ba``: the ``bab``
-    sinogram stripes arrive in a single wide DMA and the output tile is
+    sinogram stripes arrive in a single wide DMA and each sub-tile is
     read-modify-written once per block instead of once per view.
 
     Stripe reuse (bs > 1) blocks the gathered axis: while a stripe is
     resident in VMEM (double-buffered by the Pallas pipeline) it serves
     ``bs`` gathered-axis sub-tiles before eviction, dividing sinogram
-    traffic by ``bs``.  Each sub-tile keeps its own ``Wu`` detector window
-    (sized by ``bg``), so weight tiles do not widen with ``bs``."""
+    traffic by ``bs``; on the TPU the default covers the whole gathered
+    line.  The sub-tiles are walked by a loop, each keeping its own ``Wu``
+    detector window (sized by ``bg``), so weight tiles do not widen.  Every
+    voxel sums the same views in the same order at any ``bs``."""
     gb = pl.program_id(0)
     li = pl.program_id(1)
     ab = pl.program_id(3)
@@ -305,19 +307,14 @@ def _bp_kernel(params_ref,            # SMEM (n_views, 6)
         out_ref[...] = jnp.zeros_like(out_ref)
 
     lif = li.astype(jnp.float32)
-    subs = [jnp.zeros((bg, bv), jnp.float32) for _ in range(bs)]
-    for j in range(bab):
-        a = ab * bab + j
-        P = params_ref[a, 0]
-        Q = params_ref[a, 1]
-        R = params_ref[a, 2]
-        hs = params_ref[a, 3]
-        hd = params_ref[a, 4]
-        h = params_ref[a, 5]
+    views = [tuple(params_ref[ab * bab + j, k] for k in range(6))
+             for j in range(bab)]
 
-        for sj in range(bs):
-            gi0 = ((gb * bs + sj) * bg).astype(jnp.float32)
-            gi_abs = gi0 + tune.iota_f32((bg, 1), 0)
+    def sub_tile(sj, carry):
+        gi0 = ((gb * bs + sj) * bg).astype(jnp.float32)
+        gi_abs = gi0 + tune.iota_f32((bg, 1), 0)
+        sub = jnp.zeros((bg, bv), jnp.float32)
+        for j, (P, Q, R, hs, hd, h) in enumerate(views):
             uc_a = P * gi0 + Q * lif + R
             uc_b = P * (gi0 + bg - 1) + Q * lif + R
             ustart = tune.window_start((uc_a - u0) / du, (uc_b - u0) / du,
@@ -330,10 +327,13 @@ def _bp_kernel(params_ref,            # SMEM (n_views, 6)
             wgt = trapezoid_pixel_weight(el, el + du,
                                          uc - hs, uc - hd, uc + hd, uc + hs,
                                          h)
-            subs[sj] = subs[sj] + precision.mxu_dot(
-                precision.cast_like(wgt, qwin), qwin)
-    acc = subs[0] if bs == 1 else jnp.concatenate(subs, axis=0)
-    precision.store_tile(out_ref, slice(None), acc)
+            sub = sub + precision.mxu_dot(precision.cast_like(wgt, qwin),
+                                          qwin)
+        precision.store_tile(out_ref, (pl.ds(pl.multiple_of(sj * bg, bg), bg),
+                                       slice(None)), sub)
+        return carry
+
+    jax.lax.fori_loop(0, bs, sub_tile, 0)
 
 
 def _pad_cols(q, align: int):
@@ -357,9 +357,13 @@ def _run_bp_group(q, params: np.ndarray, geom: CTGeometry, gathered_x: bool,
         na, nup, nvp = q.shape
         params, q, bab = _pad_views(params, bab, q)
     nap = params.shape[0]
-    bs = max(1, min(bs, max(1, ng // bg)))    # don't block past the axis
+    bs = max(1, min(bs, -(-ng // bg)))        # at most the whole padded line
     bstr = bg * bs                            # gathered voxels per program
     ngp = tune.round_up(ng, bstr)
+    # Sinogram bytes the stripe BlockSpec fetches: once per program.
+    obs.count("kernels.bp_par_stripe_bytes",
+              (ngp // bstr) * nl * (nvp // bv) * nap * nup * bv
+              * q.dtype.itemsize)
     du, dx = geom.pixel_width, geom.vol.dx
     Wu = tune.aligned_window(int(math.ceil(bg * dx / du)) + 8, nup, align)
     u0 = float(geom.u_coords()[0])

@@ -18,7 +18,9 @@ The Pallas projector kernels are parameterized by six tile sizes:
          ``bab``-view stripe (double-buffered by the Pallas pipeline) is
          reused ``bs`` times before eviction instead of being re-fetched
          per gathered tile; the per-sub-tile detector window stays sized
-         by ``bg``, so weight tiles do not widen.
+         by ``bg``, so weight tiles do not widen.  On the TPU the parallel
+         BP's default covers the whole gathered line
+         (:func:`whole_line_bs`).
 
 Historically these were module constants (``BU``/``BV``); now every call
 site resolves a :class:`KernelConfig` through :func:`get_config`:
@@ -81,6 +83,9 @@ __all__ = [
 
 LANE = 128          # TPU lane width: the bv axis should be a multiple of this
 _SUBLANE = 8
+# VMEM budget of the parallel BP's double-buffered f32 output block
+# (2 * bs * bg * bv * 4 bytes): caps the whole-line stripe reuse.
+BP_OUT_VMEM = 4 << 20
 
 
 @dataclasses.dataclass(frozen=True)
@@ -310,6 +315,15 @@ def _autotune_enabled(flag: Optional[bool]) -> bool:
     return val not in ("", "0", "false", "no", "off")
 
 
+def whole_line_bs(geom: CTGeometry, bg: int, bv: int = LANE) -> int:
+    """Stripe reuse over the whole gathered line of either view group
+    (``ceil(max(nx, ny) / bg)`` sub-tiles), so the parallel BP fetches each
+    sinogram stripe once per (loop line, lane block, view block).  Capped
+    so the double-buffered f32 output block stays within ``BP_OUT_VMEM``."""
+    line = -(-max(geom.vol.nx, geom.vol.ny) // bg)
+    return max(1, min(line, BP_OUT_VMEM // (2 * bg * bv * 4)))
+
+
 def heuristic_config(geom: CTGeometry, batch: int = 1,
                      dtype=jnp.float32, packed: bool = False) -> KernelConfig:
     """Static table used off-TPU and as the autotune fallback/seed."""
@@ -347,10 +361,13 @@ def heuristic_config(geom: CTGeometry, batch: int = 1,
         # FP, sinogram stripe for BP); diminishing returns past ~8.
         ba = min(8 if na >= 8 else max(1, na), na)
         bab = min(4, na)
-        # One stripe serving two gathered sub-tiles halves BP stripe
-        # traffic for ~2x the output-tile VMEM — a safe default; autotune
-        # sweeps 1/2/4.
-        bs = 2 if lane_packed_bp else 1
+        # The parallel BP's stripe serves its whole gathered line; the fan
+        # (and packed cone) BP widens its window with the magnification and
+        # keeps two sub-tiles per stripe.  Autotune sweeps 1/2/4 too.
+        if geom.geom_type == "parallel":
+            bs = whole_line_bs(geom, bg, bv)
+        else:
+            bs = 2 if lane_packed_bp else 1
     else:
         # Interpret mode executes the per-view python loop serially — keep
         # programs minimal so correctness tests stay fast.
@@ -470,10 +487,14 @@ def default_candidates(geom: CTGeometry) -> Iterable[KernelConfig]:
     bas = sorted({min(b, na) for b in (1, 2, 4, 8)})
     bgs = [8, 16, 32]
     babs = sorted({min(b, na) for b in (1, 2, 4)})
-    bss = (1, 2, 4)                       # BP stripe-reuse blocking factors
     for bu, ba in itertools.product(bus, bas):
-        for bg, bab, bs in itertools.product(bgs, babs, bss):
-            yield KernelConfig(bu=bu, bv=LANE, ba=ba, bg=bg, bab=bab, bs=bs)
+        for bg, bab in itertools.product(bgs, babs):
+            bss = {1, 2, 4}               # BP stripe-reuse blocking factors
+            if geom.geom_type == "parallel":
+                bss.add(whole_line_bs(geom, bg))
+            for bs in sorted(bss):
+                yield KernelConfig(bu=bu, bv=LANE, ba=ba, bg=bg, bab=bab,
+                                   bs=bs)
 
 
 def autotune(geom: CTGeometry, batch: int = 1, dtype=jnp.float32,

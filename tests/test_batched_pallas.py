@@ -364,6 +364,66 @@ def test_bp_stripe_reuse_clamps_small_volumes():
                   ref.adjoint(y, g, "sf"))
 
 
+# name -> (geometry, leading batch, compute dtype).  The x-gathered case's
+# views all have |sin| >= |cos| and the y-gathered case's none; nx != ny so
+# the two groups' gathered lines differ, and 20 is no multiple of bg = 8.
+_WHOLE_LINE = {
+    "x-gathered": (lambda: parallel_beam(
+        5, 2, 40, VolumeGeometry(20, 12, 2),
+        angles=np.deg2rad([50.0, 70.0, 90.0, 110.0, 130.0])), 0, None),
+    "y-gathered": (lambda: parallel_beam(
+        5, 2, 40, VolumeGeometry(12, 20, 2),
+        angles=np.deg2rad([-40.0, -20.0, 0.0, 20.0, 40.0])), 0, None),
+    "ng-not-multiple": (lambda: parallel_beam(
+        7, 2, 40, VolumeGeometry(20, 28, 2)), 0, None),
+    "batch": (lambda: parallel_beam(6, 1, 30, VolumeGeometry(20, 20, 1)),
+              3, None),
+    "bf16": (lambda: parallel_beam(7, 2, 40, VolumeGeometry(20, 28, 2)), 0,
+             "bfloat16"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WHOLE_LINE))
+def test_bp_whole_line_stripe_reuse_is_bit_identical(case):
+    """Stripe reuse over the whole gathered line sums each voxel's views in
+    the order bs = 1 does: the result is equal, not merely close."""
+    make, batch, cdt = _WHOLE_LINE[case]
+    g = make()
+    lead = (batch,) if batch else ()
+    y = jax.random.normal(jax.random.PRNGKey(3), lead + g.sino_shape)
+    bs = -(-max(g.vol.nx, g.vol.ny) // 8)
+    one, line = (bp_parallel_sf_pallas(y, g, bg=8, bab=2, bs=b,
+                                       compute_dtype=cdt) for b in (1, bs))
+    np.testing.assert_array_equal(np.asarray(line), np.asarray(one))
+
+
+def test_bp_stripe_bytes_counter_falls_with_whole_line(monkeypatch):
+    """At the Table-1 512 geometry, with the TPU heuristic's whole-line bs,
+    the traced parallel BP's stripe bytes fall by ngp / (2 bg) against
+    bs = 2.  Traced only (``jax.eval_shape``): no kernel runs."""
+    from repro.configs.leap_ct import table1_geometries
+    from repro.kernels import tune
+    from repro.kernels.fp_par import _view_params
+    from repro.runtime import obs
+    monkeypatch.setattr(tune, "_on_tpu", lambda: True)
+    g = table1_geometries()["parallel_512_180"]
+    cfg = tune.heuristic_config(g)
+    assert cfg.bs == 512 // cfg.bg
+    y = jax.ShapeDtypeStruct(g.sino_shape, jnp.float32)
+    got = {}
+    for bs in (2, cfg.bs):
+        monkeypatch.setattr(obs, "_REC", obs.Recorder())
+        jax.eval_shape(lambda q: bp_parallel_sf_pallas(q, g, config=cfg,
+                                                       bs=bs), y)
+        got[bs] = obs.counter("kernels.bp_par_stripe_bytes")
+    # Both view groups: 1 whole-line program x 512 lines x 4 lane blocks
+    # (512 detector rows) x each group's padded views x 768 columns x 128
+    # lanes x 4 bytes.
+    nap = sum(tune.round_up(len(p), cfg.bab) for p in _view_params(g)[:2])
+    assert got[cfg.bs] == 512 * 4 * nap * 768 * 128 * 4
+    assert got[2] == got[cfg.bs] * 512 // (2 * cfg.bg)
+
+
 _BF16_KERNELS = [
     ("parallel", lambda: parallel_beam(6, 4, 24, VolumeGeometry(16, 16, 4))),
     ("fan", lambda: fan_beam(6, 4, 24, VolumeGeometry(16, 16, 4), sod=70.0,

@@ -23,6 +23,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.leap_ct import limited_angle_geometry, table1_geometries
+from repro.core import VolumeGeometry, parallel_beam
 from repro.core.spec import ProjectorSpec
 from repro.kernels import ops, tune
 from repro.launch.ct_train import TrainConfig, build_geometry
@@ -58,7 +59,8 @@ def one_chip(topo):
 @pytest.fixture
 def mosaic(monkeypatch):
     """Lower the kernels for the chip (not the interpreter) with the TPU
-    heuristic configs (view blocking ba=8, bab=4, stripe reuse bs=2)."""
+    heuristic configs (view blocking ba=8, bab=4; stripe reuse over the
+    whole gathered line in the parallel BP, bs=2 in the fan BPs)."""
     monkeypatch.setattr(tune, "_interpret", lambda: False)
     monkeypatch.setattr(tune, "_on_tpu", lambda: True)
 
@@ -78,6 +80,9 @@ CASES = {
                               "bfloat16"),
     "cone-exact-512-180-bf16": (_t1("cone_512_180"), 0, "exact",
                                 "bfloat16"),
+    # The z-slab each chip of the four-chip 1024^3/720 deployment runs.
+    "parallel-1024-slab-720": (lambda: parallel_beam(
+        720, 256, 1536, VolumeGeometry(1024, 1024, 256)), 0, "exact", None),
     "parallel-limited-angle-b8": (limited_angle_geometry, 8, "exact", None),
     "fan-512-b8": (_train("sparse_fan"), 8, "exact", None),
     "cone-exact-512-180": (_t1("cone_512_180"), 0, "exact", None),

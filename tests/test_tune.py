@@ -40,6 +40,46 @@ def test_candidates_sweep_stripe_reuse():
     assert {c.bs for c in cand} >= {1, 2, 4}
 
 
+@pytest.mark.parametrize("n,bs", [(512, 16), (1024, 32)])
+def test_tpu_heuristic_parallel_bp_covers_whole_line(monkeypatch, n, bs):
+    """On the TPU the parallel BP's stripe serves its whole gathered line:
+    Table-1 512 and 1024 at bg = 32."""
+    from repro.configs.leap_ct import table1_parallel
+    monkeypatch.setattr(tune, "_on_tpu", lambda: True)
+    cfg = tune.heuristic_config(table1_parallel(n, 8))
+    assert (cfg.bg, cfg.bs) == (32, bs)
+
+
+def test_tpu_heuristic_whole_line_respects_vmem_cap(monkeypatch):
+    """A very wide volume's whole-line bs is capped so the double-buffered
+    f32 output block stays within BP_OUT_VMEM."""
+    monkeypatch.setattr(tune, "_on_tpu", lambda: True)
+    cfg = tune.heuristic_config(
+        parallel_beam(8, 1, 12288, VolumeGeometry(8192, 8192, 1)))
+    assert 2 * cfg.bs * cfg.bg * cfg.bv * 4 == tune.BP_OUT_VMEM
+    assert cfg.bs * cfg.bg < 8192
+
+
+@pytest.mark.parametrize("kind", ["fan", "cone-packed"])
+def test_tpu_heuristic_fan_bps_keep_two_sub_tiles(monkeypatch, kind):
+    from repro.configs.leap_ct import table1_cone
+    from repro.core import fan_beam
+    monkeypatch.setattr(tune, "_on_tpu", lambda: True)
+    if kind == "fan":
+        cfg = tune.heuristic_config(fan_beam(
+            8, 1, 768, VolumeGeometry(512, 512, 1), sod=1000.0, sdd=1500.0))
+    else:
+        cfg = tune.heuristic_config(table1_cone(512, 8), packed=True)
+    assert cfg.bs == 2
+
+
+def test_candidates_hold_the_whole_line_bs():
+    g = parallel_beam(6, 2, 768, VolumeGeometry(512, 512, 2))
+    cand = list(tune.default_candidates(g))
+    for bg in (8, 16, 32):
+        assert {c.bs for c in cand if c.bg == bg} == {1, 2, 4, 512 // bg}
+
+
 def test_heuristic_defaults_off_tpu():
     cfg = tune.get_config(_geom())
     assert cfg.bv % 128 == 0
