@@ -19,8 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import (VolumeGeometry, cone_beam, fan_beam, helical_beam,
-                        parallel_beam)
+from repro.core import (ProjectorSpec, VolumeGeometry, cone_beam, fan_beam,
+                        helical_beam, parallel_beam)
 from repro.kernels import ref
 from repro.kernels.fp_cone import (bp_cone_packed, bp_cone_sf_pallas,
                                    cone_packed_row_shift, fp_cone_packed,
@@ -136,14 +136,16 @@ def run(csv_rows: list):
     # the VJP is the backprojection kernel, not autodiff through pallas_call.
     from repro.kernels import ops
 
+    spec2 = ProjectorSpec(g2, "sf", backend="pallas")
+
     def loss_ops(x):
-        p = ops.forward_project(x, g2, "sf", backend="pallas")
+        p = ops.forward_project(x, spec2)
         return 0.5 * jnp.sum((p - yb) ** 2)
 
     t_grad_vmap = _t(lambda x: jax.grad(
         lambda z: 0.5 * jnp.sum(
             (jax.vmap(lambda s: ops.forward_project(
-                s, g2, "sf", backend="pallas"))(z) - yb) ** 2))(x),
+                s, spec2))(z) - yb) ** 2))(x),
         fb, reps=reps)
     csv_rows.append((f"kernel/grad2d_b{B}/pallas_vmap_seed",
                      t_grad_vmap * 1e6, mode))

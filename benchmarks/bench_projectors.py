@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.leap_ct import table1_geometries
-from repro.core import Projector
+from repro.core import Projector, ProjectorSpec
 from repro.launch import roofline
 
 
@@ -45,7 +45,7 @@ def run(csv_rows: list):
     cells = table1_geometries(reduced=True)
     full = table1_geometries(reduced=False)
     for name, geom in cells.items():
-        proj = Projector(geom, "sf")
+        proj = Projector(ProjectorSpec(geom, "sf"))
         f = jnp.asarray(np.random.default_rng(0).normal(
             size=geom.vol.shape).astype(np.float32))
         fp = jax.jit(lambda x: proj(x))

@@ -7,7 +7,8 @@ Two orthogonal sharding axes, matching the physics:
 * **angle sharding** (data axis): the X-ray transform is a concatenation of
   independent per-view operators, so forward projection is embarrassingly
   parallel over views; the adjoint is a *sum* over views — an all-reduce
-  which the backprojector overlaps with compute (see ``ShardSpec.comm``).
+  which the backprojector overlaps with compute (see
+  ``ShardSpec.comm_blocks``).
 * **z-slab sharding** (model axis): axial slabs of the volume.  Three
   regimes, in increasing generality:
 
@@ -35,8 +36,6 @@ tests/test_distributed_ct.py).
 API: build a :class:`~repro.core.spec.ProjectorSpec` with a
 :class:`~repro.core.spec.ShardSpec` attached and realize it with
 :class:`DistributedProjector` (or the :func:`distribute` convenience).
-The pre-spec ``make_distributed_projector`` 4-tuple factory survives as a
-once-warning deprecation shim.
 """
 from __future__ import annotations
 
@@ -52,7 +51,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import compat
 from repro.core.geometry import CTGeometry
-from repro.core.spec import ProjectorSpec, ShardSpec, _warn_legacy
+from repro.core.spec import ProjectorSpec, ShardSpec, _require_spec
 from repro.kernels import ops, tune
 from repro.runtime import obs
 
@@ -63,7 +62,6 @@ __all__ = [
     "suggest_halo",
     "halo_exchange_z",
     "halo_reduce_z",
-    "make_distributed_projector",
 ]
 
 
@@ -444,9 +442,7 @@ def _build_distributed(spec: ProjectorSpec, mesh: Mesh):
     local_fps = [fp for fp, _ in bundles]
 
     reduce = na > 1
-    if shard.comm == "psum":
-        nb = max(1, shard.comm_blocks) if shard.comm_blocks else 1
-    elif shard.comm_blocks:
+    if shard.comm_blocks:
         nb = shard.comm_blocks
     else:
         nb = _auto_comm_blocks(per, chunk_geoms[0], spec.config) \
@@ -490,9 +486,9 @@ def _build_distributed(spec: ProjectorSpec, mesh: Mesh):
         # between the per-block Pallas backprojections — block b's
         # all-reduce is independent of block b+1's compute, so the XLA
         # async collectives hide the reduction behind the kernels.  With
-        # comm="psum" (nb=1) this degenerates to the legacy synchronous
-        # single psum after the whole local backprojection; with one
-        # angle shard there is nothing to reduce and no psum.
+        # comm_blocks=1 this is a single synchronous psum after the whole
+        # local backprojection; with one angle shard there is nothing to
+        # reduce and no psum.
         with obs.span("distributed.bp"):
             idx = _flat_idx()
             acc = None
@@ -537,11 +533,7 @@ class DistributedProjector:
     """
 
     def __init__(self, spec: ProjectorSpec, mesh: Mesh):
-        if not isinstance(spec, ProjectorSpec):
-            raise TypeError(
-                f"DistributedProjector needs a ProjectorSpec, got "
-                f"{type(spec).__name__} (legacy geometry-first callers: "
-                f"use make_distributed_projector or build a spec)")
+        _require_spec(spec, "DistributedProjector")
         if spec.shard is None:
             raise ValueError(
                 "spec has no ShardSpec attached; pass "
@@ -600,13 +592,13 @@ class DistributedProjector:
         s = self.shard
         return (f"DistributedProjector({self.geom.geom_type}, "
                 f"angle_shards={s.angle_shards}, z_shards={s.z_shards}, "
-                f"halo={s.halo}, comm={s.comm}, vol={self.geom.vol.shape}, "
+                f"halo={s.halo}, vol={self.geom.vol.shape}, "
                 f"sino={self.geom.sino_shape})")
 
 
 def distribute(spec: ProjectorSpec, mesh: Mesh, *,
                angle_axis: str = "data", z_axis: Optional[str] = None,
-               halo: Optional[int] = None, comm: str = "overlap",
+               halo: Optional[int] = None,
                comm_blocks: int = 0) -> DistributedProjector:
     """Attach a mesh-derived :class:`ShardSpec` to ``spec`` and build the
     :class:`DistributedProjector`.
@@ -615,13 +607,9 @@ def distribute(spec: ProjectorSpec, mesh: Mesh, *,
     (0 for parallel/fan).  A spec that already carries a shard passes
     through unchanged (mixing it with layout kwargs raises).
     """
-    if not isinstance(spec, ProjectorSpec):
-        raise TypeError(
-            f"distribute() needs a ProjectorSpec, got "
-            f"{type(spec).__name__}")
+    _require_spec(spec, "distribute()")
     if spec.shard is not None:
-        if (angle_axis, z_axis, halo, comm, comm_blocks) != \
-                ("data", None, None, "overlap", 0):
+        if (angle_axis, z_axis, halo, comm_blocks) != ("data", None, None, 0):
             raise TypeError(
                 "distribute(): pass either a spec with a ShardSpec or "
                 "layout kwargs, not both")
@@ -631,37 +619,6 @@ def distribute(spec: ProjectorSpec, mesh: Mesh, *,
         halo = suggest_halo(spec.geom, z_shards)
     shard = ShardSpec(mesh_axes=(angle_axis, z_axis),
                       angle_shards=int(mesh.shape[angle_axis]),
-                      z_shards=z_shards, halo=halo, comm=comm,
-                      comm_blocks=comm_blocks)
+                      z_shards=z_shards, halo=halo, comm_blocks=comm_blocks)
     return DistributedProjector(spec.replace(shard=shard), mesh)
 
-
-# --------------------------------------------------------------------------- #
-# Legacy-call-site shim (pre-ShardSpec 4-tuple factory)
-# --------------------------------------------------------------------------- #
-def make_distributed_projector(geom: CTGeometry, mesh: Mesh,
-                               model: str = "sf", backend: str = "auto",
-                               angle_axis: str = "data",
-                               z_axis: Optional[str] = None,
-                               mode: str = "auto"):
-    """Deprecated 4-tuple factory — returns ``(fp, bp, shard_volume,
-    shard_sino)`` exactly as before the ShardSpec redesign (same
-    synchronous-psum schedule, bit-exact on the old call shape).  Build a
-    ``ProjectorSpec`` with a ``ShardSpec`` and use
-    :class:`DistributedProjector` instead; warns once per process.
-    """
-    _warn_legacy("make_distributed_projector")
-    if z_axis and geom.geom_type not in ("parallel", "fan"):
-        raise NotImplementedError(
-            "z-slab sharding requires parallel or fan beam (exact z "
-            "independence) through this legacy factory; cone/modular "
-            "z-slabs need a halo — use DistributedProjector with "
-            "ShardSpec(halo=suggest_halo(geom, z_shards))")
-    shard = ShardSpec(mesh_axes=(angle_axis, z_axis),
-                      angle_shards=int(mesh.shape[angle_axis]),
-                      z_shards=int(mesh.shape[z_axis]) if z_axis else 1,
-                      halo=0, comm="psum", comm_blocks=1)
-    spec = ProjectorSpec(geom, model=model, backend=backend, mode=mode,
-                         shard=shard)
-    dp = DistributedProjector(spec, mesh)
-    return dp.fp, dp.bp, dp.shard_volume, dp.shard_sino

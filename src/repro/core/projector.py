@@ -5,8 +5,7 @@ This is the JAX analogue of the paper's ``torch.nn.Module``-derived
 into any training/inference pipeline.
 
     >>> spec = ProjectorSpec(geom)             # frozen op description
-    >>> proj = Projector(spec)                 # (legacy Projector(geom, ...)
-    ...                                        #  still works via the shim)
+    >>> proj = Projector(spec)                 # the spec alone
     >>> sino = proj(volume)                    # A x        (differentiable)
     >>> vol  = proj.backproject(sino)          # A^T y      (differentiable)
     >>> rec  = proj.fbp(sino)                  # filtered backprojection
@@ -28,30 +27,26 @@ import jax.numpy as jnp
 
 from repro.core.fbp import fbp as _fbp
 from repro.core.geometry import CTGeometry
-from repro.core.spec import ProjectorSpec, as_spec
+from repro.core.spec import ProjectorSpec, _require_spec
 from repro.kernels import ops
 from repro.kernels.tune import KernelConfig
 
 
 class Projector:
-    def __init__(self, spec_or_geom, model: str = "sf",
-                 backend: str = "auto",
-                 config: Optional[KernelConfig] = None,
-                 mode: str = "auto", compute_dtype=None):
-        """Canonical form: ``Projector(ProjectorSpec(geom, ...))`` — the
-        spec is the single frozen description of the operator and doubles
-        as the op-cache / serving-bucket key.  The legacy geometry-first
-        form (``Projector(geom, model=..., mode=...)``) keeps working via
-        the deprecation shim in :mod:`repro.core.spec`.
+    def __init__(self, spec: ProjectorSpec):
+        """``Projector(ProjectorSpec(geom, ...))`` — the spec is the single
+        frozen description of the operator and doubles as the op-cache /
+        serving-bucket key; anything else raises ``TypeError``.
 
-        ``mode`` selects between the exact kernels and the approximate
-        lane-packed cone pair: "exact" always uses the exact kernels,
-        "packed" forces the packed pair (small-cone-angle pre-resample),
-        "auto" (default) uses packed only when the geometry's derived error
-        bound is under tolerance (see ``repro.kernels.tune.packed_cone_ok``).
+        The spec's ``mode`` selects between the exact kernels and the
+        approximate lane-packed cone pair: "exact" always uses the exact
+        kernels, "packed" forces the packed pair (small-cone-angle
+        pre-resample), "auto" (default) uses packed only when the
+        geometry's derived error bound is under tolerance (see
+        ``repro.kernels.tune.packed_cone_ok``).
         Non-cone geometries are unaffected by ``mode``.
 
-        ``compute_dtype`` sets the kernel tile precision ("bfloat16" |
+        Its ``compute_dtype`` sets the kernel tile precision ("bfloat16" |
         "float32"; None follows the input dtype): tiles stream at that
         dtype, accumulation stays f32, outputs keep the input's dtype —
         see kernels/precision.py for the policy and its tolerance model.
@@ -60,11 +55,9 @@ class Projector:
         geometry (Pallas for axial frames — incl. helical — via the
         registered `supports` gate); tilted frames fall back to the Joseph
         ray-marcher inside the ref dispatch, so "sf" is always safe here."""
-        self.spec = as_spec(spec_or_geom, "Projector", model=model,
-                            backend=backend, mode=mode,
-                            compute_dtype=compute_dtype, config=config)
+        self.spec = _require_spec(spec, "Projector")
 
-    # Back-compat attribute surface: pre-spec code read these directly.
+    # The spec's fields, read through the projector.
     @property
     def geom(self) -> CTGeometry:
         return self.spec.geom
@@ -88,16 +81,6 @@ class Projector:
     @property
     def compute_dtype(self):
         return self.spec.compute_dtype
-
-    @classmethod
-    def from_model_config(cls, geom: CTGeometry, model_config, **kwargs):
-        """Build a projector honoring a ``models.config.ModelConfig``: its
-        ``compute_dtype`` (the field the LM stack already applies to its
-        matmuls) becomes the kernel tile precision, so a reconstruction
-        head shares one precision policy with the model around it."""
-        kwargs.setdefault("compute_dtype",
-                          getattr(model_config, "compute_dtype", None))
-        return cls(ProjectorSpec(geom, **kwargs))
 
     # -- linear ops -------------------------------------------------------- #
     def __call__(self, volume):

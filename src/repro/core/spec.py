@@ -21,17 +21,16 @@ The spec is simultaneously
   * the **validation point**: bad model/mode/backend/dtype values raise here,
     once, instead of deep inside dispatch.
 
-Legacy geometry-first call sites (``Projector(geom, model=...)``,
-``forward_project(f, geom, ...)``) keep working through :func:`as_spec`,
-which emits a single :class:`DeprecationWarning` per entry point per
-process and builds the equivalent spec.
+The spec is the only way to name an operator: every entry point
+(``Projector``, ``get_ops``, ``forward_project``, ``back_project``,
+``resolve_mode``, ``resolve_backend``) takes one and raises ``TypeError``
+for anything else, a bare geometry included.
 """
 from __future__ import annotations
 
 import dataclasses
 import hashlib
 import json
-import warnings
 from typing import Optional, Tuple, TYPE_CHECKING
 
 from repro.core.geometry import CTGeometry
@@ -39,12 +38,11 @@ from repro.core.geometry import CTGeometry
 if TYPE_CHECKING:                                     # pragma: no cover
     from repro.kernels.tune import KernelConfig
 
-__all__ = ["ProjectorSpec", "ShardSpec", "as_spec", "reset_legacy_warnings"]
+__all__ = ["ProjectorSpec", "ShardSpec"]
 
 _MODELS = ("sf", "joseph")
 _BACKENDS = ("auto", "pallas", "ref")
 _MODES = ("auto", "exact", "packed")
-_COMMS = ("overlap", "psum")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,24 +70,22 @@ class ShardSpec:
                        independent) and positive for cone/modular z-slabs
                        (diverging / z-travelling rays read into the
                        neighbour slab).
-        comm:          backprojection reduction schedule — ``"overlap"``
-                       (default) splits the local views into comm blocks and
-                       issues one psum per block so the collective for block
-                       *b* overlaps the Pallas BP of block *b+1*;
-                       ``"psum"`` is the legacy single synchronous psum
-                       after all local views are backprojected.
-        comm_blocks:   number of comm blocks for ``comm="overlap"``; 0 means
-                       auto (largest divisor of the per-shard view count
-                       that is <= 4, aligned with the kernels' ``bab``
-                       view-blocking granularity; 1 with one angle shard,
-                       where there is nothing to reduce and no psum).
+        comm_blocks:   backprojection reduction schedule: the local views
+                       are split into this many blocks, with one psum per
+                       block, so the collective for block *b* overlaps the
+                       Pallas BP of block *b+1*; 1 is a single synchronous
+                       psum after all local views are backprojected.  0
+                       (default) means auto: the largest divisor of the
+                       per-shard view count that is <= 4 and aligned with
+                       the kernels' ``bab`` view-blocking granularity, and
+                       1 with one angle shard, where there is nothing to
+                       reduce and no psum.
     """
 
     mesh_axes: Tuple[Optional[str], ...] = ("data", "model")
     angle_shards: int = 1
     z_shards: int = 1
     halo: int = 0
-    comm: str = "overlap"
     comm_blocks: int = 0
 
     def __post_init__(self):
@@ -121,9 +117,6 @@ class ShardSpec:
             raise ValueError(
                 f"halo={self.halo} is meaningless with z_shards=1; "
                 f"set halo=0")
-        if self.comm not in _COMMS:
-            raise ValueError(f"unknown comm schedule {self.comm!r}; "
-                             f"expected one of {_COMMS}")
         if self.comm_blocks < 0:
             raise ValueError(
                 f"comm_blocks must be >= 0 (0 = auto), got {self.comm_blocks}")
@@ -141,7 +134,7 @@ class ShardSpec:
 
     def _identity(self) -> Tuple:
         return (self.mesh_axes, self.angle_shards, self.z_shards, self.halo,
-                self.comm, self.comm_blocks)
+                self.comm_blocks)
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -271,49 +264,11 @@ class ProjectorSpec:
                 f"sino={g.sino_shape})")
 
 
-# --------------------------------------------------------------------------- #
-# Legacy-call-site shim
-# --------------------------------------------------------------------------- #
-_DEFAULTS = ("sf", "auto", "auto", None, None)
-_WARNED: set = set()
-
-
-def _warn_legacy(api: str) -> None:
-    if api in _WARNED:
-        return
-    _WARNED.add(api)
-    warnings.warn(
-        f"{api} with geometry-first arguments is deprecated; build a "
-        f"ProjectorSpec once and pass it instead, e.g. "
-        f"spec = ProjectorSpec(geom, model=..., backend=...); {api}(spec). "
-        f"(warned once per process)",
-        DeprecationWarning, stacklevel=4)
-
-
-def reset_legacy_warnings() -> None:
-    """Forget which entry points already warned (test hook)."""
-    _WARNED.clear()
-
-
-def as_spec(spec_or_geom, api: str, model: str = "sf", backend: str = "auto",
-            mode: str = "auto", compute_dtype=None,
-            config=None) -> ProjectorSpec:
-    """Coerce an entry point's first argument to a :class:`ProjectorSpec`.
-
-    A spec passes through unchanged (mixing it with legacy keyword arguments
-    is ambiguous and raises); a :class:`CTGeometry` takes the legacy path —
-    one :class:`DeprecationWarning` per ``api`` per process, then the
-    equivalent spec, so pre-redesign call sites behave identically."""
-    if isinstance(spec_or_geom, ProjectorSpec):
-        if (model, backend, mode, compute_dtype, config) != _DEFAULTS:
-            raise TypeError(
-                f"{api}: pass either a ProjectorSpec or legacy keyword "
-                f"arguments, not both (got spec plus non-default kwargs)")
-        return spec_or_geom
-    if isinstance(spec_or_geom, CTGeometry):
-        _warn_legacy(api)
-        return ProjectorSpec(spec_or_geom, model=model, backend=backend,
-                             mode=mode, compute_dtype=compute_dtype,
-                             config=config)
-    raise TypeError(f"{api}: expected a ProjectorSpec or CTGeometry, "
-                    f"got {type(spec_or_geom).__name__}")
+def _require_spec(spec, api: str) -> ProjectorSpec:
+    """The one refusal of every entry point: a spec passes, anything else
+    (a bare geometry included) raises."""
+    if not isinstance(spec, ProjectorSpec):
+        raise TypeError(
+            f"{api} takes a ProjectorSpec, got {type(spec).__name__}; "
+            f"build one with ProjectorSpec(geom, model=..., backend=...)")
+    return spec

@@ -1,7 +1,6 @@
 from repro.data.phantoms import (random_ellipse_phantom, shepp_logan_2d,
                                  analytic_parallel_projection)
 from repro.data.pipeline import CTDataPipeline
-from repro.data.tokens import TokenPipeline
 
 __all__ = ["random_ellipse_phantom", "shepp_logan_2d",
-           "analytic_parallel_projection", "CTDataPipeline", "TokenPipeline"]
+           "analytic_parallel_projection", "CTDataPipeline"]

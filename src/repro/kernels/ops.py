@@ -33,25 +33,23 @@ exact pair, ``mode="packed"`` forces the packed one, and the default
 accepts the geometry.  Both pairs are matched custom_vjp pairs, so
 gradients stay exactly consistent in every mode.
 
-Precision: every entry point takes ``compute_dtype`` ("bfloat16" |
-"float32" | None = follow the input dtype) implementing the bf16-tile /
-f32-accumulate policy of :mod:`repro.kernels.precision`; the ref backend
-applies the matching quantize-data-only policy so oracles stay
-dtype-matched.
+Precision: the spec's ``compute_dtype`` ("bfloat16" | "float32" | None =
+follow the input dtype) implements the bf16-tile / f32-accumulate policy of
+:mod:`repro.kernels.precision`; the ref backend applies the matching
+quantize-data-only policy so oracles stay dtype-matched.
 
-Specs: every entry point canonically takes a
-:class:`repro.core.spec.ProjectorSpec` — the frozen consolidation of
-``(geom, model, backend, mode, compute_dtype, config)``.  Geometry-first
-calls (``get_ops(geom, model=...)``) keep working through the deprecation
-shim in :mod:`repro.core.spec` (one warning per entry point per process).
+Specs: every entry point takes a :class:`repro.core.spec.ProjectorSpec` —
+the frozen consolidation of ``(geom, model, backend, mode, compute_dtype,
+config)`` — and nothing else; a bare geometry raises ``TypeError``.
 
-Tile/block sizes come from :class:`repro.kernels.tune.KernelConfig`; pass
-``config=`` to pin one explicitly (it becomes part of the op-cache key, so a
-fixed config never retraces).  The op cache is a bounded LRU keyed on
-``spec.cache_key()`` — geometry *content* (``CTGeometry.canonical_hash()``)
-plus model/backend/config/resolved-mode and the dtype pair (normalized
-compute policy, input dtype) — so equal geometries share ops and evicted
-entries release both the traced functions and the geometry they close over.
+Tile/block sizes come from :class:`repro.kernels.tune.KernelConfig`; set
+the spec's ``config`` to pin one explicitly (it becomes part of the
+op-cache key, so a fixed config never retraces).  The op cache is a
+bounded LRU keyed on ``spec.cache_key()`` — geometry *content*
+(``CTGeometry.canonical_hash()``) plus model/backend/config/resolved-mode
+and the dtype pair (normalized compute policy, input dtype) — so equal
+geometries share ops and evicted entries release both the traced functions
+and the geometry they close over.
 :func:`cache_stats` exposes the size and the hit and miss counters
 (``ops.cache_hit`` and ``ops.cache_miss`` in :mod:`repro.runtime.obs`,
 beside the spans ``ops.get_bundle`` and ``ops.build``); the serving layer's
@@ -67,7 +65,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.geometry import CTGeometry
-from repro.core.spec import ProjectorSpec, as_spec
+from repro.core.spec import ProjectorSpec, _require_spec
 from repro.kernels import precision, ref, tune
 from repro.runtime import obs
 
@@ -87,8 +85,6 @@ class _KernelEntry(NamedTuple):
 
 # {(geom_type, model): _KernelEntry} — filled by the kernels package on import
 _KERNEL_TABLE: Dict[Tuple[str, str], _KernelEntry] = {}
-
-_MODES = ("auto", "exact", "packed")
 
 
 def register_kernel(geom_type: str, model: str, fp: Callable, bp: Callable,
@@ -173,9 +169,7 @@ def _use_pallas(geom: CTGeometry, model: str, backend: str) -> bool:
 def _resolve_mode(geom: CTGeometry, model: str, mode: str,
                   use_pallas: bool) -> str:
     """Collapse ``mode`` to the concrete pair that will dispatch
-    ("exact" | "packed")."""
-    if mode not in _MODES:
-        raise ValueError(f"unknown mode {mode!r}; expected one of {_MODES}")
+    ("exact" | "packed"); ``ProjectorSpec`` has validated ``mode``."""
     if mode == "exact":
         return "exact"
     entry = _KERNEL_TABLE.get((geom.geom_type, model))
@@ -195,33 +189,24 @@ def _resolve_mode(geom: CTGeometry, model: str, mode: str,
     return "exact"
 
 
-def resolve_mode(geom, model: str = "sf", backend: str = "auto",
-                 mode: str = "auto") -> str:
+def resolve_mode(spec: ProjectorSpec) -> str:
     """The concrete kernel mode ("exact" | "packed") that
-    ``forward_project``/``back_project`` would dispatch for these arguments —
+    ``forward_project``/``back_project`` would dispatch for ``spec`` —
     exposed so callers (and tests) can observe the ``mode="auto"`` policy
-    without probing numerics.  Accepts a ProjectorSpec or a geometry (this
-    is a read-only probe, so the geometry form is not deprecated here)."""
-    if isinstance(geom, ProjectorSpec):
-        geom, model, backend, mode = (geom.geom, geom.model, geom.backend,
-                                      geom.mode)
-    return _resolve_mode(geom, model, mode, _use_pallas(geom, model, backend))
+    without probing numerics."""
+    s = _require_spec(spec, "resolve_mode")
+    return _resolve_mode(s.geom, s.model, s.mode,
+                         _use_pallas(s.geom, s.model, s.backend))
 
 
-def resolve_backend(spec_or_geom, model: str = "sf",
-                    backend: str = "auto") -> str:
+def resolve_backend(spec: ProjectorSpec) -> str:
     """The backend ("pallas" | "ref") that ``forward_project`` /
-    ``back_project`` would dispatch: ``backend="auto"`` resolves to "ref"
-    off the TPU and for geometries outside a kernel's ``supports`` gate, so
-    a caller that must run on the chip's kernels asserts on this.  Accepts
-    a ProjectorSpec or a geometry (a read-only probe, like
-    :func:`resolve_mode`)."""
-    if isinstance(spec_or_geom, ProjectorSpec):
-        geom, model, backend = (spec_or_geom.geom, spec_or_geom.model,
-                                spec_or_geom.backend)
-    else:
-        geom = spec_or_geom
-    return "pallas" if _use_pallas(geom, model, backend) else "ref"
+    ``back_project`` would dispatch for ``spec``: ``backend="auto"``
+    resolves to "ref" off the TPU and for geometries outside a kernel's
+    ``supports`` gate, so a caller that must run on the chip's kernels
+    asserts on this."""
+    s = _require_spec(spec, "resolve_backend")
+    return "pallas" if _use_pallas(s.geom, s.model, s.backend) else "ref"
 
 
 @obs.spanned("ops.build")
@@ -323,31 +308,23 @@ def cache_stats() -> Dict[str, int]:
             "misses": obs.counter("ops.cache_miss")}
 
 
-def get_ops(spec_or_geom, model: str = "sf", backend: str = "auto",
-            config: Optional[tune.KernelConfig] = None,
-            mode: str = "auto", compute_dtype=None) -> Tuple[Callable, Callable]:
+def get_ops(spec: ProjectorSpec) -> Tuple[Callable, Callable]:
     """Return the (forward, back) matched differentiable pair for a spec.
 
-    Canonical form: ``get_ops(ProjectorSpec(geom, ...))``.  The legacy
-    geometry-first form (``get_ops(geom, model=..., ...)``) still works via
-    the deprecation shim in :mod:`repro.core.spec`.
+    The spec's ``mode`` selects between the exact kernels and an
+    approximate *packed* pair where one is registered (cone): "exact"
+    forces the exact pair, "packed" forces the packed pair (error ignored),
+    "auto" uses packed only when the per-geometry error bound is under
+    tolerance (``tune.packed_cone_ok``).  The packed pair is matched (exact
+    transpose of itself), so gradients stay consistent in every mode.
 
-    ``mode`` selects between the exact kernels and an approximate *packed*
-    pair where one is registered (cone): "exact" forces the exact pair,
-    "packed" forces the packed pair (error ignored), "auto" uses packed only
-    when the per-geometry error bound is under tolerance
-    (``tune.packed_cone_ok``).  The packed pair is matched (exact transpose
-    of itself), so gradients stay consistent in every mode.
-
-    ``compute_dtype`` sets the kernels' tile precision ("bfloat16" |
+    Its ``compute_dtype`` sets the kernels' tile precision ("bfloat16" |
     "float32"; None follows the input dtype) — accumulation is always f32
     and outputs keep the caller's dtype (see kernels/precision.py).
 
     Repeated calls with an equal spec return the *same* function objects, so
     jit caches built around them never retrace."""
-    spec = as_spec(spec_or_geom, "get_ops", model=model, backend=backend,
-                   mode=mode, compute_dtype=compute_dtype, config=config)
-    bundle = _get_bundle(spec)
+    bundle = _get_bundle(_require_spec(spec, "get_ops"))
     return bundle.fp, bundle.bp
 
 
@@ -378,31 +355,14 @@ def _apply(op: Callable, op_batched: Optional[Callable], x, ndim_in: int):
     return out if extra == 1 else out.reshape(lead + out.shape[1:])
 
 
-def forward_project(f, spec_or_geom, model: str = "sf",
-                    backend: str = "auto",
-                    config: Optional[tune.KernelConfig] = None,
-                    mode: str = "auto", compute_dtype=None):
-    """A @ f.  ``f``: (..., nx, ny, nz) -> (..., n_angles, n_rows, n_cols).
-
-    Canonical form: ``forward_project(f, ProjectorSpec(geom, ...))``; the
-    geometry-first form survives via the deprecation shim."""
-    spec = as_spec(spec_or_geom, "forward_project", model=model,
-                   backend=backend, mode=mode, compute_dtype=compute_dtype,
-                   config=config)
-    b = _get_bundle(spec, in_dtype=f.dtype)
+def forward_project(f, spec: ProjectorSpec):
+    """A @ f.  ``f``: (..., nx, ny, nz) -> (..., n_angles, n_rows, n_cols)."""
+    b = _get_bundle(_require_spec(spec, "forward_project"), in_dtype=f.dtype)
     return _apply(b.fp, b.fp_batched, f, 3)
 
 
-def back_project(p, spec_or_geom, model: str = "sf",
-                 backend: str = "auto",
-                 config: Optional[tune.KernelConfig] = None,
-                 mode: str = "auto", compute_dtype=None):
-    """A^T @ p.  ``p``: (..., n_angles, n_rows, n_cols) -> (..., nx, ny, nz).
-
-    Canonical form: ``back_project(p, ProjectorSpec(geom, ...))``; the
-    geometry-first form survives via the deprecation shim."""
-    spec = as_spec(spec_or_geom, "back_project", model=model,
-                   backend=backend, mode=mode, compute_dtype=compute_dtype,
-                   config=config)
-    b = _get_bundle(spec, in_dtype=p.dtype)
+def back_project(p, spec: ProjectorSpec):
+    """A^T @ p.  ``p``: (..., n_angles, n_rows, n_cols) ->
+    (..., nx, ny, nz)."""
+    b = _get_bundle(_require_spec(spec, "back_project"), in_dtype=p.dtype)
     return _apply(b.bp, b.bp_batched, p, 3)

@@ -15,7 +15,7 @@ One idiom, applied uniformly (see docs/KERNELS.md "Precision policy"):
   restored only once, on the final result (:func:`store_tile` is the single
   point where an accumulator meets an output ref).
 
-The policy is threaded as ``compute_dtype`` from ``Projector`` / ``get_ops``
+The policy is threaded as the ``ProjectorSpec``'s ``compute_dtype``
 through ``ops.py`` into each kernel entry point; ``None`` means "follow the
 input's dtype" (f32 in -> f32 tiles, bf16 in -> bf16 tiles + f32 accum).
 """

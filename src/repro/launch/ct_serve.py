@@ -1,9 +1,7 @@
 """Recon-as-a-service: geometry-bucketed dynamic batching for CT requests.
 
-The LM side of the repo serves token streams with fixed decode slots
-(:mod:`repro.launch.serve`); this module is the CT analogue.  A scanner farm
-produces a stream of small reconstruction jobs, most sharing a handful of
-protocol geometries.  The server
+A scanner farm produces a stream of small reconstruction jobs, most
+sharing a handful of protocol geometries.  The server
 
   * **buckets** incoming requests by ``(tier, solver, spec.bucket_key(),
     solver kwargs)`` — two requests may share a packed batch iff their
@@ -122,7 +120,7 @@ def _size_class(n: int, max_batch: int) -> int:
 class CTServer:
     """Geometry-bucketed dynamic batcher over the projector stack.
 
-    Synchronous by design (like :class:`repro.launch.serve.Server`): callers
+    Synchronous by design: callers
     ``submit`` then ``drain``/``step``.  ``max_batch=1`` degenerates to a
     serial per-request loop — the baseline the throughput bench compares
     against.

@@ -29,9 +29,9 @@ projection-consistency residual per geometry — the same numbers the
 
 Scale-out: ``data_parallel=True`` runs the train step under
 ``compat.shard_map`` over the local mesh's data axis — params/opt/EMA
-replicated, the batch sharded, grads+loss pmean'd — the same classic-DP
-schedule as :func:`repro.launch.train.make_ct_dp_train_step` (the projector
-stays local per shard; a spec carrying a
+replicated, the batch sharded, grads+loss pmean'd — the repo's one
+data-parallel CT training path (the projector stays local per shard; a
+spec carrying a
 :class:`~repro.core.spec.ShardSpec` is stripped the same way, because DP
 and operator sharding compose through
 :class:`~repro.core.distributed.DistributedProjector`, not through this

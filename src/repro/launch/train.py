@@ -5,14 +5,8 @@ sharded data pipeline, atomic async checkpointing with auto-resume, gradient
 clipping, (optional) 1-bit error-feedback gradient compression for the DP
 axis, and supervisor-based crash restart.
 
-The CT side plugs into the same mesh machinery:
-:func:`make_ct_dp_train_step` builds a data-parallel
-projector-in-the-loop step (the paper's differentiable projector inside
-the loss, gradients pmean'd over the data axis) for training recon
-networks against sinogram consistency.  It is the minimal DP primitive;
-the full CT training subsystem — supervised + DC losses, EMA, checkpoint
-/resume, eval harness, the same shard_map schedule behind
-``data_parallel=True`` — is :mod:`repro.launch.ct_train`.
+CT training (supervised + DC losses, EMA, checkpoint/resume, eval harness,
+data-parallel under ``data_parallel=True``) is :mod:`repro.launch.ct_train`.
 
 Examples:
     # smoke-train an assigned arch (reduced config) on CPU
@@ -30,9 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from jax.sharding import PartitionSpec as P
-
-from repro import compat, configs
+from repro import configs
 from repro.data.tokens import TokenPipeline
 from repro.launch import sharding
 from repro.launch.mesh import make_local_mesh, make_production_mesh
@@ -42,42 +34,6 @@ from repro.optim import adamw, warmup_cosine
 from repro.runtime import checkpoint as CKPT
 from repro.runtime import compression
 from repro.runtime.fault import Supervisor
-
-
-def make_ct_dp_train_step(spec, mesh, apply_fn, lr: float = 1e-3,
-                          axis: str = "data"):
-    """Data-parallel projector-in-the-loop CT train step on ``mesh``.
-
-    ``apply_fn(params, y) -> volume(s)`` is the recon network;  the loss is
-    the projection-consistency term ``0.5 * mean (A x - y)^2`` with the
-    paper's differentiable forward projector inside the graph, so gradients
-    flow through the matched pair.  Each device runs the full projector on
-    its batch shard (classic DP — the projector itself stays local; use
-    :class:`~repro.core.distributed.DistributedProjector` when the *volume*
-    outgrows a device instead), then grads and loss are pmean'd over
-    ``axis``.  Returns a jitted ``step(params, y) -> (params, loss)`` with
-    params replicated and ``y`` batch-sharded over ``axis``.
-    """
-    from repro.core.projector import Projector
-    if getattr(spec, "shard", None) is not None:
-        spec = spec.replace(shard=None)
-    proj = Projector(spec)
-
-    def _step(params, y):
-        def loss_fn(p):
-            x = apply_fn(p, y)
-            return proj.data_consistency(x, y)
-
-        loss, grads = jax.value_and_grad(loss_fn)(params)
-        grads = jax.lax.pmean(grads, axis)
-        loss = jax.lax.pmean(loss, axis)
-        params = jax.tree_util.tree_map(lambda p, g: p - lr * g,
-                                        params, grads)
-        return params, loss
-
-    stepped = compat.shard_map(_step, mesh, in_specs=(P(), P(axis)),
-                               out_specs=(P(), P()), check_vma=False)
-    return jax.jit(stepped)
 
 
 def build(cfg, mesh, lr=3e-4, total_steps=10_000, compress=False):

@@ -12,7 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import Projector, VolumeGeometry, cone_beam, parallel_beam
+from repro.core import (Projector, ProjectorSpec, VolumeGeometry, cone_beam,
+                        parallel_beam)
 from repro.data.phantoms import (Ellipse, analytic_parallel_projection,
                                  rasterize, shepp_logan_2d)
 
@@ -29,7 +30,7 @@ def _phantom_geom(n=64, na=24, supersample=4):
 @pytest.mark.parametrize("model", ["sf", "joseph"])
 def test_analytic_ellipse_projection(model):
     g, ells, f = _phantom_geom()
-    sino = Projector(g, model)(f)[:, 0, :]
+    sino = Projector(ProjectorSpec(g, model))(f)[:, 0, :]
     ana = analytic_parallel_projection(ells, np.asarray(g.angles),
                                        g.u_coords())
     err = np.abs(np.asarray(sino) - ana)
@@ -44,7 +45,7 @@ def test_sf_mass_conservation():
     vol = VolumeGeometry(32, 32, 4)
     g = parallel_beam(16, 4, 64, vol)
     f = jax.random.uniform(jax.random.PRNGKey(0), vol.shape)
-    sino = Projector(g, "sf")(f)
+    sino = Projector(ProjectorSpec(g, "sf"))(f)
     mass_p = np.asarray(sino[:, 1, :].sum(axis=1)) * g.pixel_width
     mass_f = float(f[:, :, 1].sum()) * vol.dx * vol.dy
     np.testing.assert_allclose(mass_p, mass_f, rtol=1e-5)
@@ -60,8 +61,8 @@ def test_mm_scaling(model):
     g2 = dataclasses.replace(g1, vol=vol2, pixel_width=g1.pixel_width * s,
                              pixel_height=g1.pixel_height * s)
     f = jax.random.uniform(jax.random.PRNGKey(1), vol1.shape)
-    p1 = Projector(g1, model)(f)
-    p2 = Projector(g2, model)(f)
+    p1 = Projector(ProjectorSpec(g1, model))(f)
+    p2 = Projector(ProjectorSpec(g2, model))(f)
     np.testing.assert_allclose(np.asarray(p2), np.asarray(p1) * s,
                                rtol=1e-4, atol=1e-5)
 
@@ -73,7 +74,7 @@ def test_fbp_quantitative_parallel():
     X, Y = np.meshgrid(xs, vol.y_coords(), indexing="ij")
     f = (0.02 * ((X ** 2 + Y ** 2) <= 15.0 ** 2)).astype(np.float32)
     f = jnp.asarray(np.repeat(f[:, :, None], 2, axis=2))
-    proj = Projector(g, "sf")
+    proj = Projector(ProjectorSpec(g, "sf"))
     rec = proj.fbp(proj(f))
     center = np.asarray(rec[42:54, 42:54, 1]).mean()
     assert abs(center / 0.02 - 1.0) < 0.02
@@ -87,7 +88,7 @@ def test_fdk_quantitative_cone():
     X, Y = np.meshgrid(xs, vol.y_coords(), indexing="ij")
     f = (0.02 * ((X ** 2 + Y ** 2) <= 15.0 ** 2)).astype(np.float32)
     f = jnp.asarray(np.repeat(f[:, :, None], 4, axis=2))
-    proj = Projector(g, "sf")
+    proj = Projector(ProjectorSpec(g, "sf"))
     rec = proj.fbp(proj(f))
     center = np.asarray(rec[42:54, 42:54, 2]).mean()
     assert abs(center / 0.02 - 1.0) < 0.05
@@ -97,7 +98,7 @@ def test_shepp_logan_roundtrip_psnr():
     vol = VolumeGeometry(64, 64, 1)
     g = parallel_beam(90, 1, 96, vol)
     f = jnp.asarray(shepp_logan_2d(vol)[:, :, None]) * 0.02
-    proj = Projector(g, "sf")
+    proj = Projector(ProjectorSpec(g, "sf"))
     rec = proj.fbp(proj(f))
     mse = float(jnp.mean((rec - f) ** 2))
     psnr = 10 * np.log10(float(jnp.max(f)) ** 2 / mse)

@@ -28,8 +28,8 @@ except ImportError:
 
     st = _NullStrategies()
 
-from repro.core import (Projector, VolumeGeometry, cone_beam, fan_beam,
-                        helical_beam, parallel_beam)
+from repro.core import (Projector, ProjectorSpec, VolumeGeometry, cone_beam,
+                        fan_beam, helical_beam, parallel_beam)
 from repro.core.geometry import cone_as_modular
 
 
@@ -48,7 +48,7 @@ def _dot_test(proj, key=0, tol=1e-4):
 def test_parallel_matched(model):
     v = VolumeGeometry(24, 24, 6)
     g = parallel_beam(10, 6, 36, v)
-    _dot_test(Projector(g, model))
+    _dot_test(Projector(ProjectorSpec(g, model)))
 
 
 @pytest.mark.parametrize("model", ["joseph", "sf"])
@@ -56,14 +56,14 @@ def test_cone_matched(model):
     v = VolumeGeometry(24, 24, 8)
     g = cone_beam(8, 12, 36, v, sod=120.0, sdd=240.0,
                   pixel_width=2.0, pixel_height=2.0)
-    _dot_test(Projector(g, model))
+    _dot_test(Projector(ProjectorSpec(g, model)))
 
 
 def test_cone_curved_matched():
     v = VolumeGeometry(24, 24, 8)
     g = cone_beam(8, 12, 36, v, sod=120.0, sdd=240.0, pixel_width=2.0,
                   pixel_height=2.0, detector_type="curved")
-    _dot_test(Projector(g, "joseph"))
+    _dot_test(Projector(ProjectorSpec(g, "joseph")))
 
 
 # Flat-detector cone Pallas matched pair (FP and BP both on-kernel) across
@@ -83,7 +83,7 @@ def test_cone_pallas_pair_matched_angles(nz, nv, dv, sod, sdd):
     v = VolumeGeometry(16, 16, nz)
     g = cone_beam(6, nv, 24, v, sod=sod, sdd=sdd,
                   pixel_width=2.0, pixel_height=dv)
-    _dot_test(Projector(g, "sf", backend="pallas"))
+    _dot_test(Projector(ProjectorSpec(g, "sf", backend="pallas")))
 
 
 def test_cone_pallas_bp_gradient_is_forward():
@@ -92,7 +92,7 @@ def test_cone_pallas_bp_gradient_is_forward():
     v = VolumeGeometry(16, 16, 8)
     g = cone_beam(5, 8, 24, v, sod=80.0, sdd=160.0,
                   pixel_width=2.0, pixel_height=2.0)
-    proj = Projector(g, "sf", backend="pallas")
+    proj = Projector(ProjectorSpec(g, "sf", backend="pallas"))
     y = jax.random.normal(jax.random.PRNGKey(0), g.sino_shape)
     x = jax.random.normal(jax.random.PRNGKey(1), v.shape)
     grad_y = jax.grad(lambda q: jnp.vdot(proj.T(q), x))(y)
@@ -105,7 +105,7 @@ def test_fan_matched(det):
     v = VolumeGeometry(24, 24, 4)
     g = fan_beam(8, 4, 36, v, sod=120.0, sdd=240.0, pixel_width=2.0,
                  detector_type=det)
-    _dot_test(Projector(g, "sf"))
+    _dot_test(Projector(ProjectorSpec(g, "sf")))
 
 
 @pytest.mark.parametrize("det", ["flat", "curved"])
@@ -113,14 +113,14 @@ def test_fan_pallas_pair_matched(det):
     v = VolumeGeometry(24, 24, 4)
     g = fan_beam(8, 4, 36, v, sod=120.0, sdd=240.0, pixel_width=2.0,
                  detector_type=det)
-    _dot_test(Projector(g, "sf", backend="pallas"))
+    _dot_test(Projector(ProjectorSpec(g, "sf", backend="pallas")))
 
 
 def test_modular_matched():
     v = VolumeGeometry(20, 20, 6)
     g = cone_as_modular(cone_beam(6, 10, 30, v, sod=100.0, sdd=200.0,
                                   pixel_width=2.0, pixel_height=2.0))
-    _dot_test(Projector(g))
+    _dot_test(Projector(ProjectorSpec(g)))
 
 
 # Modular Pallas matched pair (FP and BP both on-kernel) across frame
@@ -131,7 +131,7 @@ def test_modular_pallas_pair_matched_cone_frames():
     v = VolumeGeometry(20, 20, 6)
     g = cone_as_modular(cone_beam(6, 10, 30, v, sod=100.0, sdd=200.0,
                                   pixel_width=2.0, pixel_height=2.0))
-    _dot_test(Projector(g, "sf", backend="pallas"))
+    _dot_test(Projector(ProjectorSpec(g, "sf", backend="pallas")))
 
 
 @pytest.mark.parametrize("nz,pitch,nv", [(8, 8.0, 10), (24, 16.0, 6)])
@@ -139,7 +139,7 @@ def test_modular_pallas_pair_matched_helical(nz, pitch, nv):
     v = VolumeGeometry(16, 16, nz)
     g = helical_beam(1.0, pitch, 6, nv, 24, v, sod=80.0, sdd=160.0,
                      pixel_width=2.0, pixel_height=2.0)
-    _dot_test(Projector(g, "sf", backend="pallas"))
+    _dot_test(Projector(ProjectorSpec(g, "sf", backend="pallas")))
 
 
 def test_modular_pallas_pair_matched_batched():
@@ -161,7 +161,7 @@ def test_modular_pallas_bp_gradient_is_forward():
     v = VolumeGeometry(16, 16, 8)
     g = helical_beam(1.0, 8.0, 5, 8, 24, v, sod=80.0, sdd=160.0,
                      pixel_width=2.0, pixel_height=2.0)
-    proj = Projector(g, "sf", backend="pallas")
+    proj = Projector(ProjectorSpec(g, "sf", backend="pallas"))
     y = jax.random.normal(jax.random.PRNGKey(0), g.sino_shape)
     x = jax.random.normal(jax.random.PRNGKey(1), v.shape)
     grad_y = jax.grad(lambda q: jnp.vdot(proj.T(q), x))(y)
@@ -172,7 +172,7 @@ def test_modular_pallas_bp_gradient_is_forward():
 def test_pallas_pair_matched():
     v = VolumeGeometry(24, 24, 6)
     g = parallel_beam(10, 6, 36, v)
-    _dot_test(Projector(g, "sf", backend="pallas"))
+    _dot_test(Projector(ProjectorSpec(g, "sf", backend="pallas")))
 
 
 @settings(max_examples=8, deadline=None)
@@ -186,7 +186,7 @@ def test_parallel_matched_property(na, nu, off, du, seed):
     v = VolumeGeometry(16, 16, 4, offset_x=off)
     g = parallel_beam(na, 4, nu, v, angles=ang, pixel_width=du,
                       center_col=off / 2)
-    _dot_test(Projector(g, "sf"), key=seed)
+    _dot_test(Projector(ProjectorSpec(g, "sf")), key=seed)
 
 
 @settings(max_examples=6, deadline=None)
@@ -196,7 +196,7 @@ def test_cone_matched_property(sod, mag, seed):
     v = VolumeGeometry(16, 16, 6)
     g = cone_beam(6, 10, 30, v, sod=sod, sdd=sod * mag,
                   pixel_width=2.0, pixel_height=2.0)
-    _dot_test(Projector(g, "sf"), key=seed)
+    _dot_test(Projector(ProjectorSpec(g, "sf")), key=seed)
 
 
 @settings(max_examples=6, deadline=None)
@@ -208,14 +208,14 @@ def test_fan_matched_property(sod, mag, curved, seed):
     v = VolumeGeometry(16, 16, 4)
     g = fan_beam(6, 4, 30, v, sod=sod, sdd=sod * mag, pixel_width=2.0,
                  detector_type="curved" if curved else "flat")
-    _dot_test(Projector(g, "sf"), key=seed)
+    _dot_test(Projector(ProjectorSpec(g, "sf")), key=seed)
 
 
 def test_gradient_is_backprojection():
     """d/dx 0.5||Ax - y||^2 == A^T(Ax - y) exactly (custom_vjp wiring)."""
     v = VolumeGeometry(20, 20, 4)
     g = parallel_beam(8, 4, 30, v)
-    proj = Projector(g, "sf")
+    proj = Projector(ProjectorSpec(g, "sf"))
     x = jax.random.normal(jax.random.PRNGKey(0), v.shape)
     y = jax.random.normal(jax.random.PRNGKey(1), g.sino_shape)
     grad = jax.grad(lambda x: 0.5 * jnp.sum((proj(x) - y) ** 2))(x)
@@ -228,7 +228,7 @@ def test_double_differentiation():
     """grad of back_project (A^T)^T == A: the pair is self-consistent."""
     v = VolumeGeometry(16, 16, 2)
     g = parallel_beam(6, 2, 24, v)
-    proj = Projector(g, "sf")
+    proj = Projector(ProjectorSpec(g, "sf"))
     y = jax.random.normal(jax.random.PRNGKey(0), g.sino_shape)
     x = jax.random.normal(jax.random.PRNGKey(1), v.shape)
     grad_y = jax.grad(lambda y: jnp.vdot(proj.T(y), x))(y)
@@ -264,15 +264,15 @@ BF16_GEOMS = {
 @pytest.mark.parametrize("name", sorted(BF16_GEOMS))
 def test_bf16_pallas_pair_dot(name):
     g = BF16_GEOMS[name]()
-    proj = Projector(g, "sf", backend="pallas", mode="exact",
-                     compute_dtype="bfloat16")
+    proj = Projector(ProjectorSpec(g, "sf", backend="pallas", mode="exact",
+                                   compute_dtype="bfloat16"))
     _dot_test(proj, tol=float(precision.BF16_DOT_TOL))
 
 
 def test_bf16_packed_cone_pair_dot():
     g = BF16_GEOMS["cone"]()
-    proj = Projector(g, "sf", backend="pallas", mode="packed",
-                     compute_dtype="bfloat16")
+    proj = Projector(ProjectorSpec(g, "sf", backend="pallas", mode="packed",
+                                   compute_dtype="bfloat16"))
     _dot_test(proj, tol=float(precision.BF16_DOT_TOL))
 
 
@@ -281,7 +281,8 @@ def test_bf16_gradient_is_backprojection():
     *same* bf16 BP op, so grad == A^T(Ax - y) holds tightly (same closure,
     not merely the same math)."""
     g = BF16_GEOMS["parallel"]()
-    proj = Projector(g, "sf", backend="pallas", compute_dtype="bfloat16")
+    proj = Projector(ProjectorSpec(g, "sf", backend="pallas",
+                                   compute_dtype="bfloat16"))
     x = jax.random.normal(jax.random.PRNGKey(0), proj.vol_shape())
     y = jax.random.normal(jax.random.PRNGKey(1), proj.sino_shape())
     grad = jax.grad(lambda x: 0.5 * jnp.sum((proj(x) - y) ** 2))(x)

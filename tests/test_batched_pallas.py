@@ -10,8 +10,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (Projector, VolumeGeometry, cone_beam, fan_beam,
-                        parallel_beam)
+from repro.core import (Projector, ProjectorSpec, VolumeGeometry, cone_beam,
+                        fan_beam, parallel_beam)
 from repro.core.geometry import cone_as_modular
 from repro.kernels import ops, ref
 from repro.kernels.fp_fan import bp_fan_sf_pallas, fp_fan_sf_pallas
@@ -97,7 +97,7 @@ def test_lane_packed_bp_matches_vmap_and_oracle(shape):
 def test_lane_packed_pair_is_matched():
     """<A x, y> == <x, A^T y> on the batched lane-packed pallas path."""
     g = parallel_beam(10, 2, 36, VolumeGeometry(24, 24, 2))
-    proj = Projector(g, "sf", backend="pallas")
+    proj = Projector(ProjectorSpec(g, "sf", backend="pallas"))
     x = jax.random.normal(jax.random.PRNGKey(0), (6,) + g.vol.shape)
     y = jax.random.normal(jax.random.PRNGKey(1), (6,) + g.sino_shape)
     lhs = jnp.vdot(proj(x), y)
@@ -109,7 +109,7 @@ def test_lane_packed_gradient_is_backprojection():
     """The custom_vjp wiring holds on the batched path: the gradient of the
     data-consistency loss is exactly the batched backprojection."""
     g = parallel_beam(8, 1, 30, VolumeGeometry(20, 20, 1))
-    proj = Projector(g, "sf", backend="pallas")
+    proj = Projector(ProjectorSpec(g, "sf", backend="pallas"))
     x = jax.random.normal(jax.random.PRNGKey(0), (4,) + g.vol.shape)
     y = jax.random.normal(jax.random.PRNGKey(1), (4,) + g.sino_shape)
     grad = jax.grad(lambda x: 0.5 * jnp.sum((proj(x) - y) ** 2))(x)
@@ -119,7 +119,7 @@ def test_lane_packed_gradient_is_backprojection():
 def test_multi_leading_dims_flatten_through_kernel():
     g = parallel_beam(6, 2, 24, VolumeGeometry(16, 16, 2))
     f = jax.random.normal(jax.random.PRNGKey(0), (2, 3) + g.vol.shape)
-    out = ops.forward_project(f, g, "sf", backend="pallas")
+    out = ops.forward_project(f, ProjectorSpec(g, "sf", backend="pallas"))
     assert out.shape == (2, 3) + g.sino_shape
     _assert_close(out[1, 2], ref.forward(f[1, 2], g, "sf"))
 
@@ -152,7 +152,7 @@ def test_kernel_registered_dispatch():
     assert ("cone", "sf") in ops._KERNEL_TABLE
     g = parallel_beam(6, 4, 24, VolumeGeometry(16, 16, 4))
     f = jax.random.normal(jax.random.PRNGKey(0), g.vol.shape)
-    out = ops.forward_project(f, g, "sf", backend="pallas")
+    out = ops.forward_project(f, ProjectorSpec(g, "sf", backend="pallas"))
     _assert_close(out, ref.forward(f, g, "sf"))
 
 
@@ -167,34 +167,34 @@ def _dot_test(proj, key=0, tol=1e-4):
 
 @pytest.mark.parametrize("model", ["joseph", "sf"])
 def test_parallel_matched(model):
-    _dot_test(Projector(parallel_beam(10, 6, 36, VolumeGeometry(24, 24, 6)),
-                        model))
+    g = parallel_beam(10, 6, 36, VolumeGeometry(24, 24, 6))
+    _dot_test(Projector(ProjectorSpec(g, model)))
 
 
 @pytest.mark.parametrize("model", ["joseph", "sf"])
 def test_cone_matched(model):
     g = cone_beam(8, 12, 36, VolumeGeometry(24, 24, 8), sod=120.0, sdd=240.0,
                   pixel_width=2.0, pixel_height=2.0)
-    _dot_test(Projector(g, model))
+    _dot_test(Projector(ProjectorSpec(g, model)))
 
 
 def test_cone_curved_matched():
     g = cone_beam(8, 12, 36, VolumeGeometry(24, 24, 8), sod=120.0, sdd=240.0,
                   pixel_width=2.0, pixel_height=2.0, detector_type="curved")
-    _dot_test(Projector(g, "joseph"))
+    _dot_test(Projector(ProjectorSpec(g, "joseph")))
 
 
 def test_modular_matched():
     g = cone_as_modular(cone_beam(6, 10, 30, VolumeGeometry(20, 20, 6),
                                   sod=100.0, sdd=200.0,
                                   pixel_width=2.0, pixel_height=2.0))
-    _dot_test(Projector(g))
+    _dot_test(Projector(ProjectorSpec(g)))
 
 
 def test_double_differentiation():
     """grad of back_project (A^T)^T == A: the pair is self-consistent."""
     g = parallel_beam(6, 2, 24, VolumeGeometry(16, 16, 2))
-    proj = Projector(g, "sf")
+    proj = Projector(ProjectorSpec(g, "sf"))
     y = jax.random.normal(jax.random.PRNGKey(0), g.sino_shape)
     x = jax.random.normal(jax.random.PRNGKey(1), g.vol.shape)
     grad_y = jax.grad(lambda y: jnp.vdot(proj.T(y), x))(y)
@@ -261,7 +261,7 @@ def test_fan_lane_packed_pair_is_matched():
     """<A x, y> == <x, A^T y> on the batched lane-packed fan pallas path."""
     g = fan_beam(8, 2, 32, VolumeGeometry(20, 20, 2), sod=70.0, sdd=140.0,
                  pixel_width=2.0)
-    proj = Projector(g, "sf", backend="pallas")
+    proj = Projector(ProjectorSpec(g, "sf", backend="pallas"))
     x = jax.random.normal(jax.random.PRNGKey(0), (4,) + g.vol.shape)
     y = jax.random.normal(jax.random.PRNGKey(1), (4,) + g.sino_shape)
     lhs = jnp.vdot(proj(x), y)
@@ -300,7 +300,7 @@ def test_cone_pallas_pair_matched_unclamped_z_window():
     the kernels' axial window NZW, so the z-window genuinely slides."""
     g = cone_beam(6, 8, 24, VolumeGeometry(16, 16, 24), sod=100.0, sdd=150.0,
                   pixel_width=2.0, pixel_height=1.0)
-    proj = Projector(g, "sf", backend="pallas")
+    proj = Projector(ProjectorSpec(g, "sf", backend="pallas"))
     x = jax.random.normal(jax.random.PRNGKey(0), g.vol.shape)
     y = jax.random.normal(jax.random.PRNGKey(1), g.sino_shape)
     lhs = jnp.vdot(proj(x), y)
@@ -326,7 +326,7 @@ def test_cone_batched_bp_matches_vmap_and_oracle():
 def test_cone_batched_pair_is_matched():
     g = cone_beam(4, 8, 24, VolumeGeometry(16, 16, 8), sod=80.0, sdd=160.0,
                   pixel_width=2.0, pixel_height=2.0)
-    proj = Projector(g, "sf", backend="pallas")
+    proj = Projector(ProjectorSpec(g, "sf", backend="pallas"))
     x = jax.random.normal(jax.random.PRNGKey(0), (2,) + g.vol.shape)
     y = jax.random.normal(jax.random.PRNGKey(1), (2,) + g.sino_shape)
     lhs = jnp.vdot(proj(x), y)
@@ -433,7 +433,8 @@ _BF16_KERNELS = [
 ]
 
 
-@pytest.mark.parametrize("name,mk", _BF16_KERNELS, ids=[n for n, _ in _BF16_KERNELS])
+@pytest.mark.parametrize("name,mk", _BF16_KERNELS, ids=[n for n,
+                         _ in _BF16_KERNELS])
 def test_bf16_fp_bp_error_within_documented_bound(name, mk):
     """compute_dtype="bfloat16" stays within BF16_FP_REL_BOUND of the f32
     oracle for every registered pair, and actually perturbs the numerics
@@ -443,10 +444,10 @@ def test_bf16_fp_bp_error_within_documented_bound(name, mk):
     y = jax.random.normal(jax.random.PRNGKey(1), g.sino_shape)
     s_ref = ref.forward(f, g, "sf")
     b_ref = ref.adjoint(y, g, "sf")
-    s = ops.forward_project(f, g, "sf", backend="pallas", mode="exact",
-                            compute_dtype="bfloat16")
-    b = ops.back_project(y, g, "sf", backend="pallas", mode="exact",
+    spec = ProjectorSpec(g, "sf", backend="pallas", mode="exact",
                          compute_dtype="bfloat16")
+    s = ops.forward_project(f, spec)
+    b = ops.back_project(y, spec)
     assert s.dtype == jnp.float32 and b.dtype == jnp.float32
     rel_s = float(jnp.abs(s - s_ref).max() / jnp.abs(s_ref).max())
     rel_b = float(jnp.abs(b - b_ref).max() / jnp.abs(b_ref).max())

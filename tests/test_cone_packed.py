@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import Projector, VolumeGeometry, cone_beam
+from repro.core import Projector, ProjectorSpec, VolumeGeometry, cone_beam
 from repro.kernels import fp_cone, ops, tune
 from repro.kernels.tune import LANE, KernelConfig
 
@@ -144,10 +144,11 @@ def test_packed_kernels_match_jnp_oracle():
 def test_auto_dispatches_packed_under_tolerance():
     g = _geom(sod=400.0)
     assert tune.packed_cone_ok(g)
-    assert ops.resolve_mode(g, backend="pallas") == "packed"
+    assert ops.resolve_mode(ProjectorSpec(g, backend="pallas")) == "packed"
     # the dispatched op really is the packed kernel
     f = _blob_volume(g.vol)
-    out = ops.forward_project(f, g, backend="pallas", mode="auto")
+    out = ops.forward_project(f, ProjectorSpec(g, backend="pallas",
+                                               mode="auto"))
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(fp_cone.fp_cone_packed(f, g)),
                                rtol=1e-5, atol=1e-5)
@@ -160,9 +161,10 @@ def test_auto_refuses_past_threshold():
                   sod=40.0, sdd=80.0, pixel_width=2.0, pixel_height=2.0)
     assert fp_cone.cone_packed_row_shift(g) > tune.packed_cone_tolerance()
     assert not tune.packed_cone_ok(g)
-    assert ops.resolve_mode(g, backend="pallas") == "exact"
+    assert ops.resolve_mode(ProjectorSpec(g, backend="pallas")) == "exact"
     f = jax.random.normal(jax.random.PRNGKey(0), g.vol.shape)
-    out = ops.forward_project(f, g, backend="pallas", mode="auto")
+    out = ops.forward_project(f, ProjectorSpec(g, backend="pallas",
+                                               mode="auto"))
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(fp_cone.fp_cone_sf_pallas(f, g)),
                                rtol=1e-5, atol=1e-5)
@@ -173,7 +175,7 @@ def test_tolerance_env_override(monkeypatch):
     assert tune.packed_cone_ok(g)
     monkeypatch.setenv("REPRO_PACKED_CONE_TOL", "1e-9")
     assert not tune.packed_cone_ok(g)
-    assert ops.resolve_mode(g, backend="pallas") == "exact"
+    assert ops.resolve_mode(ProjectorSpec(g, backend="pallas")) == "exact"
     # a typo'd tolerance must be loud, not a silent fallback to the default
     monkeypatch.setenv("REPRO_PACKED_CONE_TOL", "0.1rows")
     with pytest.raises(ValueError):
@@ -183,8 +185,10 @@ def test_tolerance_env_override(monkeypatch):
 def test_mode_packed_forces_packed_and_exact_forces_exact():
     g = _geom(sod=60.0)     # past nothing — just distinguishable numerics
     f = _blob_volume(g.vol)
-    y_pack = ops.forward_project(f, g, backend="pallas", mode="packed")
-    y_exact = ops.forward_project(f, g, backend="pallas", mode="exact")
+    y_pack = ops.forward_project(f, ProjectorSpec(g, backend="pallas",
+                                                  mode="packed"))
+    y_exact = ops.forward_project(f, ProjectorSpec(g, backend="pallas",
+                                                   mode="exact"))
     np.testing.assert_allclose(np.asarray(y_pack),
                                np.asarray(fp_cone.fp_cone_packed(f, g)),
                                rtol=1e-5, atol=1e-5)
@@ -197,30 +201,31 @@ def test_mode_packed_forces_packed_and_exact_forces_exact():
 def test_mode_validation_and_unavailable_packed():
     g = _geom()
     with pytest.raises(ValueError):
-        ops.resolve_mode(g, mode="fast")
+        ops.resolve_mode(ProjectorSpec(g, mode="fast"))
     with pytest.raises(ValueError):
-        Projector(g, mode="fast")
+        Projector(ProjectorSpec(g, mode="fast"))
     # no packed pair registered for parallel: forcing it must raise
     from repro.core import parallel_beam
     gp = parallel_beam(4, 2, 16, VolumeGeometry(8, 8, 2))
     with pytest.raises(NotImplementedError):
-        ops.forward_project(jnp.zeros(gp.vol.shape), gp,
-                            backend="pallas", mode="packed")
+        ops.forward_project(jnp.zeros(gp.vol.shape),
+                            ProjectorSpec(gp, backend="pallas", mode="packed"))
     # curved-detector cone: packed pre-resample is flat-only — explicit raise
     gc = cone_beam(4, 4, 16, VolumeGeometry(8, 8, 4), sod=200.0, sdd=400.0,
                    pixel_width=2.0, pixel_height=2.0, detector_type="curved")
     with pytest.raises(NotImplementedError):
-        ops.forward_project(jnp.zeros(gc.vol.shape), gc,
-                            backend="pallas", mode="packed")
+        ops.forward_project(jnp.zeros(gc.vol.shape),
+                            ProjectorSpec(gc, backend="pallas", mode="packed"))
     # off the pallas backend mode="auto" quietly stays exact (ref path)
-    assert ops.resolve_mode(g, backend="ref", mode="auto") == "exact"
+    assert ops.resolve_mode(ProjectorSpec(g, backend="ref",
+                                          mode="auto")) == "exact"
 
 
 def test_projector_mode_plumbing_and_gradients():
     """mode= flows Projector -> ops; the packed pair is wired as a matched
     custom_vjp pair, so the gradient of the data term is the packed BP."""
     g = _geom(sod=400.0)
-    proj = Projector(g, backend="pallas", mode="packed")
+    proj = Projector(ProjectorSpec(g, backend="pallas", mode="packed"))
     f = _blob_volume(g.vol)
     y = fp_cone.fp_cone_packed(f, g)
     np.testing.assert_allclose(np.asarray(proj(f)), np.asarray(y),

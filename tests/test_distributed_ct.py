@@ -1,8 +1,8 @@
 """Distributed CT projection (shard_map over angles / z-slabs).
 
 Single-device CI runs the (1, 1)-mesh paths (shard_map wiring, psum,
-ppermute self-loops, validation, the legacy shim) plus everything that is
-pure host code (``suggest_halo``).  The multi-shard numerics — halo
+ppermute self-loops, validation) plus everything that is pure host code
+(``suggest_halo``).  The multi-shard numerics — halo
 exchange vs a numpy oracle, the three sharded layouts vs local ops,
 adjointness, the sliding-z helical capacity proof — run under the CI
 ``distributed`` leg with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
@@ -20,8 +20,7 @@ from repro.core import (Projector, ProjectorSpec, ShardSpec, VolumeGeometry,
                         cone_beam, helical_beam, parallel_beam)
 from repro.core.distributed import (DistributedProjector, _angle_chunks,
                                     distribute, halo_exchange_z,
-                                    halo_reduce_z, make_distributed_projector,
-                                    suggest_halo)
+                                    halo_reduce_z, suggest_halo)
 from repro.kernels import ops
 from repro.recon.result import as_projector
 from repro.recon.sirt import sirt
@@ -83,8 +82,8 @@ def _vs_local(dp, geom, tol=2e-5, seed=0):
 def test_legacy_factory_matches_local(mesh):
     vol = VolumeGeometry(24, 24, 4)
     g = parallel_beam(8, 4, 36, vol)
-    fp, bp, shard_v, shard_s = make_distributed_projector(
-        g, mesh, angle_axis="data", z_axis="model")
+    dp = distribute(ProjectorSpec(g), mesh, z_axis="model", comm_blocks=1)
+    fp, bp, shard_v, shard_s = dp.fp, dp.bp, dp.shard_volume, dp.shard_sino
     f = jax.random.normal(jax.random.PRNGKey(0), vol.shape)
     proj = Projector(ProjectorSpec(g))
     np.testing.assert_allclose(np.asarray(fp(shard_v(f))),
@@ -94,26 +93,6 @@ def test_legacy_factory_matches_local(mesh):
                                np.asarray(proj.T(y)), rtol=1e-5, atol=1e-5)
     # the spec_vol/spec_sino attribute-stuffing hack is gone
     assert not hasattr(fp, "spec_vol") and not hasattr(fp, "spec_sino")
-
-
-def test_legacy_factory_warns_once(mesh):
-    from repro.core.spec import reset_legacy_warnings
-    reset_legacy_warnings()
-    vol = VolumeGeometry(16, 16, 4)
-    g = parallel_beam(4, 4, 24, vol)
-    with pytest.warns(DeprecationWarning):
-        make_distributed_projector(g, mesh)
-    import warnings as _w
-    with _w.catch_warnings():
-        _w.simplefilter("error")
-        make_distributed_projector(g, mesh)   # second call: silent
-
-
-def test_legacy_factory_cone_zslab_still_not_implemented(mesh):
-    vol = VolumeGeometry(16, 16, 4)
-    g = cone_beam(4, 4, 24, vol, sod=60.0, sdd=80.0)
-    with pytest.raises(NotImplementedError, match="halo"):
-        make_distributed_projector(g, mesh, z_axis="model")
 
 
 def test_distributed_pair_matched(mesh):
@@ -217,7 +196,7 @@ def test_sirt_bit_parity_single_device_mesh(mesh):
     vol = VolumeGeometry(16, 16, 4)
     g = parallel_beam(8, 4, 24, vol)
     spec = ProjectorSpec(g)
-    dp = distribute(spec, mesh, comm="psum")
+    dp = distribute(spec, mesh, comm_blocks=1)
     f = jnp.abs(jax.random.normal(jax.random.PRNGKey(0), vol.shape))
     y = Projector(spec)(f)
     a = sirt(dp, y, n_iters=4)
@@ -353,38 +332,12 @@ def test_overlap_comm_matches_psum(mesh24):
     g = helical_beam(n_turns=4, pitch=8.0, n_angles=32, n_rows=6, n_cols=32,
                      vol=tall, sod=60.0, sdd=80.0)
     spec = ProjectorSpec(g)
-    over = distribute(spec, mesh24, z_axis="model", comm="overlap")
-    sync = distribute(spec, mesh24, z_axis="model", comm="psum")
+    over = distribute(spec, mesh24, z_axis="model", comm_blocks=0)
+    sync = distribute(spec, mesh24, z_axis="model", comm_blocks=1)
     y = jax.random.normal(jax.random.PRNGKey(0), g.sino_shape)
     np.testing.assert_allclose(np.asarray(over.T(over.shard_sino(y))),
                                np.asarray(sync.T(sync.shard_sino(y))),
                                rtol=1e-5, atol=1e-5)
-
-
-@needs8
-def test_dp_train_step_decreases_loss(mesh42):
-    from repro.launch.train import make_ct_dp_train_step
-    vol = VolumeGeometry(16, 16, 8)
-    g = parallel_beam(16, 8, 24, vol)
-    spec = ProjectorSpec(g)
-
-    def apply_fn(params, y):
-        return jnp.broadcast_to(params["vol"], (y.shape[0],) + vol.shape)
-
-    step = make_ct_dp_train_step(spec, mesh42, apply_fn, lr=5e-3,
-                                 axis="data")
-    truth = jnp.abs(jax.random.normal(jax.random.PRNGKey(0), vol.shape))
-    y1 = Projector(spec)(truth)
-    yb = jnp.stack([y1] * 8)
-    params = {"vol": jnp.zeros(vol.shape)}
-    losses = []
-    for _ in range(5):
-        params, loss = step(params, yb)
-        losses.append(float(loss))
-    # mechanics test, not a convergence benchmark: grads flow through the
-    # matched pair, the pmean syncs shards, and every step improves
-    assert all(b < a for a, b in zip(losses, losses[1:]))
-    assert losses[-1] < losses[0]
 
 
 # --------------------------------------------------------------------------- #

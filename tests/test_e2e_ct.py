@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import Projector, VolumeGeometry, parallel_beam
+from repro.core import Projector, ProjectorSpec, VolumeGeometry, parallel_beam
 from repro.data.pipeline import CTDataPipeline
 from repro.nn.unet import unet_apply, unet_init
 from repro.optim import adamw, apply_updates, constant
@@ -22,7 +22,7 @@ def psnr(a, b, peak):
 def trained():
     vol = VolumeGeometry(32, 32, 1)
     geom = parallel_beam(48, 1, 48, vol)
-    proj = Projector(geom, "sf")
+    proj = Projector(ProjectorSpec(geom, "sf"))
     pipe = CTDataPipeline(geom, batch_size=4, seed=0, mode="limited_angle",
                           available_deg=60.0)
     params = unet_init(jax.random.PRNGKey(0), base=8, levels=2)

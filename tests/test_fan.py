@@ -8,7 +8,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import Projector, VolumeGeometry, fan_beam, parallel_beam
+from repro.core import (Projector, ProjectorSpec, VolumeGeometry, fan_beam,
+                        parallel_beam)
 from repro.core.fbp import parker_weights
 from repro.kernels import ops, ref
 from repro.kernels.fp_fan import bp_fan_sf_pallas, fp_fan_sf_pallas
@@ -85,7 +86,7 @@ def test_fan_registered_dispatch():
     g = fan_beam(6, 2, 24, VolumeGeometry(16, 16, 2), sod=60.0, sdd=120.0,
                  pixel_width=2.0)
     f = jax.random.normal(jax.random.PRNGKey(0), g.vol.shape)
-    out = ops.forward_project(f, g, "sf", backend="pallas")
+    out = ops.forward_project(f, ProjectorSpec(g, "sf", backend="pallas"))
     _assert_close(out, ref.forward(f, g, "sf"))
 
 
@@ -115,7 +116,7 @@ def test_fan_fbp_quantitative_disc():
     for det in ("flat", "curved"):
         g = fan_beam(180, 2, 112, vol, sod=180.0, sdd=360.0, pixel_width=2.0,
                      angular_range=360.0, detector_type=det)
-        proj = Projector(g, "sf")
+        proj = Projector(ProjectorSpec(g, "sf"))
         rec = proj.fbp(proj(fd))
         center = np.asarray(rec[28:36, 28:36, 1]).mean()
         assert abs(center / 0.02 - 1.0) < 0.05, (det, center)
@@ -127,12 +128,12 @@ def test_fan_fbp_psnr_matches_parallel_baseline():
     vol = VolumeGeometry(64, 64, 1)
     f = jnp.asarray(shepp_logan_2d(vol)[:, :, None]) * 0.02
     gp = parallel_beam(90, 1, 96, vol)
-    pp = Projector(gp, "sf")
+    pp = Projector(ProjectorSpec(gp, "sf"))
     base = _psnr(pp.fbp(pp(f)), f)
     for det in ("flat", "curved"):
         gf = fan_beam(360, 1, 96, vol, sod=200.0, sdd=400.0, pixel_width=2.0,
                       angular_range=360.0, detector_type=det)
-        pf = Projector(gf, "sf")
+        pf = Projector(ProjectorSpec(gf, "sf"))
         got = _psnr(pf.fbp(pf(f)), f)
         assert got > base - 1.0, (det, got, base)
 
@@ -146,7 +147,7 @@ def test_fan_parker_short_scan():
     rng_deg = math.degrees(math.pi + 2 * gamma_max)
     g = fan_beam(144, 1, 96, vol, sod=200.0, sdd=400.0, pixel_width=2.0,
                  angular_range=rng_deg)
-    proj = Projector(g, "sf")
+    proj = Projector(ProjectorSpec(g, "sf"))
     sino = proj(f)
     parker = _psnr(proj.fbp(sino), f)               # auto-detects short scan
     naive = _psnr(proj.fbp(sino, short_scan=False), f)

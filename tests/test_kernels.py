@@ -10,6 +10,7 @@ pytest.importorskip(
     "kernel-vs-oracle coverage lives in tests/test_batched_pallas.py")
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
+from repro.core import ProjectorSpec
 from repro.core.geometry import VolumeGeometry, parallel_beam
 from repro.kernels import ref
 from repro.kernels.fp_par import bp_parallel_sf_pallas, fp_parallel_sf_pallas
@@ -88,7 +89,7 @@ def test_kernel_registered_dispatch():
     g = parallel_beam(6, 4, 24, vol)
     f = jax.random.normal(jax.random.PRNGKey(0), vol.shape)
     # explicit pallas backend routes through the kernel
-    out = ops.forward_project(f, g, "sf", backend="pallas")
+    out = ops.forward_project(f, ProjectorSpec(g, "sf", backend="pallas"))
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(ref.forward(f, g, "sf")),
                                rtol=2e-4, atol=2e-4)
@@ -198,7 +199,7 @@ def test_cone_pallas_pair_matched():
     vol = VolumeGeometry(16, 16, 8)
     g = cone_beam(6, 8, 24, vol, sod=80.0, sdd=160.0,
                   pixel_width=2.0, pixel_height=2.0)
-    proj = Projector(g, "sf", backend="pallas")
+    proj = Projector(ProjectorSpec(g, "sf", backend="pallas"))
     x = jax.random.normal(jax.random.PRNGKey(0), vol.shape)
     y = jax.random.normal(jax.random.PRNGKey(1), g.sino_shape)
     lhs = jnp.vdot(proj(x), y)

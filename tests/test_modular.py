@@ -20,8 +20,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (Projector, VolumeGeometry, cone_beam, from_config,
-                        helical_beam, modular_beam)
+from repro.core import (Projector, ProjectorSpec, VolumeGeometry, cone_beam,
+                        from_config, helical_beam, modular_beam)
 from repro.core.geometry import cone_as_modular
 from repro.kernels import fp_cone, fp_modular, ops, ref, tune
 from repro.recon import cgls, fista_tv, sirt
@@ -287,7 +287,7 @@ def test_joseph_oracle_quantitative_agreement():
 def test_projector_gradient_is_modular_bp():
     v = _vol()
     g = _helical(v, na=6)
-    proj = Projector(g, "sf", backend="pallas")
+    proj = Projector(ProjectorSpec(g, "sf", backend="pallas"))
     assert proj.model == "sf"                      # no joseph coercion left
     f = _rand(v.shape)
     y = _rand(g.sino_shape, seed=1)
@@ -305,7 +305,7 @@ def test_recon_helical_out_of_the_box():
                      pixel_width=1.5, pixel_height=1.5)
     f = (jnp.zeros(v.shape).at[5:11, 5:11, 2:6].set(0.02)
          .at[8:13, 3:7, 3:5].set(0.03))
-    proj = Projector(g)
+    proj = Projector(ProjectorSpec(g))
     y = proj(f)
     err0 = float(jnp.linalg.norm(f))
     x_s = sirt(proj, y, n_iters=30).image

@@ -11,7 +11,8 @@ import pytest
 pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
-from repro.core import (Projector, VolumeGeometry, fan_beam, parallel_beam)
+from repro.core import (Projector, ProjectorSpec, VolumeGeometry, fan_beam,
+                        parallel_beam)
 
 # hypothesis strategy over geometry families: parallel + fan (flat/curved)
 GEOM_KINDS = st.sampled_from(["parallel", "fan-flat", "fan-curved"])
@@ -33,7 +34,7 @@ def _geom(na=8, seed=0, kind="parallel"):
        seed=st.integers(0, 50), kind=GEOM_KINDS)
 def test_projector_linearity(a, b, seed, kind):
     g = _geom(seed=seed, kind=kind)
-    proj = Projector(g, "sf")
+    proj = Projector(ProjectorSpec(g, "sf"))
     kx, ky = jax.random.split(jax.random.PRNGKey(seed))
     x = jax.random.normal(kx, g.vol.shape)
     y = jax.random.normal(ky, g.vol.shape)
@@ -53,8 +54,8 @@ def test_view_subset_consistency(seed, k, kind):
     idx = np.sort(np.random.default_rng(seed).choice(8, size=k, replace=False))
     sub = g.subset(idx)
     x = jax.random.normal(jax.random.PRNGKey(seed), g.vol.shape)
-    full = Projector(g, "sf")(x)
-    part = Projector(sub, "sf")(x)
+    full = Projector(ProjectorSpec(g, "sf"))(x)
+    part = Projector(ProjectorSpec(sub, "sf"))(x)
     np.testing.assert_allclose(np.asarray(part), np.asarray(full[idx]),
                                rtol=1e-5, atol=1e-5)
 
@@ -67,7 +68,7 @@ def test_rotation_symmetry_radially_symmetric_object():
     X, Y = np.meshgrid(xs, vol.y_coords(), indexing="ij")
     f = np.exp(-(X ** 2 + Y ** 2) / 40.0).astype(np.float32)
     f = jnp.asarray(np.repeat(f[:, :, None], 2, 2))
-    sino = np.asarray(Projector(g, "sf")(f))
+    sino = np.asarray(Projector(ProjectorSpec(g, "sf"))(f))
     spread = np.abs(sino - sino.mean(axis=0)).max()
     assert spread < 6e-3 * sino.max()
 
@@ -76,7 +77,7 @@ def test_backprojection_of_uniform_sino_is_smooth_interior():
     """A^T(1) is strictly positive over the interior FOV (sanity for SIRT's
     normalization vectors)."""
     g = _geom()
-    col = Projector(g, "sf").T(jnp.ones(g.sino_shape))
+    col = Projector(ProjectorSpec(g, "sf")).T(jnp.ones(g.sino_shape))
     interior = np.asarray(col)[4:12, 4:12, 1:3]
     assert interior.min() > 0
 

@@ -1,7 +1,5 @@
-"""ProjectorSpec: validation, content identity, cache keys, legacy shims,
-and the stable-geometry-hash bugfix."""
-import warnings
-
+"""ProjectorSpec: validation, content identity, cache keys, the one
+refusal of every entry point, and the stable-geometry-hash bugfix."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -9,7 +7,7 @@ import pytest
 from repro.core import (Projector, ProjectorSpec, VolumeGeometry, cone_beam,
                         fan_beam, from_config, helical_beam, modular_beam,
                         parallel_beam)
-from repro.core.spec import ShardSpec, as_spec, reset_legacy_warnings
+from repro.core.spec import ShardSpec
 from repro.kernels import ops
 from repro.kernels.tune import KernelConfig
 
@@ -114,17 +112,6 @@ def test_modular_frames_hashed_by_content():
 
 
 # -- op-cache key unification ------------------------------------------------ #
-def test_spec_and_legacy_share_op_cache(geom):
-    ops.clear_cache()
-    spec = ProjectorSpec(geom)
-    fp_spec, bp_spec = ops.get_ops(spec)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        fp_legacy, bp_legacy = ops.get_ops(geom)
-    assert fp_spec is fp_legacy and bp_spec is bp_legacy
-    assert ops.cache_stats()["size"] == 1
-
-
 def test_equal_specs_share_cached_bundle(geom):
     ops.clear_cache()
     f = jnp.ones(geom.vol.shape)
@@ -136,49 +123,23 @@ def test_equal_specs_share_cached_bundle(geom):
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0, atol=0)
 
 
-# -- legacy shims ------------------------------------------------------------ #
-def test_legacy_kwargs_warn_exactly_once(geom):
-    reset_legacy_warnings()
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        p1 = Projector(geom, model="sf")
-        p2 = Projector(geom, model="joseph")
-    dep = [w for w in rec if issubclass(w.category, DeprecationWarning)]
-    assert len(dep) == 1
-    assert "ProjectorSpec" in str(dep[0].message)
-    # distinct entry points warn independently, still once each
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        ops.forward_project(jnp.ones(geom.vol.shape), geom)
-        ops.forward_project(jnp.ones(geom.vol.shape), geom)
-    dep = [w for w in rec if issubclass(w.category, DeprecationWarning)]
-    assert len(dep) == 1
-    assert p1.spec.model == "sf" and p2.spec.model == "joseph"
+# -- the one refusal -------------------------------------------------------- #
+_ENTRY_POINTS = {
+    "Projector": lambda g: Projector(g),
+    "get_ops": lambda g: ops.get_ops(g),
+    "forward_project": lambda g: ops.forward_project(jnp.ones(g.vol.shape), g),
+    "back_project": lambda g: ops.back_project(jnp.ones(g.sino_shape), g),
+    "resolve_mode": lambda g: ops.resolve_mode(g),
+    "resolve_backend": lambda g: ops.resolve_backend(g),
+}
 
 
-def test_legacy_behavior_preserved(geom):
-    f = jnp.ones(geom.vol.shape)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        legacy = Projector(geom, "sf", mode="exact")
-        y_fn = ops.forward_project(f, geom, mode="exact")
-    spec = ProjectorSpec(geom, model="sf", mode="exact")
-    modern = Projector(spec)
-    assert legacy.spec == spec
-    np.testing.assert_allclose(np.asarray(legacy(f)), np.asarray(modern(f)),
-                               rtol=0, atol=0)
-    np.testing.assert_allclose(np.asarray(y_fn), np.asarray(modern(f)),
-                               rtol=0, atol=0)
-
-
-def test_spec_plus_kwargs_is_an_error(geom):
-    spec = ProjectorSpec(geom)
-    with pytest.raises(TypeError, match="not both"):
-        Projector(spec, model="joseph")
-    with pytest.raises(TypeError, match="not both"):
-        as_spec(spec, "get_ops", mode="packed")
-    with pytest.raises(TypeError, match="ProjectorSpec or CTGeometry"):
-        as_spec(42, "get_ops")
+@pytest.mark.parametrize("entry", sorted(_ENTRY_POINTS))
+def test_spec_plus_kwargs_is_an_error(geom, entry):
+    """Every entry point takes a ProjectorSpec and nothing else: a bare
+    geometry raises one TypeError that names ProjectorSpec."""
+    with pytest.raises(TypeError, match=f"{entry} takes a ProjectorSpec"):
+        _ENTRY_POINTS[entry](geom)
 
 
 def test_projector_backcompat_attributes(geom):
@@ -205,8 +166,6 @@ def test_shard_spec_validation():
         ShardSpec(halo=-1)
     with pytest.raises(ValueError, match="meaningless"):
         ShardSpec(z_shards=1, halo=2)
-    with pytest.raises(ValueError, match="comm"):
-        ShardSpec(comm="ring")
     with pytest.raises(ValueError, match="comm_blocks"):
         ShardSpec(comm_blocks=-1)
 
@@ -221,7 +180,7 @@ def test_shard_spec_hash_roundtrip():
     import dataclasses
     c = ShardSpec(**dataclasses.asdict(a))
     assert c == a and hash(c) == hash(a)
-    assert len({a, b, a.replace(comm="psum")}) == 2
+    assert len({a, b, a.replace(comm_blocks=1)}) == 2
 
 
 def test_shard_participates_in_spec_identity(geom):
@@ -238,7 +197,7 @@ def test_shard_participates_in_spec_identity(geom):
     assert sharded == again and hash(sharded) == hash(again)
     assert sharded.bucket_key() == again.bucket_key()
     # different layouts must not share serving buckets or cache entries
-    other = ProjectorSpec(geom, shard=shard.replace(comm="psum"))
+    other = ProjectorSpec(geom, shard=shard.replace(comm_blocks=1))
     assert other != sharded and other.bucket_key() != sharded.bucket_key()
     with pytest.raises(TypeError, match="ShardSpec"):
         ProjectorSpec(geom, shard="angle")

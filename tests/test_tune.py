@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import Projector, VolumeGeometry, parallel_beam
+from repro.core import Projector, ProjectorSpec, VolumeGeometry, parallel_beam
 from repro.kernels import ops, ref, tune
 from repro.kernels.tune import KernelConfig
 
@@ -166,7 +166,7 @@ def test_resolve_backend_reports_what_dispatches():
     """``backend="auto"`` picks the jnp oracle off the TPU, and for a
     geometry outside a kernel's ``supports`` gate even on it;
     ``resolve_backend`` makes that visible instead of silent."""
-    from repro.core import ProjectorSpec, modular_beam
+    from repro.core import modular_beam
     g = _geom()
     on_tpu = jax.default_backend() == "tpu"
     assert ops.resolve_backend(ProjectorSpec(g)) == \
@@ -188,7 +188,7 @@ def test_pinned_config_produces_correct_kernels():
     g = _geom()
     tune.register_config(tune.shape_class(g), KernelConfig(bu=8, ba=3, bab=2))
     f = jax.random.normal(jax.random.PRNGKey(0), g.vol.shape)
-    out = ops.forward_project(f, g, "sf", backend="pallas")
+    out = ops.forward_project(f, ProjectorSpec(g, "sf", backend="pallas"))
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(ref.forward(f, g, "sf")),
                                rtol=2e-4, atol=2e-4)
@@ -257,8 +257,8 @@ def test_tune_cache_pre_stripe_entry_still_loads(tmp_path, monkeypatch):
 # --------------------------------------------------------------------------- #
 def test_ops_cache_content_keyed():
     """Two distinct but equal geometry objects share one op entry."""
-    fp1, _ = ops.get_ops(_geom(), "sf", "ref")
-    fp2, _ = ops.get_ops(_geom(), "sf", "ref")
+    fp1, _ = ops.get_ops(ProjectorSpec(_geom(), "sf", "ref"))
+    fp2, _ = ops.get_ops(ProjectorSpec(_geom(), "sf", "ref"))
     assert fp1 is fp2
 
 
@@ -267,7 +267,7 @@ def test_ops_cache_bounded_eviction():
     for i in range(ops._OPS_CACHE_SIZE + 40):
         g = parallel_beam(6, 2, 24, VolumeGeometry(16, 16, 2,
                                                    offset_x=1e-3 * (i + 1)))
-        ops.get_ops(g, "sf", "ref")
+        ops.get_ops(ProjectorSpec(g, "sf", "ref"))
     assert len(ops._OPS_CACHE) <= ops._OPS_CACHE_SIZE
 
 
@@ -275,10 +275,13 @@ def test_config_roundtrip_no_retrace():
     """Equal configs map to the same cached ops, so an outer jit never
     retraces; a different config is a different entry."""
     g = _geom()
-    fp1, bp1 = ops.get_ops(g, "sf", "pallas", config=KernelConfig(ba=2))
-    fp2, bp2 = ops.get_ops(g, "sf", "pallas", config=KernelConfig(ba=2))
+    fp1, bp1 = ops.get_ops(ProjectorSpec(g, "sf", "pallas",
+                                         config=KernelConfig(ba=2)))
+    fp2, bp2 = ops.get_ops(ProjectorSpec(g, "sf", "pallas",
+                                         config=KernelConfig(ba=2)))
     assert fp1 is fp2 and bp1 is bp2
-    fp3, _ = ops.get_ops(g, "sf", "pallas", config=KernelConfig(ba=3))
+    fp3, _ = ops.get_ops(ProjectorSpec(g, "sf", "pallas",
+                                       config=KernelConfig(ba=3)))
     assert fp3 is not fp1
 
 
@@ -304,7 +307,8 @@ def test_dtype_keyed_config_reachable():
     try:
         fp_par.fp_parallel_sf_pallas(f, g)
         ops.clear_cache()
-        ops.forward_project(f, g, "sf", backend="pallas")   # dispatch path too
+        # dispatch path too
+        ops.forward_project(f, ProjectorSpec(g, "sf", backend="pallas"))
     finally:
         tune.get_config = orig
     assert seen.count("bfloat16") >= 2
@@ -324,7 +328,7 @@ def test_batched_dispatch_resolves_with_real_batch(monkeypatch):
     monkeypatch.setattr(tune, "get_config", spy)
     ops.clear_cache()
     f = jax.random.normal(jax.random.PRNGKey(0), (8,) + g.vol.shape)
-    out = ops.forward_project(f, g, "sf", backend="pallas")
+    out = ops.forward_project(f, ProjectorSpec(g, "sf", backend="pallas"))
     assert out.shape == (8,) + g.sino_shape
     assert 8 in calls
 
@@ -334,19 +338,20 @@ def test_ops_cache_dtype_keyed():
     dtypes get distinct bundles (a cdt=None bundle follows its input's
     dtype, so f32 and bf16 callers must never share traced closures)."""
     g = _geom()
-    fp32, _ = ops.get_ops(g, "sf", "ref")
-    fpb, _ = ops.get_ops(g, "sf", "ref", compute_dtype="bfloat16")
+    fp32, _ = ops.get_ops(ProjectorSpec(g, "sf", "ref"))
+    fpb, _ = ops.get_ops(ProjectorSpec(g, "sf", "ref",
+                                       compute_dtype="bfloat16"))
     assert fp32 is not fpb
     # alias normalizes into the same key
-    fpb2, _ = ops.get_ops(g, "sf", "ref", compute_dtype="bf16")
+    fpb2, _ = ops.get_ops(ProjectorSpec(g, "sf", "ref", compute_dtype="bf16"))
     assert fpb is fpb2
     # input dtype is part of the content key even on the default-f32 path
     ops.clear_cache()
     f32 = jax.random.normal(jax.random.PRNGKey(0), g.vol.shape)
-    out32 = ops.forward_project(f32, g, "sf", backend="pallas")
+    out32 = ops.forward_project(f32, ProjectorSpec(g, "sf", backend="pallas"))
     n1 = len(ops._OPS_CACHE)
-    out16 = ops.forward_project(f32.astype(jnp.bfloat16), g, "sf",
-                                backend="pallas")
+    out16 = ops.forward_project(f32.astype(jnp.bfloat16),
+                                ProjectorSpec(g, "sf", backend="pallas"))
     assert len(ops._OPS_CACHE) == n1 + 1
     assert out32.dtype == jnp.float32 and out16.dtype == jnp.bfloat16
 
@@ -358,26 +363,27 @@ def test_projector_compute_dtype_roundtrip():
     g = _geom()
     x = jax.random.normal(jax.random.PRNGKey(0), g.vol.shape)
     from repro.kernels import precision
-    s32 = Projector(g, "sf", backend="pallas")(x)
-    sb = Projector(g, "sf", backend="pallas", compute_dtype="bf16")(x)
+    s32 = Projector(ProjectorSpec(g, "sf", backend="pallas"))(x)
+    sb = Projector(ProjectorSpec(g, "sf", backend="pallas",
+                                 compute_dtype="bf16"))(x)
     assert sb.dtype == jnp.float32
     rel = float(jnp.abs(sb - s32).max() / jnp.abs(s32).max())
     assert 0.0 < rel < precision.BF16_FP_REL_BOUND
     with pytest.raises(ValueError):
-        Projector(g, compute_dtype="float64")
+        Projector(ProjectorSpec(g, compute_dtype="float64"))
 
 
 def test_projector_accepts_config():
     g = _geom()
     cfg = KernelConfig(bu=8, ba=2)
-    proj = Projector(g, "sf", backend="pallas", config=cfg)
+    proj = Projector(ProjectorSpec(g, "sf", backend="pallas", config=cfg))
     x = jax.random.normal(jax.random.PRNGKey(0), g.vol.shape)
     y = jax.random.normal(jax.random.PRNGKey(1), g.sino_shape)
     lhs = jnp.vdot(proj(x), y)
     rhs = jnp.vdot(x, proj.T(y))
     assert abs(lhs - rhs) / max(abs(lhs), 1e-6) < 1e-4
     with pytest.raises(TypeError):
-        Projector(g, "sf", config="big")      # not a KernelConfig
+        Projector(ProjectorSpec(g, "sf", config="big"))  # not a KernelConfig
 
 
 def test_fbp_accepts_config():
