@@ -11,11 +11,24 @@ For non-equispaced angles the per-view quadrature weight is half the angular
 distance between its neighbours (trapezoid rule), matching the paper's
 "non-equispaced projection angles" support.
 
+Parallel and fan beams sample each view at detector-row resolution and
+map rows to z slices with one lerp matrix ``Lz`` that does not depend on
+the angle, while each view's quadrature weight is a scalar (fan beams
+also weight each (x, y) column, which leaves the rows alone).  So
+``sum_a w_a S_a^T Lz = (sum_a w_a S_a)^T Lz``: the scan accumulates the
+weighted views in row space and the one dense z contraction runs after
+it, at float32 (``Precision.HIGHEST``) whatever the caller's default
+matmul precision.  FDK samples z at an angle-dependent magnification and
+has no such contraction.  The counter ``fbp.z_contractions`` counts, at
+trace time, the dense z contractions each traced reconstruction will
+run: 1 for parallel and fan beams; FDK never counts, so it reads 0.
+
 Building the host tables (coordinates, interpolation matrix, quadrature
 weights) is the span ``fbp.tables``.  On the device, ``fbp_parallel``'s
-stages run under the scopes ``fbp_filter`` (the ramp filter), and in each
-view of the scan ``fbp_interp`` (the two gathers and the lerp),
-``fbp_zcontract`` (the detector-row to z contraction) and ``fbp_accum``.
+stages run under the scopes ``fbp_filter`` (the ramp filter), in each
+view of the scan ``fbp_interp`` (the two gathers and the lerp) and
+``fbp_accum`` (the weighted sum in row space), and once, after the scan,
+``fbp_zcontract`` (the detector-row to z contraction; ``fbp_fan``'s too).
 """
 from __future__ import annotations
 
@@ -30,6 +43,10 @@ from repro.core.geometry import CTGeometry
 from repro.runtime import obs
 
 _EPS = 1e-9
+# The one z contraction sums every view at once, so a bfloat16 pass would
+# round the whole reconstruction: it runs at float32 whatever the caller's
+# default matmul precision.
+_ZPREC = jax.lax.Precision.HIGHEST
 
 
 def _angle_weights(angles: np.ndarray, full_range: float) -> np.ndarray:
@@ -91,14 +108,15 @@ def fbp_parallel(sino, geom: CTGeometry, filter_name: str = "ramp"):
             g0 = jnp.take(qa, jnp.clip(j, 0, nu - 1), axis=1)    # (nv, nxy)
             g1 = jnp.take(qa, jnp.clip(j + 1, 0, nu - 1), axis=1)
             S = g0 * jnp.where(ok0, 1 - t, 0.0) + g1 * jnp.where(ok1, t, 0.0)
-        with jax.named_scope("fbp_zcontract"):
-            vol = jnp.einsum("vq,vz->qz", S, Lz).reshape(nx, ny, nz)
         with jax.named_scope("fbp_accum"):
-            return acc + w * vol, 0
+            return acc + w * S, 0
 
-    acc0 = jnp.zeros(v.shape, sino.dtype)
+    acc0 = jnp.zeros((nv, nx * ny), sino.dtype)
     acc, _ = jax.lax.scan(one, acc0, (angs, wts, q))
-    return acc
+    obs.count("fbp.z_contractions")
+    with jax.named_scope("fbp_zcontract"):
+        return jnp.einsum("vq,vz->qz", acc, Lz, precision=_ZPREC).reshape(
+            nx, ny, nz)
 
 
 def _fan_gamma(geom: CTGeometry) -> np.ndarray:
@@ -143,7 +161,12 @@ def fbp_fan(sino, geom: CTGeometry, filter_name: str = "ramp",
     curved detectors), then distance-weighted backprojection —
     ``sod^2/ell^2`` at flat-detector scale, ``sod*sdd/L^2`` equiangular.
     ``short_scan=None`` auto-detects: an angular span under ~2*pi enables
-    Parker weights (and drops the full-scan double-coverage 1/2)."""
+    Parker weights (and drops the full-scan double-coverage 1/2).
+
+    The distance weight depends on (x, y) and the angle but not on the
+    detector row, so it scales each view in row space before the view's
+    weighted add; the rows go to z slices in one contraction after the
+    scan, as in :func:`fbp_parallel`."""
     v = geom.vol
     nx, ny, nz = v.shape
     nu, nv = geom.n_cols, geom.n_rows
@@ -200,15 +223,22 @@ def fbp_fan(sino, geom: CTGeometry, filter_name: str = "ramp",
         g1 = jnp.take(qa, jnp.clip(j + 1, 0, nu - 1), axis=1)
         S = g0 * jnp.where(ok0, 1 - frac, 0.0) + g1 * jnp.where(ok1, frac, 0.0)
         S = S * wdist[None, :]
-        return acc + w * jnp.einsum("vq,vz->qz", S, Lz).reshape(nx, ny, nz), 0
+        return acc + w * S, 0
 
-    acc0 = jnp.zeros(v.shape, sino.dtype)
+    acc0 = jnp.zeros((nv, nx * ny), sino.dtype)
     acc, _ = jax.lax.scan(one, acc0, (angs, wts, q))
-    return acc
+    obs.count("fbp.z_contractions")
+    with jax.named_scope("fbp_zcontract"):
+        return jnp.einsum("vq,vz->qz", acc, Lz, precision=_ZPREC).reshape(
+            nx, ny, nz)
 
 
 def fbp_cone(sino, geom: CTGeometry, filter_name: str = "ramp"):
-    """FDK reconstruction (flat detector)."""
+    """FDK reconstruction (flat detector).
+
+    Each voxel's detector row depends on the angle (magnification
+    ``sdd/ell``), so z is sampled per view inside the scan: there is no
+    angle-independent row-to-z matrix to contract once after it."""
     v = geom.vol
     nx, ny, nz = v.shape
     nu, nv = geom.n_cols, geom.n_rows
